@@ -1,76 +1,50 @@
 """Persistent XLA compilation cache.
 
 Every entry point in this framework pays a trace+compile cost on first
-call (~20-40 s for the larger programs on TPU — SURVEY.md notes first
-compile latency as a TPU-environment fact). XLA can persist compiled
-executables to disk and reload them across process restarts; this module
-is the one switch that turns that on with safe settings, so server
-restarts, bench runs, and CLI scripts skip recompilation entirely.
+call (seconds for the serving buckets and the road solver's
+``while_loop`` programs). XLA can persist compiled executables to disk
+and reload them across process restarts; this module is the one switch
+that turns that on, so server restarts, replicas of one fleet, and the
+phases of one chip run skip recompilation.
 
-The reference has no analog (its compute is outsourced — SaaS calls have
-no compile step); this is TPU-native operational surface. Opt-out with
-``RTPU_COMPILE_CACHE=0``; point ``RTPU_COMPILE_CACHE=/path`` at a shared
-location to reuse one cache across jobs (safe: entries are keyed by
-program fingerprint, concurrent writers race benignly).
+Placement rule — the directory is part of every entry's key, so it must
+be the same path in every process that should share compiles:
 
-Security posture (shared with the native-library cache via
-``utils/paths.secure_user_cache_dir``): the default location is a
-per-user 0700 directory, and anything not ours or group/world-writable
-is rejected (a poisoned cache entry would be deserialized into the
-process), falling back to disabled rather than trusting it. An explicit
-path — argument or ``RTPU_COMPILE_CACHE=/path`` — is operator choice and
-used as-is; if it cannot be created the cache is disabled, never fatal.
+- ``JAX_COMPILATION_CACHE_DIR`` set: JAX already holds that directory
+  (it reads the variable itself); nothing here names one.
+- unset: ``COMPILE_CACHE_DIR``, one fixed git-ignored path inside the
+  checkout.
+
+Either way the two size/time floors are dropped so *everything* is
+cached — this framework's programs are small relative to disk, and the
+ones worth caching most (the serving buckets, the road solver) are
+exactly the ones a floor would skip.
 """
 
 from __future__ import annotations
 
 import os
-from typing import Mapping, Optional
+from typing import Optional
 
-from routest_tpu.utils.paths import secure_user_cache_dir
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache")
 
-_DISABLE = ("0", "off", "false", "no", "none", "disabled")
 
-
-def enable_compile_cache(path: Optional[str] = None,
-                         env: Optional[Mapping[str, str]] = None) -> Optional[str]:
-    """Turn on the persistent compilation cache; returns the directory in
-    use, or None when disabled (``RTPU_COMPILE_CACHE=0`` with no explicit
-    ``path`` / unusable location / jax too old to support it).
-
-    Resolution order: explicit ``path`` arg (wins even over an env
-    opt-out — it is a programmatic decision) > ``RTPU_COMPILE_CACHE``
-    env > per-user default under the system temp dir. Thresholds are set
-    to cache *everything* — this framework's programs are small relative
-    to disk, and the programs worth caching most (the serving buckets,
-    the road solver's while_loop) are exactly the ones a size/time floor
-    would skip.
-    """
-    env = dict(env if env is not None else os.environ)
-    target = path
-    if target is None:
-        raw = env.get("RTPU_COMPILE_CACHE")
-        if raw is not None and raw.strip().lower() in _DISABLE:
-            return None
-        target = raw or secure_user_cache_dir("routest_tpu_xla")
-    if not target:
-        return None
-    try:
-        os.makedirs(target, exist_ok=True)
-    except OSError:
-        return None  # unwritable/planted path: run uncached, don't crash
-    if not os.access(target, os.W_OK):
-        return None
-
+def enable_compile_cache() -> Optional[str]:
+    """Turn on the persistent compilation cache; returns the directory
+    in use, or None when the in-checkout default cannot be created (a
+    read-only checkout runs uncached rather than not at all)."""
     import jax
 
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
     try:
-        # Thresholds FIRST: if this jax predates them, nothing has been
-        # enabled yet and we report disabled truthfully instead of
-        # leaving a half-configured cache behind.
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_compilation_cache_dir", target)
-    except AttributeError:  # ancient jax without the persistent cache
+        os.makedirs(COMPILE_CACHE_DIR, exist_ok=True)
+    except OSError:
         return None
-    return target
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR

@@ -1242,7 +1242,6 @@ KNOWN_KNOBS: Mapping[str, str] = {
     "ROUTEST_FORCE_CPU": "force the CPU backend with N virtual devices",
     "ROUTEST_MESH": "arm the serving device mesh (sharded scoring)",
     "RTPU_CPU_COMPUTE": "compute-dtype policy override on CPU backends",
-    "RTPU_COMPILE_CACHE": "persistent XLA compile-cache directory",
     "RTPU_COORDINATOR": "multi-process coordinator address (host:port)",
     "RTPU_NUM_PROCESSES": "multi-process world size",
     "RTPU_PROCESS_ID": "this process's index in the multi-process world",

@@ -1,21 +1,12 @@
-"""shard_map compatibility shim across jax versions.
-
-jax ≥0.8 promotes ``shard_map`` to the top level (keyword-only, with
-``check_vma``); the ``jax.experimental.shard_map`` spelling (positional,
-``check_rep``) is deprecated. One import site so model code never cares.
-"""
+"""``shard_map`` with this repo's calling convention (positional mesh
+and specs, replication checking off unless asked). One import site so
+model code never spells the keyword-only form."""
 
 from __future__ import annotations
 
-try:  # jax >= 0.8
-    from jax import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
-    def shard_map(f, mesh, in_specs, out_specs, check: bool = False):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=check)
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
 
-    def shard_map(f, mesh, in_specs, out_specs, check: bool = False):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check)
+def shard_map(f, mesh, in_specs, out_specs, check: bool = False):
+    return _shard_map(f, mesh=mesh, in_specs=in_specs,
+                      out_specs=out_specs, check_vma=check)

@@ -89,11 +89,16 @@ from jax.experimental.pallas import tpu as pltpu
 
 from routest_tpu.data.features import N_FEATURES
 
-# jax renamed TPUCompilerParams → CompilerParams across 0.4.x/0.5.x;
-# support both so the kernel (and its tier-1 parity tests) track the
-# installed version instead of pinning one.
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
+# Largest batch tile the kernel accepts. Every intermediate of a tile
+# lives in VMEM (several (tile, 256) f32/bf16 activations plus the
+# lane-padded input and output blocks): for a TPU v5e, 4096 rows compile
+# in the bf16 and int8 variants and 8192 is refused by Mosaic with
+# ``RESOURCE_EXHAUSTED ... memory space vmem``; the f32 variant's
+# multi-pass matmuls need more and stop at 2048
+# (tests/test_tpu_compile.py holds both sides). Checked here so no
+# caller can ask for a tile the compiler refuses.
+MAX_TILE = 4096
+MAX_TILE_F32 = 2048
 
 # Lane layout of the in-kernel expanded feature vector (width = LANES).
 # Chosen so every region starts where VPU masks are cheap; the 32-wide
@@ -259,6 +264,12 @@ def _kernel(n_layers: int, compute, n_q: int, quant: bool,
     )
 
     h = xfull.astype(compute)
+    # The MXU multiplies float32 operands in bfloat16 passes at default
+    # precision, so the f32 variant asks for HIGHEST on every dot — or
+    # its "full-precision" answers carry bf16-class error on the chip
+    # (the interpreter on a CPU never showed this).
+    precision = (jax.lax.Precision.HIGHEST if compute == jnp.float32
+                 else None)
     stride = 3 if quant else 2
     for i in range(n_layers):
         w_ref, b_ref = refs[stride * i], refs[stride * i + 1]
@@ -271,7 +282,8 @@ def _kernel(n_layers: int, compute, n_q: int, quant: bool,
             w = (w_ref[:].astype(jnp.float32) * s_ref[:]).astype(compute)
         else:
             w = w_ref[:]
-        out = jnp.dot(h, w, preferred_element_type=jnp.float32)
+        out = jnp.dot(h, w, preferred_element_type=jnp.float32,
+                      precision=precision)
         out = out + b_ref[:]
         if i < n_layers - 1:
             h = jax.nn.gelu(out).astype(compute)
@@ -294,8 +306,10 @@ def _kernel(n_layers: int, compute, n_q: int, quant: bool,
         over_m = ((row - n_q <= col) & (row >= n_q)
                   & (row < 2 * n_q)).astype(jnp.float32)
         sp = jax.nn.softplus(out)
-        pace = jnp.dot(sp, pace_m, preferred_element_type=jnp.float32)
-        overhead = jnp.dot(sp, over_m, preferred_element_type=jnp.float32)
+        pace = jnp.dot(sp, pace_m, preferred_element_type=jnp.float32,
+                       precision=precision)
+        overhead = jnp.dot(sp, over_m, preferred_element_type=jnp.float32,
+                           precision=precision)
         out_ref[:] = pace * dist + overhead
 
 
@@ -316,6 +330,12 @@ def fused_eta_forward(packed: Packed, x: jax.Array, *, n_q: int = 0,
     # int8 variant: dequantized matmuls run in bf16 (MXU-native);
     # otherwise the packed weight dtype IS the compute dtype.
     compute = jnp.bfloat16 if quant else ws[0].dtype
+    max_tile = MAX_TILE_F32 if compute == jnp.float32 else MAX_TILE
+    if not 0 < tile <= max_tile or tile % 8:
+        raise ValueError(
+            f"fused_eta_forward: tile={tile} must be a multiple of 8 in "
+            f"[8, {max_tile}] for {jnp.dtype(compute).name} compute "
+            f"(larger tiles do not fit VMEM)")
     n_layers = len(ws)
     b_rows = x.shape[0]
     if b_rows == 0:
@@ -371,7 +391,7 @@ def fused_eta_forward(packed: Packed, x: jax.Array, *, n_q: int = 0,
         out_specs=pl.BlockSpec((tile, n_out), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((b_pad, n_out), jnp.float32),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         cost_estimate=pl.CostEstimate(
             flops=flops, bytes_accessed=bytes_accessed,

@@ -1519,8 +1519,8 @@ def _prometheus_text(snapshot: dict) -> str:
 def _device_memory(jax) -> dict:
     """Per-device HBM residency gauge (SURVEY.md §5.5 — "HBM residency"
     is one of the TPU gauges the health contract promises). CPU backends
-    and tunnel transports may not implement memory_stats(); report what
-    exists, never fail health over a gauge."""
+    do not implement memory_stats(); report what exists, never fail
+    health over a gauge."""
     out = {}
     try:
         for d in jax.local_devices():
@@ -1536,7 +1536,7 @@ def _device_memory(jax) -> dict:
             out[str(d)] = entry
     except Exception as e:
         # Gauge-only: health must never fail over missing memory stats
-        # (CPU backends, tunnel transports) — but the miss is loggable.
+        # (CPU backends) — but the miss is loggable.
         _log.debug("device_memory_unavailable",
                    error=f"{type(e).__name__}: {e}")
     return out
@@ -1547,25 +1547,24 @@ _roofline_cache: dict = {"mtime": None, "value": None}
 
 def _tpu_roofline(jax) -> dict:
     """Chip identity + peak table + the last recorded bench roofline
-    (achieved TFLOP/s, MFU, HBM GB/s — VERDICT r3 weak #7: these gauges
-    must be readable from the serving surface, not reconstructed by a
-    reviewer). The bench artifact is the measurement of record; health
+    (achieved TFLOP/s, MFU, HBM GB/s: these gauges must be readable from
+    the serving surface, not reconstructed by a reviewer). The bench artifact is the measurement of record; health
     only surfaces it, never re-runs it — and caches the parse on the
     file's mtime, because orchestrators poll health every few seconds
     while the artifact changes once per bench run."""
-    out: dict = {}
-    try:
-        from bench import chip_peaks  # repo-root bench owns the peak table
+    device = jax.devices()[0]
+    out: dict = {"device_kind": device.device_kind}
+    if device.platform != "cpu":
+        # An accelerator the peak table does not know is reported, not
+        # omitted: health stays up and says which row is missing.
+        try:
+            from bench import chip_peaks  # repo-root bench owns the table
 
-        kind = str(getattr(jax.devices()[0], "device_kind", ""))
-        peak_tflops, peak_hbm = chip_peaks(kind)
-        out["device_kind"] = kind
-        if peak_tflops is not None:
-            out["peak_tflops_bf16"] = peak_tflops
-            out["peak_hbm_gbps"] = peak_hbm
-    except Exception as e:
-        _log.debug("chip_peaks_unavailable",
-                   error=f"{type(e).__name__}: {e}")
+            out["peak_tflops_bf16"], out["peak_hbm_gbps"] = chip_peaks(
+                device.device_kind)
+        except (ImportError, ValueError) as e:
+            out["peaks_error"] = f"{type(e).__name__}: {e}"
+            _log.warning("chip_peaks_unavailable", error=out["peaks_error"])
     try:
         import json as _json
 

@@ -1554,8 +1554,15 @@ class Gateway:
 
             do_GET = do_POST = do_DELETE = do_PUT = do_OPTIONS = _handle
 
-        httpd = http.server.ThreadingHTTPServer((host, port), Handler)
-        httpd.daemon_threads = True
+        class Server(http.server.ThreadingHTTPServer):
+            # The replicas' listen backlog (werkzeug's 128), not the
+            # stdlib's 5: a burst of new connections overflowed it, and
+            # the kernel then drops or resets the excess (seconds of
+            # SYN-retry latency at 48 simultaneous connects).
+            request_queue_size = 128
+            daemon_threads = True
+
+        httpd = Server((host, port), Handler)
         self._httpd = httpd
         if self.slo is not None and self.slo.config.tick_s > 0:
             self.slo.start()  # burn-rate ticker lives with the listener

@@ -10,9 +10,10 @@ from "what does this host have" to "what do we boot":
 - :func:`detect_inventory` — how many chips, on what platform. The
   operator override (``RTPU_FLEET_CHIPS``) wins; a forced-CPU virtual
   device count (``XLA_FLAGS --xla_force_host_platform_device_count``)
-  is honored next so placement shape is testable before hardware shows
-  up; otherwise JAX is asked (lazily — hermetic callers never pay the
-  import).
+  is honored next so placement shape is testable without hardware;
+  otherwise JAX is asked in a short-lived child process — never in the
+  caller, because a chip belongs to one process at a time and the fleet
+  parent must leave every chip free for the replicas it spawns.
 - :func:`candidate_layouts` — the ways ``chips`` devices can be carved
   into replica slices (8 → 8×1, 4×2, 2×4, 1×8; odd counts get a mixed
   remainder slice: 6 → …, 4+2; every chip is owned by exactly one
@@ -44,6 +45,8 @@ import dataclasses
 import json
 import os
 import re
+import subprocess
+import sys
 from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 
 from routest_tpu.utils.logging import get_logger
@@ -70,11 +73,30 @@ class DeviceInventory:
     source: str
 
 
+_DEVICE_QUERY = ("import jax; d = jax.devices(); "
+                 "print(d[0].platform, len(d))")
+
+
+def _query_devices(env: Mapping[str, str]) -> Tuple[str, int]:
+    """(platform, device count) as JAX reports them in a child process
+    that exits — and so releases the chips — before this returns."""
+    proc = subprocess.run([sys.executable, "-c", _DEVICE_QUERY],
+                          env=dict(env), capture_output=True, text=True,
+                          timeout=300)
+    if proc.returncode != 0:
+        raise RuntimeError(
+            f"device detection failed (rc={proc.returncode}): "
+            f"{proc.stderr.strip()[-500:]}")
+    platform, count = proc.stdout.split()[-2:]
+    return platform, int(count)
+
+
 def detect_inventory(
         env: Optional[Mapping[str, str]] = None) -> DeviceInventory:
-    """Enumerate local devices WITHOUT importing JAX when an env
-    override answers first (the fleet parent and hermetic tests must
-    not pay a JAX import to plan a placement)."""
+    """Enumerate local devices WITHOUT importing JAX in this process:
+    env overrides answer first, else a child process asks JAX. A failed
+    detection raises — planning "1 chip, cpu" on a host whose chips
+    could not be seen would boot replicas on the wrong device."""
     env = env if env is not None else os.environ
     raw = env.get("RTPU_FLEET_CHIPS")
     if raw:
@@ -92,15 +114,8 @@ def detect_inventory(
         if m:
             return DeviceInventory("cpu", int(m.group(1)), "xla_flags")
         return DeviceInventory("cpu", 1, "default")
-    try:
-        import jax
-
-        return DeviceInventory(jax.default_backend(), len(jax.devices()),
-                               "jax")
-    except Exception as e:  # no backend at all: plan a 1-chip host
-        _log.warning("device_detect_failed",
-                     error=f"{type(e).__name__}: {e}")
-        return DeviceInventory("cpu", 1, "default")
+    platform, chips = _query_devices(env)
+    return DeviceInventory(platform, chips, "jax")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -176,14 +191,26 @@ def candidate_layouts(chips: int) -> List[Tuple[int, ...]]:
     return out
 
 
+# x,y,z extent of the chips one masked process owns, by slice width —
+# what ran on a v5e 2x2 host (PR 21): four one-chip processes at once,
+# and two two-chip processes over the consecutive ids (0,1) and (2,3)
+# that ``plan_placement`` hands out ("2,1,1" and the pairs (0,2), (1,3)
+# were refused). Other widths get the mask alone until a chip run shows
+# their bounds; a slice that is the whole host needs none.
+_TPU_CHIP_BOUNDS = {1: "1,1,1", 2: "1,2,1"}
+
+
 def slice_env(platform: str, chips: int, device_ids: Sequence[int],
               label: str) -> Dict[str, str]:
     """The per-replica env overlay that makes a worker own exactly its
     slice. CPU slices get a virtual device count (the shape-pinning
     path: ``XLA_FLAGS --xla_force_host_platform_device_count``); GPU
     slices mask with ``CUDA_VISIBLE_DEVICES``; TPU slices mask with
-    ``TPU_VISIBLE_DEVICES`` (+ the chips count for the mesh). Multi-
-    chip slices force the serving mesh on (``ROUTEST_MESH=1``) with
+    ``TPU_VISIBLE_CHIPS`` and tell libtpu that this process is a whole
+    topology of its own (process bounds 1,1,1 and the slice's chip
+    bounds) — with the mask alone, every process after the first fails
+    on libtpu's multi-process lockfile. Multi-chip slices
+    force the serving mesh on (``ROUTEST_MESH=1``) with
     ``RTPU_MESH_DATA`` = the slice width so the batch shards over
     exactly the owned devices."""
     ids = ",".join(str(i) for i in device_ids)
@@ -195,8 +222,12 @@ def slice_env(platform: str, chips: int, device_ids: Sequence[int],
         env["ROUTEST_FORCE_CPU"] = "1"
     elif platform == "gpu":
         env["CUDA_VISIBLE_DEVICES"] = ids
-    else:  # tpu and tpu-like backends
-        env["TPU_VISIBLE_DEVICES"] = ids
+    else:  # tpu
+        env["TPU_VISIBLE_CHIPS"] = ids
+        bounds = _TPU_CHIP_BOUNDS.get(chips)
+        if bounds:
+            env["TPU_CHIPS_PER_PROCESS_BOUNDS"] = bounds
+            env["TPU_PROCESS_BOUNDS"] = "1,1,1"
     env["RTPU_MESH_DATA"] = str(chips)
     env["ROUTEST_MESH"] = "1" if chips > 1 else "0"
     return env
@@ -309,6 +340,12 @@ def plan_placement(inventory: DeviceInventory, *,
 
     def build(layout: Tuple[int, ...], source: str,
               rate_fn) -> PlacementPlan:
+        if platform != "cpu" and sum(layout) > chips:
+            # A chip belongs to one process: a second replica pinned to
+            # an owned chip cannot start. Refuse the plan instead.
+            raise ValueError(
+                f"placement {layout} needs {sum(layout)} {platform} "
+                f"chips; host has {chips}")
         slices: List[ReplicaSlice] = []
         next_id = 0
         base_rate = rate_fn(1)

@@ -618,7 +618,20 @@ class DynamicBatcher:
                         # flush also records the device trace that
                         # explains it (one trace id across both).
                         with maybe_device_trace(ds):
-                            preds = np.asarray(self._score(padded))[:n]
+                            out = self._score(padded)
+                            preds = np.asarray(out)[:n]
+                        # Where the flush actually ran (health's
+                        # ``checks.tpu.batcher.last_flush``): a mesh
+                        # replica must show the bucket split over its
+                        # whole slice, not resident on one device.
+                        sharding = getattr(out, "sharding", None)
+                        if sharding is not None:
+                            self.stats["last_flush"] = {
+                                "bucket": bucket,
+                                "devices": sorted(
+                                    d.id for d in sharding.device_set),
+                                "rows_per_device": int(
+                                    sharding.shard_shape(out.shape)[0])}
                         if skew:
                             preds = preds + skew
                     if batch_slab is not None and \
@@ -1055,9 +1068,10 @@ class EtaService:
         kernel chain) and XLA everywhere else — the per-size winner
         table is ``artifacts/kernel_bench.json``, re-measured by
         ``scripts/bench_serving_kernel.py``. Probed eagerly with one
-        row: any pack/compile failure (non-TPU backend, unexpected
-        param shapes, Mosaic regressions) keeps the XLA path — the
-        kernel is an optimization, never a dependency.
+        row: in AUTO mode any pack/compile failure (unexpected param
+        shapes, Mosaic regressions) keeps the XLA path with a
+        ``fused_kernel_unavailable`` warning; a kernel FORCED on a TPU
+        that fails raises instead.
         """
         mode = os.environ.get("ROUTEST_FUSED", "auto")
         if mode == "0":
@@ -1116,6 +1130,10 @@ class EtaService:
             self.kernel_dtype = variant
             return score
         except Exception as e:  # pragma: no cover - depends on backend
+            if mode == "1":
+                # The operator forced the kernel on a TPU: serving XLA
+                # under that setting would hide the failure.
+                raise
             from routest_tpu.utils.logging import get_logger
 
             get_logger("routest_tpu.serve").warning(
@@ -1379,11 +1397,20 @@ class EtaService:
         and the placement slice label the supervisor stamped."""
         import jax
 
+        devices = jax.devices()
         info: dict = {
-            "devices": len(jax.devices()),
+            "devices": len(devices),
+            "device_ids": [d.id for d in devices],
+            "device_kind": devices[0].device_kind,
             "platform": jax.default_backend(),
             "sharded": self._runtime is not None,
         }
+        # The chip mask the placement overlay set, as this process sees
+        # it: masked processes each number their chips from 0, so the
+        # mask is what tells one-chip replicas of a host apart.
+        visible = os.environ.get("TPU_VISIBLE_CHIPS")
+        if visible is not None:
+            info["visible_chips"] = visible
         label = os.environ.get("RTPU_FLEET_PLACEMENT_LABEL")
         if label:
             info["placement"] = label

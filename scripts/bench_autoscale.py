@@ -61,12 +61,12 @@ def _get_json(base, path, timeout=15.0):
         return {}
 
 
-def boot_fleet(args, autoscale: bool, cache_dir: str, recorder_dir: str,
+def boot_fleet(args, autoscale: bool, recorder_dir: str,
                queue_depth: int = 32):
     """→ (supervisor, gateway, autoscaler-or-None, base_url). One real
-    serving worker to start; the autoscaler grows it. Replicas share an
-    XLA compile cache so scaled-up workers reuse the first boot's
-    compilations (elastic boots must not pay full compile)."""
+    serving worker to start; the autoscaler grows it. Replicas share
+    the one XLA compile cache (``core/cache.py``) so scaled-up workers
+    reuse the first boot's compilations."""
     from routest_tpu.core.config import (AutoscaleConfig, FleetConfig,
                                          RecorderConfig)
     from routest_tpu.obs.recorder import FlightRecorder, configure_recorder
@@ -87,8 +87,7 @@ def boot_fleet(args, autoscale: bool, cache_dir: str, recorder_dir: str,
         "REDIS_URL": f"tcp://127.0.0.1:{broker.port}",
         "ROUTEST_FORCE_CPU": "1",
         "ROUTEST_MESH": "0",
-        "ROUTEST_WARM_BUCKETS": "0",   # elastic boots: compile lazily …
-        "RTPU_COMPILE_CACHE": cache_dir,   # … and share the XLA cache
+        "ROUTEST_WARM_BUCKETS": "0",   # elastic boots: compile lazily
         "ETA_MODEL_PATH": MODEL,
         "RTPU_RECORDER_DIR": os.path.join(recorder_dir, "workers"),
         "RTPU_RECORDER_MIN_INTERVAL_S": "0",
@@ -223,10 +222,8 @@ def scenario_flash_crowd(args) -> dict:
                                      fetch_metrics, poisson_schedule,
                                      run_open_loop, summarize, timeline)
 
-    cache_dir = tempfile.mkdtemp(prefix="autoscale-xla-")
     recorder_dir = tempfile.mkdtemp(prefix="autoscale-pm-")
     sup, gw, scaler, base = boot_fleet(args, autoscale=True,
-                                       cache_dir=cache_dir,
                                        recorder_dir=recorder_dir)
     try:
         workload = ZipfODWorkload(s=args.zipf_s, seed=args.seed)
@@ -323,7 +320,6 @@ def scenario_flash_crowd(args) -> dict:
         return out
     finally:
         shutdown_fleet(sup, gw, scaler)
-        shutil.rmtree(cache_dir, ignore_errors=True)
         shutil.rmtree(recorder_dir, ignore_errors=True)
 
 
@@ -337,10 +333,8 @@ def scenario_diurnal(args) -> dict:
                                      poisson_schedule, run_open_loop,
                                      summarize, timeline)
 
-    cache_dir = tempfile.mkdtemp(prefix="autoscale-xla-")
     recorder_dir = tempfile.mkdtemp(prefix="autoscale-pm-")
     sup, gw, scaler, base = boot_fleet(args, autoscale=True,
-                                       cache_dir=cache_dir,
                                        recorder_dir=recorder_dir)
     try:
         workload = MixedWorkload(
@@ -405,7 +399,6 @@ def scenario_diurnal(args) -> dict:
         return out
     finally:
         shutdown_fleet(sup, gw, scaler)
-        shutil.rmtree(cache_dir, ignore_errors=True)
         shutil.rmtree(recorder_dir, ignore_errors=True)
 
 
@@ -420,13 +413,11 @@ def scenario_closed_vs_open(args) -> dict:
                                      paced_schedule, run_closed_loop,
                                      run_open_loop, summarize)
 
-    cache_dir = tempfile.mkdtemp(prefix="autoscale-xla-")
     recorder_dir = tempfile.mkdtemp(prefix="autoscale-pm-")
     # Deep admission queue: THIS scenario wants the overload to QUEUE
     # (the backlog is what closed-loop accounting hides); the autoscale
     # scenarios keep the shallow production-shaped queue and shed.
     sup, gw, scaler, base = boot_fleet(args, autoscale=False,
-                                       cache_dir=cache_dir,
                                        recorder_dir=recorder_dir,
                                        queue_depth=512)
     try:
@@ -467,7 +458,6 @@ def scenario_closed_vs_open(args) -> dict:
         }
     finally:
         shutdown_fleet(sup, gw, scaler)
-        shutil.rmtree(cache_dir, ignore_errors=True)
         shutil.rmtree(recorder_dir, ignore_errors=True)
 
 

@@ -389,12 +389,11 @@ def main() -> None:
     lt = _load_load_test()
     cores = len(os.sched_getaffinity(0)) \
         if hasattr(os, "sched_getaffinity") else (os.cpu_count() or 1)
-    try:
-        import jax
-
-        backend = jax.default_backend()
-    except Exception:
-        backend = "cpu"
+    # Every replica is booted with a forced-CPU slice overlay
+    # (boot_layout), so the record's backend is the CPU whatever this
+    # host holds — and the parent never initialises a JAX backend, which
+    # on a chip host would take a chip from the processes it spawns.
+    backend = "cpu"
 
     curve, oracle = run_curve(args.chips, lt, args, cores)
     max_chips = max(args.chips)

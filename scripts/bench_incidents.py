@@ -595,8 +595,6 @@ def main() -> None:
     parser.add_argument("--seed", type=int, default=42)
     parser.add_argument("--scenarios", nargs="*", default=None,
                         choices=sorted(SCENARIOS))
-    parser.add_argument("--cache-dir", default=os.path.join(
-        REPO, "artifacts", "bench_cache", "incidents"))
     parser.add_argument("--out", default=os.path.join(
         REPO, "artifacts", "incidents.json"))
     args = parser.parse_args()
@@ -604,10 +602,9 @@ def main() -> None:
     args.clean_flips = 20 if args.quick else 30
 
     os.environ.setdefault("ROUTEST_FORCE_CPU", "1")
-    os.makedirs(args.cache_dir, exist_ok=True)
     from routest_tpu.core.cache import enable_compile_cache
 
-    enable_compile_cache(os.path.join(args.cache_dir, "xla"))
+    enable_compile_cache()
     from routest_tpu.utils.logging import get_logger
 
     log = get_logger("routest_tpu.bench_incidents")

@@ -150,12 +150,11 @@ def main() -> None:
 
     work_dir = tempfile.mkdtemp(prefix="live-traffic-")
     hier_cache = os.path.join(work_dir, "hier")
-    xla_cache = os.path.join(work_dir, "xla")
     gnn_path = os.path.join(work_dir, "road_gnn_live.msgpack")
     os.environ["ROUTEST_HIER_CACHE"] = hier_cache
     os.environ["RTPU_RECORDER_DIR"] = os.path.join(work_dir,
                                                    "postmortems")
-    enable_compile_cache(xla_cache)
+    enable_compile_cache()
     channel = "rtpu.probes"
     slo_spec = (f"/api/request_route:latency_ms={args.slo_ms:.0f},"
                 f"latency_target=0.9,availability=0.99;"
@@ -209,7 +208,6 @@ def main() -> None:
     env.update({
         "ROAD_GRAPH_OSM": extract,
         "ROUTEST_HIER_CACHE": hier_cache,
-        "RTPU_COMPILE_CACHE": xla_cache,
         "ROUTEST_MESH": "0",
         "ROUTEST_WARM_BUCKETS": "0",
         "ETA_MODEL_PATH": MODEL,

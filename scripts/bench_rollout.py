@@ -179,10 +179,11 @@ class LoadArm:
             len(self.records))
 
 
-def boot_fleet(args, n: int, cache_dir: str, recorder_dir: str,
+def boot_fleet(args, n: int, recorder_dir: str,
                model_path: str, reload_sec: float = 0.0):
     """→ (supervisor, gateway, base_url). ``n`` real serving workers on
-    version ``v1``; shared XLA cache so replacement boots are cheap."""
+    version ``v1``; they share the one XLA cache (``core/cache.py``) so
+    replacement boots are cheap."""
     from routest_tpu.core.config import FleetConfig, RecorderConfig
     from routest_tpu.obs.recorder import FlightRecorder, configure_recorder
     from routest_tpu.serve.fleet.gateway import Gateway
@@ -195,7 +196,6 @@ def boot_fleet(args, n: int, cache_dir: str, recorder_dir: str,
         "ROUTEST_FORCE_CPU": "1",
         "ROUTEST_MESH": "0",
         "ROUTEST_WARM_BUCKETS": "0",
-        "RTPU_COMPILE_CACHE": cache_dir,
         "ETA_MODEL_PATH": model_path,
         "RTPU_VERSION": "v1",
         "RTPU_RECORDER_DIR": os.path.join(recorder_dir, "workers"),
@@ -277,12 +277,10 @@ def _swap_counts(base: str) -> dict:
 # ── scenario: verified hot-swap under load ───────────────────────────
 
 def scenario_hot_swap(args, forge: ModelForge) -> dict:
-    cache_dir = tempfile.mkdtemp(prefix="rollout-xla-")
     recorder_dir = tempfile.mkdtemp(prefix="rollout-pm-")
     live_path = os.path.join(forge.workdir, "live.msgpack")
     shutil.copyfile(BASE_MODEL, live_path)
-    sup, gw, base = boot_fleet(args, n=1, cache_dir=cache_dir,
-                               recorder_dir=recorder_dir,
+    sup, gw, base = boot_fleet(args, n=1, recorder_dir=recorder_dir,
                                model_path=live_path,
                                reload_sec=args.reload_sec)
     try:
@@ -369,7 +367,6 @@ def scenario_hot_swap(args, forge: ModelForge) -> dict:
         return out
     finally:
         shutdown_fleet(sup, gw)
-        shutil.rmtree(cache_dir, ignore_errors=True)
         shutil.rmtree(recorder_dir, ignore_errors=True)
 
 
@@ -383,12 +380,10 @@ def _rollout_scenario(args, forge: ModelForge, *, version: str,
     from routest_tpu.core.config import RolloutConfig
     from routest_tpu.serve.fleet.rollout import RolloutController
 
-    cache_dir = tempfile.mkdtemp(prefix="rollout-xla-")
     recorder_dir = tempfile.mkdtemp(prefix="rollout-pm-")
     live_path = os.path.join(forge.workdir, f"base_{version}.msgpack")
     shutil.copyfile(BASE_MODEL, live_path)
-    sup, gw, base = boot_fleet(args, n=2, cache_dir=cache_dir,
-                               recorder_dir=recorder_dir,
+    sup, gw, base = boot_fleet(args, n=2, recorder_dir=recorder_dir,
                                model_path=live_path)
     if chaos_spec:
         chaos.configure(chaos.ChaosEngine(spec=chaos_spec,
@@ -462,7 +457,6 @@ def _rollout_scenario(args, forge: ModelForge, *, version: str,
 
             _chaos.configure(None)
         shutdown_fleet(sup, gw)
-        shutil.rmtree(cache_dir, ignore_errors=True)
         shutil.rmtree(recorder_dir, ignore_errors=True)
 
 

@@ -182,13 +182,12 @@ def main() -> None:
 
     work_dir = tempfile.mkdtemp(prefix="router-serving-")
     hier_cache = os.path.join(work_dir, "hier")
-    xla_cache = os.path.join(work_dir, "xla")
     os.environ["ROUTEST_HIER_CACHE"] = hier_cache
     # Postmortem bundles from warm-phase SLO edges (the first road
     # request pays the router build) belong to the run dir, not the
     # repo's artifacts/.
     os.environ["RTPU_RECORDER_DIR"] = os.path.join(work_dir, "postmortems")
-    enable_compile_cache(xla_cache)
+    enable_compile_cache()
     slo_spec = (f"/api/request_route:latency_ms={args.slo_ms:.0f},"
                 f"latency_target=0.95,availability=0.99;"
                 f"/api/predict_eta:latency_ms=1000,latency_target=0.95,"
@@ -203,7 +202,6 @@ def main() -> None:
     env.update({
         "ROAD_GRAPH_OSM": extract,
         "ROUTEST_HIER_CACHE": hier_cache,
-        "RTPU_COMPILE_CACHE": xla_cache,
         "ROUTEST_MESH": "0",
         "ROUTEST_WARM_BUCKETS": "0",
         "ETA_MODEL_PATH": MODEL,

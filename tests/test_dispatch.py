@@ -716,20 +716,3 @@ def test_dispatch_bench_quick(tmp_path):
     assert jam["checks"]["user_slo_ok"], jam
     fault = record["scenarios"]["wrong_plan_fault"]
     assert fault["checks"]["dispatch_probe_paged"], fault
-
-
-@pytest.mark.slow
-def test_committed_dispatch_artifact_passes():
-    record = json.load(open(os.path.join(REPO, "artifacts",
-                                         "dispatch.json")))
-    assert record["all_pass"], record["checks"]
-    rows = record["batch_scaling"]["rows"]
-    assert len(rows) >= 3
-    assert all(r["oracle_parity"] for r in rows)
-    # Scaling direction: merged batches beat batch=1 on solves/s.
-    assert rows[-1]["solves_per_s"] > rows[0]["solves_per_s"]
-    jam = record["scenarios"]["corridor_jam"]
-    assert jam["checks"]["exactly_the_affected"]
-    assert jam["checks"]["plan_update_within_bound"]
-    assert record["scenarios"]["wrong_plan_fault"]["checks"][
-        "dispatch_probe_paged"]

@@ -145,7 +145,17 @@ def test_slice_env_pins_per_platform():
     assert "--xla_force_host_platform_device_count=4" in cpu["XLA_FLAGS"]
     assert cpu["ROUTEST_MESH"] == "1" and cpu["RTPU_MESH_DATA"] == "4"
     tpu = slice_env("tpu", 2, (4, 5), "s1:2chip")
-    assert tpu["TPU_VISIBLE_DEVICES"] == "4,5"
+    assert tpu["TPU_VISIBLE_CHIPS"] == "4,5"
+    assert tpu["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,2,1"
+    # one-chip TPU slices: the mask alone lets only the first process
+    # start; each is also told it is a whole 1x1x1 topology
+    one = [slice_env("tpu", 1, (i,), f"s{i}:1chip") for i in range(4)]
+    assert all(e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+               and e["TPU_PROCESS_BOUNDS"] == "1,1,1" for e in one)
+    assert [e["TPU_VISIBLE_CHIPS"] for e in one] == ["0", "1", "2", "3"]
+    # a width no chip run has shown bounds for: the mask, nothing guessed
+    assert "TPU_CHIPS_PER_PROCESS_BOUNDS" not in slice_env(
+        "tpu", 8, tuple(range(8)), "s0:8chip")
     gpu = slice_env("gpu", 1, (3,), "s2:1chip")
     assert gpu["CUDA_VISIBLE_DEVICES"] == "3"
     assert gpu["ROUTEST_MESH"] == "0"
@@ -162,6 +172,56 @@ def test_detect_inventory_env_layers():
     inv2 = detect_inventory({"RTPU_FLEET_CHIPS": "lots",
                              "ROUTEST_FORCE_CPU": "1"})
     assert inv2.chips == 1 and inv2.platform == "cpu"
+
+
+def test_detect_inventory_asks_jax_in_a_child_not_in_the_caller():
+    """A chip belongs to one process: the fleet parent that enumerated
+    devices itself would hold the chips its replicas need. Run in a
+    fresh interpreter so this suite's own backend does not count."""
+    import os
+    import subprocess
+    import textwrap
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    script = textwrap.dedent("""
+        import os
+        from jax._src import xla_bridge
+        from routest_tpu.serve.fleet import placement
+        platform, chips = placement._query_devices(os.environ)
+        assert not xla_bridge.backends_are_initialized()
+        print(platform, chips)
+    """)
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=3")
+    proc = subprocess.run([sys.executable, "-c", script], env=env, cwd=repo,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert proc.stdout.split() == ["cpu", "3"]
+
+
+def test_detect_inventory_failure_is_an_error_not_a_one_chip_cpu_plan(
+        monkeypatch):
+    from routest_tpu.serve.fleet import placement
+
+    def boom(env):
+        raise RuntimeError("device detection failed (rc=1): no backend")
+
+    monkeypatch.setattr(placement, "_query_devices", boom)
+    with pytest.raises(RuntimeError, match="device detection failed"):
+        detect_inventory({})      # no override answers: JAX is asked
+    monkeypatch.setattr(placement, "_query_devices", lambda env: ("tpu", 4))
+    inv = detect_inventory({})
+    assert (inv.platform, inv.chips, inv.source) == ("tpu", 4, "jax")
+
+
+def test_accelerator_plan_refuses_to_stack_replicas_on_one_chip():
+    with pytest.raises(ValueError, match="needs 4 tpu chips; host has 1"):
+        plan_placement(DeviceInventory("tpu", 1, "jax"), replicas=4,
+                       spec="replica", record_path="")
+    # virtual CPU devices time-share one host: still allowed there
+    plan = plan_placement(DeviceInventory("cpu", 1, "default"), replicas=4,
+                          spec="replica", record_path="")
+    assert len(plan.slices) == 4
 
 
 def test_growth_slice_repeats_the_plan_unit():
@@ -290,7 +350,7 @@ _STUB_WORKER = """
 import http.server, json, os
 LABEL = os.environ.get("RTPU_FLEET_PLACEMENT_LABEL")
 CHIPS = int(os.environ.get("RTPU_FLEET_SLICE_CHIPS") or 1)
-VISIBLE = os.environ.get("TPU_VISIBLE_DEVICES")
+VISIBLE = os.environ.get("TPU_VISIBLE_CHIPS")
 VERSION = os.environ.get("RTPU_VERSION") or None
 class H(http.server.BaseHTTPRequestHandler):
     protocol_version = "HTTP/1.1"

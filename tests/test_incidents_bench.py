@@ -51,8 +51,7 @@ def test_incidents_quick(tmp_path):
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "scripts",
                                       "bench_incidents.py"),
-         "--quick", "--out", str(out),
-         "--cache-dir", str(tmp_path / "cache")],
+         "--quick", "--out", str(out)],
         cwd=REPO, timeout=900, capture_output=True, text=True)
     assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-2000:]
     _assert_record_shape(json.loads(out.read_text()))

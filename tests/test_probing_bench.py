@@ -52,21 +52,3 @@ def test_probing_quick(tmp_path):
     assert clean["checks"]["probe_traffic_excluded"], clean["exclusion"]
     assert clean["checks"]["strict_oracle_parity"], clean["strict_oracle"]
     assert clean["checks"]["overhead_within_budget"], clean["overhead"]
-
-
-@pytest.mark.slow
-def test_committed_probing_artifact_passes():
-    """The committed measurement of record must itself satisfy the
-    acceptance bar."""
-    record = json.load(open(os.path.join(REPO, "artifacts",
-                                         "probing.json")))
-    assert record["all_pass"], record["checks"]
-    assert len(record["scenarios"]) == 4
-    for name in ("compute_divergence", "stale_epoch",
-                 "divergent_model"):
-        s = record["scenarios"][name]
-        assert s["checks"]["bundle_names_faulty_replica"], s
-    clean = record["scenarios"]["clean"]
-    assert clean["swaps_accepted"] >= 1 and clean["metric_flips"] >= 1
-    assert clean["exclusion"]["probe_family_count"] > 0
-    assert not clean["exclusion"]["leaked_user_counts"]

@@ -1,0 +1,99 @@
+"""The main path's device programs, compiled for a TPU v5e that is
+described and not attached (``jax.experimental.topologies``): what the
+chip's compiler refuses fails here, on the CPU, at no chip time.
+
+Interpret mode cannot show these: a Pallas tile that does not fit VMEM,
+a slice not aligned to the tiling, a program that does not fit HBM. A
+compile that passes is not a chip run — ``chip_smoke.py`` is that.
+Skipped where the TPU compiler cannot describe the topology.
+"""
+
+import functools
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or it logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from routest_tpu.models.eta_mlp import EtaMLP
+from routest_tpu.ops import fused_eta_forward, pack_eta_params
+from routest_tpu.ops.fused_mlp import MAX_TILE, MAX_TILE_F32
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One described v5e chip as a sharding. The persistent compile
+    cache is off while this module runs: an entry compiled for a
+    described chip cannot be read back without one, and the next
+    compile would warn instead of staying silent."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _on(sharding, tree):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+@functools.lru_cache(maxsize=None)
+def _model(n_q: int):
+    """The shipped width (13->256->256->128), seeded weights."""
+    model = EtaMLP(quantiles=(0.1, 0.5, 0.9) if n_q else ())
+    return model, model.init(jax.random.PRNGKey(0))
+
+
+# The serving buckets' largest (4,096) and bench.py's offline batch. The
+# f32 variant's HIGHEST-precision matmuls take three times as long to
+# compile, so it is held to the larger batch: the per-tile program is
+# the same at both, only the grid differs.
+@pytest.mark.parametrize("dtype,n_q,batch", [
+    (dtype, n_q, batch)
+    for dtype in ("bf16", "int8", "f32") for n_q in (0, 3)
+    for batch in (4096, 131072) if dtype != "f32" or batch == 131072])
+def test_fused_kernel_compiles_for_v5e(v5e, dtype, n_q, batch):
+    model, params = _model(n_q)
+    packed = _on(v5e, pack_eta_params(model, params, dtype=dtype))
+    x = jax.ShapeDtypeStruct((batch, 12), jnp.float32, sharding=v5e)
+    compiled = fused_eta_forward.lower(packed, x, n_q=n_q,
+                                       tile=2048).compile()
+    assert "tpu_custom_call" in compiled.as_text()   # Mosaic, not interpret
+
+
+def test_xla_scorer_compiles_for_v5e(v5e):
+    """The 4,096-row serving bucket as ``EtaService._aot_score`` lowers
+    it: jit with the input slab donated."""
+    model, params = _model(3)
+    x = jax.ShapeDtypeStruct((4096, 12), np.float32, sharding=v5e)
+    compiled = jax.jit(model.apply_quantiles, donate_argnums=(1,)).lower(
+        _on(v5e, params), x).compile()
+    assert compiled.memory_analysis().output_size_in_bytes > 0
+
+
+def test_oversized_tile_is_refused_by_the_kernel_not_by_mosaic(v5e):
+    """8192-row tiles exhaust v5e VMEM (4096-row ones in f32); the
+    kernel's own bound names the limit before the compiler is asked."""
+    model, params = _model(3)
+    packed = _on(v5e, pack_eta_params(model, params))
+    x = jax.ShapeDtypeStruct((131072, 12), jnp.float32, sharding=v5e)
+    with pytest.raises(ValueError, match=f"{MAX_TILE}"):
+        fused_eta_forward.lower(packed, x, n_q=3, tile=2 * MAX_TILE)
+    packed_f32 = _on(v5e, pack_eta_params(model, params, dtype="f32"))
+    with pytest.raises(ValueError, match=f"{MAX_TILE_F32}"):
+        fused_eta_forward.lower(packed_f32, x, n_q=3, tile=MAX_TILE)
