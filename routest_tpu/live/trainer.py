@@ -21,9 +21,11 @@ cycle track a drifting world instead of re-learning it.
 
 from __future__ import annotations
 
+import contextlib
+import os
 import threading
 import time
-from typing import Dict, Optional
+from typing import Dict, Iterator, Optional, Tuple
 
 import numpy as np
 
@@ -46,8 +48,30 @@ def _trainer_metrics():
             "dur": reg.histogram(
                 "rtpu_live_retrain_seconds",
                 "One retrain cycle: window build + steps + save."),
+            "phase": reg.histogram(
+                "rtpu_live_retrain_phase_seconds",
+                "One phase of a retrain cycle (aggregate / upload / "
+                "steps / apply / save): its span's own duration.",
+                ("phase",)),
         }
     return _metrics
+
+
+@contextlib.contextmanager
+def _phase(phase: str, **attrs) -> Iterator:
+    """One child span of ``live.retrain``, its duration observed into
+    ``rtpu_live_retrain_phase_seconds{phase}`` as well, so that
+    ``/metrics`` and the timeline show one number. The span's own
+    duration is the one taken; only where none was recorded (tracer
+    off, trace unsampled) does the histogram get this clock's."""
+    from routest_tpu.obs import trace_span
+
+    t0 = time.perf_counter()
+    with trace_span("live.retrain." + phase, **attrs) as span:
+        yield span
+    ms = getattr(span, "duration_ms", None)
+    _trainer_metrics()["phase"].labels(phase=phase).observe(
+        time.perf_counter() - t0 if ms is None else ms / 1000.0)
 
 
 class ContinuousTrainer:
@@ -133,26 +157,47 @@ class ContinuousTrainer:
     # ── one cycle ─────────────────────────────────────────────────────
 
     def run_once(self) -> Dict:
-        """One retrain cycle; returns a result dict, never raises."""
+        """One retrain cycle; returns a result dict, never raises.
+
+        The cycle is one ``live.retrain`` span with five sequential
+        children (aggregate / upload / steps / apply / save). A child
+        ends where the host already is — nothing synchronises for the
+        tracer's sake — so an upload still in flight when ``upload``
+        closes is absorbed by ``steps``."""
+        from routest_tpu.obs import trace_span
+        from routest_tpu.utils.logging import get_logger
+
+        with trace_span("live.retrain") as root:
+            try:
+                result, self.last_result = self._cycle(root)
+            except Exception as e:
+                get_logger("routest_tpu.live").error(
+                    "live_retrain_failed", error=f"{type(e).__name__}: {e}")
+                result, self.last_result = "failed", {
+                    "trained": False, "reason": f"{type(e).__name__}: {e}"}
+            _trainer_metrics()["runs"].labels(result=result).inc()
+            root.set_attr("result", result)
+            return self.last_result
+
+    def _cycle(self, root) -> Tuple[str, Dict]:
+        """The cycle proper: (result label, result dict)."""
         import jax.numpy as jnp
 
         from routest_tpu.models.gnn import GraphBatch, edge_feature_array
         from routest_tpu.utils.logging import get_logger
 
-        m = _trainer_metrics()
         t0 = time.perf_counter()
-        log = get_logger("routest_tpu.live")
-        try:
+        with _phase("aggregate"):
             win = self._state.window()
             n_obs = len(win["edge"])
+            root.set_attr("observations", n_obs)
             if n_obs < self.min_obs:
-                m["runs"].labels(result="skipped").inc()
-                self.last_result = {
+                return "skipped", {
                     "trained": False,
                     "reason": f"window {n_obs} < min_obs {self.min_obs}"}
-                return self.last_result
             g = self._graph
             E = len(g["senders"])
+            root.set_attr("edges", E)
             # Per-edge window aggregation: mean observed seconds, last
             # observed hour (the window is oldest-first, so a plain
             # index write leaves the LAST occurrence standing).
@@ -166,24 +211,34 @@ class ContinuousTrainer:
                                  / counts[observed]).astype(np.float32)
             hours = np.full(E, time.localtime().tm_hour, np.int32)
             hours[win["edge"]] = win["hour"]
+            # everything the device is handed, in the order of upload
+            host = {
+                "senders": np.asarray(g["senders"], np.int32),
+                "receivers": np.asarray(g["receivers"], np.int32),
+                "edge_feats": edge_feature_array(
+                    g["length_m"], g["speed_limit"], g["road_class"],
+                    hours),
+                "length_m": np.asarray(g["length_m"], np.float32),
+                "speed_limit": np.asarray(g["speed_limit"], np.float32),
+                "targets": targets,
+                "loss_w": observed.astype(np.float32),
+                "coords": np.asarray(g["node_coords"], np.float32)}
+        with _phase("upload",
+                    bytes=sum(a.nbytes for a in host.values())):
             self._ensure_model()
             self._ensure_step()
             batch = GraphBatch(
-                senders=jnp.asarray(np.asarray(g["senders"], np.int32)),
-                receivers=jnp.asarray(np.asarray(g["receivers"],
-                                                 np.int32)),
-                edge_feats=jnp.asarray(edge_feature_array(
-                    g["length_m"], g["speed_limit"], g["road_class"],
-                    hours)),
-                length_m=jnp.asarray(np.asarray(g["length_m"],
-                                                np.float32)),
-                speed_limit=jnp.asarray(np.asarray(g["speed_limit"],
-                                                   np.float32)),
-                targets=jnp.asarray(targets),
+                senders=jnp.asarray(host["senders"]),
+                receivers=jnp.asarray(host["receivers"]),
+                edge_feats=jnp.asarray(host["edge_feats"]),
+                length_m=jnp.asarray(host["length_m"]),
+                speed_limit=jnp.asarray(host["speed_limit"]),
+                targets=jnp.asarray(host["targets"]),
                 weights=jnp.ones((E,), jnp.float32))
-            loss_w = jnp.asarray(observed.astype(np.float32))
-            coords = jnp.asarray(np.asarray(g["node_coords"],
-                                            np.float32))
+            loss_w = jnp.asarray(host["loss_w"])
+            coords = jnp.asarray(host["coords"])
+        root.set_attr("steps", self.steps)
+        with _phase("steps", steps=self.steps):
             params, opt_state = self._params, self._opt_state
             loss = float("nan")
             for _ in range(self.steps):
@@ -191,17 +246,16 @@ class ContinuousTrainer:
                     params, opt_state, coords, batch, loss_w)
             loss = float(loss)
             if not np.isfinite(loss):
-                m["runs"].labels(result="rejected").inc()
-                self.last_result = {"trained": False,
+                return "rejected", {"trained": False,
                                     "reason": f"non-finite loss {loss}"}
-                return self.last_result
+        with _phase("apply") as span:
             pred = np.asarray(self._model.apply(params, coords, batch))
+            span.set_attr("bytes", pred.nbytes)
             if not np.isfinite(pred).all():
-                m["runs"].labels(result="rejected").inc()
-                self.last_result = {
+                return "rejected", {
                     "trained": False,
                     "reason": "non-finite predictions after fit"}
-                return self.last_result
+        with _phase("save") as span:
             # Accept the cycle: carry the optimizer state forward and
             # land the artifact atomically (the router verifies again,
             # independently, before ITS generation flips).
@@ -209,27 +263,20 @@ class ContinuousTrainer:
             from routest_tpu.train.checkpoint import save_gnn
 
             save_gnn(self._path, self._model, params, g)
-            dur = time.perf_counter() - t0
-            self.cycles += 1
-            m["runs"].labels(result="saved").inc()
-            m["dur"].observe(dur)
-            obs_rmse = float(np.sqrt(np.mean(
-                (pred[observed] - targets[observed]) ** 2)))
-            self.last_result = {
-                "trained": True, "observations": n_obs,
-                "edges_labeled": int(observed.sum()),
-                "loss": round(loss, 3),
-                "window_rmse_s": round(obs_rmse, 3),
-                "train_s": round(dur, 3), "path": self._path}
-            log.info("live_retrain_saved", **self.last_result)
-            return self.last_result
-        except Exception as e:
-            m["runs"].labels(result="failed").inc()
-            log.error("live_retrain_failed",
-                      error=f"{type(e).__name__}: {e}")
-            self.last_result = {"trained": False,
-                                "reason": f"{type(e).__name__}: {e}"}
-            return self.last_result
+            span.set_attr("bytes", os.path.getsize(self._path))
+        dur = time.perf_counter() - t0
+        self.cycles += 1
+        _trainer_metrics()["dur"].observe(dur)
+        obs_rmse = float(np.sqrt(np.mean(
+            (pred[observed] - targets[observed]) ** 2)))
+        last = {
+            "trained": True, "observations": n_obs,
+            "edges_labeled": int(observed.sum()),
+            "loss": round(loss, 3),
+            "window_rmse_s": round(obs_rmse, 3),
+            "train_s": round(dur, 3), "path": self._path}
+        get_logger("routest_tpu.live").info("live_retrain_saved", **last)
+        return "saved", last
 
     def start(self, interval_s: float = 30.0) -> None:
         def run() -> None:

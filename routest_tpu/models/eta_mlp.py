@@ -181,19 +181,24 @@ class EtaMLP:
 
     def _trunk(self, params: Params, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
         """Shared forward: raw head outputs (B, n_heads) f32 + distance."""
-        feats, dist_km = self._expand(params, x)
-        h = feats.astype(self.policy.compute_dtype)
+        # named scopes: metadata only (an instruction's ``op_name``, an
+        # xplane's ``tf_op``), so a device trace names the layers
+        with jax.named_scope("eta.expand"):
+            feats, dist_km = self._expand(params, x)
+            h = feats.astype(self.policy.compute_dtype)
         layers = params["layers"]
-        for layer in layers[:-1]:
-            w = layer["w"].astype(self.policy.compute_dtype)
-            b = layer["b"].astype(self.policy.compute_dtype)
-            h = jax.nn.gelu(h @ w + b)
+        for i, layer in enumerate(layers[:-1]):
+            with jax.named_scope(f"eta.layer{i}"):
+                w = layer["w"].astype(self.policy.compute_dtype)
+                b = layer["b"].astype(self.policy.compute_dtype)
+                h = jax.nn.gelu(h @ w + b)
         last = layers[-1]
-        out = h @ last["w"].astype(self.policy.compute_dtype) + last["b"].astype(
-            self.policy.compute_dtype
-        )
-        return (out.astype(self.policy.output_dtype),
-                dist_km.astype(self.policy.output_dtype))
+        with jax.named_scope("eta.heads"):
+            out = h @ last["w"].astype(self.policy.compute_dtype) + last[
+                "b"].astype(self.policy.compute_dtype)
+            out = out.astype(self.policy.output_dtype)
+            dist_km = dist_km.astype(self.policy.output_dtype)
+        return out, dist_km
 
     def apply(self, params: Params, x: jax.Array) -> jax.Array:
         """(B, 12) ABI features → (B,) ETA minutes. bf16 trunk, f32 out.
@@ -204,9 +209,10 @@ class EtaMLP:
             q50 = self.quantiles.index(0.5)
             return self.apply_quantiles(params, x)[..., q50]
         out, dist_km = self._trunk(params, x)
-        pace = jax.nn.softplus(out[..., 0])       # min/km, positive
-        overhead = jax.nn.softplus(out[..., 1])   # min, positive
-        return pace * dist_km + overhead
+        with jax.named_scope("eta.heads"):
+            pace = jax.nn.softplus(out[..., 0])       # min/km, positive
+            overhead = jax.nn.softplus(out[..., 1])   # min, positive
+            return pace * dist_km + overhead
 
     def apply_quantiles(self, params: Params, x: jax.Array) -> jax.Array:
         """(B, 12) → (B, Q) ETA minutes per quantile, non-crossing.
@@ -223,7 +229,8 @@ class EtaMLP:
                              "construct EtaMLP(quantiles=...)")
         n_q = len(self.quantiles)
         out, dist_km = self._trunk(params, x)
-        return quantile_heads(out, dist_km, n_q)
+        with jax.named_scope("eta.heads"):
+            return quantile_heads(out, dist_km, n_q)
 
 
 def fit_normalizer(features: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:

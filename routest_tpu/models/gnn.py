@@ -162,40 +162,57 @@ class RoadGNN:
         """Per-edge predicted seconds. ``combine`` merges per-shard node
         aggregations (identity on one device; psum under shard_map)."""
         c = self.policy.compute_dtype
-        coords_n = ((node_coords
-                     - jnp.asarray([14.54, 121.03], node_coords.dtype))
-                    * 50.0).astype(c)
-        h = jax.nn.gelu(self._mlp(params["embed"], coords_n))
-        ef = batch.edge_feats.astype(c)
-        w = batch.weights.astype(c)
+        # The named scopes are metadata only: they land in each
+        # instruction's ``op_name`` (an xplane's ``tf_op``), so that a
+        # device trace tells the gathers, the segment sums and the
+        # small products of each round apart.
+        with jax.named_scope("gnn.embed"):
+            coords_n = ((node_coords
+                         - jnp.asarray([14.54, 121.03], node_coords.dtype))
+                        * 50.0).astype(c)
+            h = jax.nn.gelu(self._mlp(params["embed"], coords_n))
+            ef = batch.edge_feats.astype(c)
+            w = batch.weights.astype(c)
         # in-degree for mean aggregation (hub nodes would otherwise blow up
         # activations through the rounds and destabilize training)
-        degree = combine(jax.ops.segment_sum(w, batch.receivers,
-                                             num_segments=self.n_nodes))
-        inv_deg = (1.0 / jnp.maximum(degree, 1.0))[:, None]
-        for _ in range(self.n_rounds):
-            m_in = jnp.concatenate(
-                [h[batch.senders], h[batch.receivers], ef], axis=-1
-            )
-            # padded edges (weight 0) must not inject messages
-            messages = self._mlp(params["msg"], m_in) * w[:, None]
-            agg = jax.ops.segment_sum(messages, batch.receivers,
-                                      num_segments=self.n_nodes)
-            agg = combine(agg) * inv_deg
-            h = h + jax.nn.gelu(
-                self._mlp(params["upd"], jnp.concatenate([h, agg], axis=-1))
-            )
-            # parameter-free layer norm keeps round-over-round scale stable
-            h = (h - h.mean(-1, keepdims=True)) / jnp.sqrt(
-                h.var(-1, keepdims=True) + 1e-6)
-        r_in = jnp.concatenate([h[batch.senders], h[batch.receivers], ef],
-                               axis=-1)
-        out = self._mlp(params["readout"], r_in).astype(self.policy.output_dtype)
-        # Physical decomposition, as in the ETA model: free-flow time scaled
-        # by a learned congestion factor, plus learned fixed overhead.
-        freeflow = batch.length_m / jnp.maximum(batch.speed_limit, 0.1)
-        return (freeflow * jax.nn.softplus(out[..., 0])
-                + jax.nn.softplus(out[..., 1]))
+        with jax.named_scope("gnn.degree"):
+            degree = combine(jax.ops.segment_sum(w, batch.receivers,
+                                                 num_segments=self.n_nodes))
+            inv_deg = (1.0 / jnp.maximum(degree, 1.0))[:, None]
+        for i in range(self.n_rounds):
+            with jax.named_scope(f"gnn.round{i}.gather"):
+                m_in = jnp.concatenate(
+                    [h[batch.senders], h[batch.receivers], ef], axis=-1
+                )
+            with jax.named_scope(f"gnn.round{i}.message"):
+                # padded edges (weight 0) must not inject messages
+                messages = self._mlp(params["msg"], m_in) * w[:, None]
+            with jax.named_scope(f"gnn.round{i}.scatter"):
+                agg = jax.ops.segment_sum(messages, batch.receivers,
+                                          num_segments=self.n_nodes)
+                agg = combine(agg) * inv_deg
+            with jax.named_scope(f"gnn.round{i}.update"):
+                h = h + jax.nn.gelu(
+                    self._mlp(params["upd"],
+                              jnp.concatenate([h, agg], axis=-1))
+                )
+            with jax.named_scope(f"gnn.round{i}.norm"):
+                # parameter-free layer norm keeps round-over-round scale
+                # stable
+                h = (h - h.mean(-1, keepdims=True)) / jnp.sqrt(
+                    h.var(-1, keepdims=True) + 1e-6)
+        with jax.named_scope("gnn.readout.gather"):
+            r_in = jnp.concatenate(
+                [h[batch.senders], h[batch.receivers], ef], axis=-1)
+        with jax.named_scope("gnn.readout"):
+            out = self._mlp(params["readout"], r_in).astype(
+                self.policy.output_dtype)
+            # Physical decomposition, as in the ETA model: free-flow time
+            # scaled by a learned congestion factor, plus learned fixed
+            # overhead.
+            freeflow = batch.length_m / jnp.maximum(batch.speed_limit, 0.1)
+            return (freeflow * jax.nn.softplus(out[..., 0])
+                    + jax.nn.softplus(out[..., 1]))
 
     def apply(self, params: Params, node_coords: jax.Array,
               batch: GraphBatch) -> jax.Array:

@@ -21,6 +21,7 @@ import contextvars
 import os
 import random
 import re
+import sys
 import threading
 import time
 import uuid
@@ -149,6 +150,18 @@ class _NoopSpan:
 
 NOOP_SPAN = _NoopSpan()
 
+
+def _device_trace_annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` for a recorded span, so that a
+    running device capture (``obs/profiler.py``, ``maybe_device_trace``,
+    a bench's own) holds the span on ``/host:CPU``, on the thread that
+    ran it and on the clock of the device's operations. With no capture
+    running it is a flag test in the runtime. None where ``jax`` has
+    not been imported: this module never imports it (the gateway and
+    the fleet parent must stay off JAX)."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    return None if profiler is None else profiler.TraceAnnotation(name)
+
 _current: contextvars.ContextVar[Optional[SpanContext]] = \
     contextvars.ContextVar("rtpu_current_span", default=None)
 
@@ -242,6 +255,9 @@ class Tracer:
         ctx = SpanContext(trace_id, _new_span_id(), sampled)
         span = Span(name, ctx, parent_id, attrs if sampled else {},
                     remote_parent=remote_parent)
+        annotation = _device_trace_annotation(name) if sampled else None
+        if annotation is not None:
+            annotation.__enter__()
         token = _current.set(ctx)
         error: Optional[BaseException] = None
         try:
@@ -251,6 +267,8 @@ class Tracer:
             raise
         finally:
             _current.reset(token)
+            if annotation is not None:
+                annotation.__exit__(None, None, None)
             if sampled:
                 rec = span._finish(error)
                 if self.tail is None:

@@ -62,3 +62,14 @@ def tiny_dataset():
 @pytest.fixture()
 def rng():
     return np.random.default_rng(0)
+
+
+@pytest.fixture()
+def tracer():
+    """A fresh always-sampling tracer as the process tracer, so that a
+    test reads its own span buffer; the old one is put back after."""
+    from routest_tpu.obs import Tracer, configure_tracer, get_tracer
+
+    old = get_tracer()
+    yield configure_tracer(Tracer(enabled=True, sample_rate=1.0))
+    configure_tracer(old)
