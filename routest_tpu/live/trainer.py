@@ -94,6 +94,8 @@ class ContinuousTrainer:
         self.hidden = int(hidden)
         self.seed = int(seed)
         self._graph = router.graph_dict()
+        self._layout = None
+        self._static: Optional[Dict[str, np.ndarray]] = None
         self._model = None
         self._params = None
         self._opt = None
@@ -154,6 +156,33 @@ class ContinuousTrainer:
 
         self._step_fn = step
 
+    def _ensure_layout(self, root) -> None:
+        """Once per trainer: the graph's ``GraphLayout`` where it gets
+        one (``models/gnn.graph_layout`` decides from its degrees), under
+        which the train step holds no scatter-add, and the static arrays
+        in the order the device is handed them: the layout's, else the
+        graph's own."""
+        if self._static is not None:
+            return
+        from routest_tpu.models.gnn import graph_layout
+
+        g = self._graph
+        t0 = time.perf_counter()
+        lay = graph_layout(g["senders"], g["receivers"],
+                           len(g["node_coords"]))
+        static = {k: np.asarray(g[k]) for k in (
+            "senders", "receivers", "length_m", "speed_limit",
+            "road_class", "node_coords")}
+        if lay is not None:
+            for k in ("length_m", "speed_limit", "road_class"):
+                static[k] = static[k][lay.arc_order]
+            static["senders"], static["receivers"] = (lay.senders,
+                                                      lay.receivers)
+            static["node_coords"] = static["node_coords"][lay.node_order]
+            root.set_attr("layout_build_ms",
+                          round((time.perf_counter() - t0) * 1e3, 3))
+        self._layout, self._static = lay, static
+
     # ── one cycle ─────────────────────────────────────────────────────
 
     def run_once(self) -> Dict:
@@ -181,6 +210,7 @@ class ContinuousTrainer:
 
     def _cycle(self, root) -> Tuple[str, Dict]:
         """The cycle proper: (result label, result dict)."""
+        import jax
         import jax.numpy as jnp
 
         from routest_tpu.models.gnn import GraphBatch, edge_feature_array
@@ -195,7 +225,13 @@ class ContinuousTrainer:
                 return "skipped", {
                     "trained": False,
                     "reason": f"window {n_obs} < min_obs {self.min_obs}"}
-            g = self._graph
+            self._ensure_layout(root)
+            g, lay = self._static, self._layout
+            root.set_attr("layout",
+                          "segment_sum" if lay is None else "dense")
+            # the window's arc ids, in the order the arrays are in
+            edge = (win["edge"] if lay is None
+                    else lay.arc_rank[win["edge"]])
             E = len(g["senders"])
             root.set_attr("edges", E)
             # Per-edge window aggregation: mean observed seconds, last
@@ -203,14 +239,14 @@ class ContinuousTrainer:
             # index write leaves the LAST occurrence standing).
             sums = np.zeros(E, np.float64)
             counts = np.zeros(E, np.float64)
-            np.add.at(sums, win["edge"], win["time_s"])
-            np.add.at(counts, win["edge"], 1.0)
+            np.add.at(sums, edge, win["time_s"])
+            np.add.at(counts, edge, 1.0)
             observed = counts > 0
             targets = np.zeros(E, np.float32)
             targets[observed] = (sums[observed]
                                  / counts[observed]).astype(np.float32)
             hours = np.full(E, time.localtime().tm_hour, np.int32)
-            hours[win["edge"]] = win["hour"]
+            hours[edge] = win["hour"]
             # everything the device is handed, in the order of upload
             host = {
                 "senders": np.asarray(g["senders"], np.int32),
@@ -223,8 +259,9 @@ class ContinuousTrainer:
                 "targets": targets,
                 "loss_w": observed.astype(np.float32),
                 "coords": np.asarray(g["node_coords"], np.float32)}
-        with _phase("upload",
-                    bytes=sum(a.nbytes for a in host.values())):
+            slabs = lay and lay.slabs
+        with _phase("upload", bytes=sum(
+                a.nbytes for a in jax.tree_util.tree_leaves((host, slabs)))):
             self._ensure_model()
             self._ensure_step()
             batch = GraphBatch(
@@ -234,7 +271,8 @@ class ContinuousTrainer:
                 length_m=jnp.asarray(host["length_m"]),
                 speed_limit=jnp.asarray(host["speed_limit"]),
                 targets=jnp.asarray(host["targets"]),
-                weights=jnp.ones((E,), jnp.float32))
+                weights=jnp.ones((E,), jnp.float32),
+                layout=jax.tree_util.tree_map(jnp.asarray, slabs))
             loss_w = jnp.asarray(host["loss_w"])
             coords = jnp.asarray(host["coords"])
         root.set_attr("steps", self.steps)
@@ -262,7 +300,9 @@ class ContinuousTrainer:
             self._params, self._opt_state = params, opt_state
             from routest_tpu.train.checkpoint import save_gnn
 
-            save_gnn(self._path, self._model, params, g)
+            # the graph as the router gave it: the artifact's
+            # fingerprint is the router's, whatever order trained it
+            save_gnn(self._path, self._model, params, self._graph)
             span.set_attr("bytes", os.path.getsize(self._path))
         dur = time.perf_counter() - t0
         self.cycles += 1

@@ -26,7 +26,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Dict, NamedTuple, Tuple
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -45,6 +45,40 @@ _N_HOUR_FEATURES = 8  # four Fourier harmonics of hour-of-day
 N_EDGE_FEATURES = 2 + _N_CLASSES + _N_HOUR_FEATURES
 
 
+# A road network's nodes have a handful of arcs (a grid 0-4, an OSM
+# extract under 10). A graph in which some node has more than this many,
+# in or out, is hub-and-spoke, not roads: it gets no layout and keeps
+# the indexed sums (a sum under a layout is one addition a slab, so a
+# hub of 500 arcs would be 500 one-row additions).
+MAX_DEGREE = 16
+
+# (degree, number of nodes of that degree), by rising degree
+Classes = Tuple[Tuple[int, int], ...]
+
+
+@dataclasses.dataclass(frozen=True)
+class ArcSlabs:
+    """What the device program reads of a ``GraphLayout``. Nodes are
+    grouped by in-degree; the arcs into a class of ``n`` nodes of degree
+    ``d`` are ``d`` slabs of ``n`` rows, slab ``k`` holding the ``k``-th
+    arc of every node of the class, in node order. A segment sum is then
+    the sum of a class's slabs and its transpose (the gather
+    ``h[receivers]``) the class's rows ``d`` times over: contiguous
+    slices and a concatenation, no index, no reshape."""
+
+    perm_s: jax.Array       # (E,) int32: the arcs as slabs by sender
+    # (N,) int32, or None where out-degree order is node order (a
+    # symmetric graph): takes sums in out-degree order back to nodes
+    out_unperm: Optional[jax.Array]
+    in_classes: Classes
+    out_classes: Classes
+
+
+jax.tree_util.register_dataclass(
+    ArcSlabs, data_fields=["perm_s", "out_unperm"],
+    meta_fields=["in_classes", "out_classes"])
+
+
 class GraphBatch(NamedTuple):
     senders: jax.Array     # (E,) int32
     receivers: jax.Array   # (E,) int32
@@ -53,6 +87,138 @@ class GraphBatch(NamedTuple):
     speed_limit: jax.Array  # (E,) m/s
     targets: jax.Array     # (E,) observed seconds
     weights: jax.Array     # (E,) 0/1 (padding mask)
+    # set only on a batch whose arcs and nodes are in a GraphLayout's
+    # order: the forward then takes the dense path
+    layout: Optional[ArcSlabs] = None
+
+
+@dataclasses.dataclass(frozen=True)
+class GraphLayout:
+    """A static graph renumbered once, on the host, so that no sum over
+    a node's arcs needs an index (``graph_layout`` builds it). The model
+    has no per-node parameter, so losses and gradients are those of the
+    graph as given."""
+
+    node_order: np.ndarray  # (N,) laid-out node -> the graph's node
+    arc_order: np.ndarray   # (E,) laid-out arc -> the graph's arc
+    arc_rank: np.ndarray    # (E,) the graph's arc -> laid-out arc
+    senders: np.ndarray     # (E,) int32, laid-out arcs and nodes
+    receivers: np.ndarray   # (E,) int32
+    slabs: ArcSlabs         # numpy arrays until uploaded
+
+
+def _inverse(order: np.ndarray) -> np.ndarray:
+    rank = np.empty(len(order), np.int32)
+    rank[order] = np.arange(len(order), dtype=np.int32)
+    return rank
+
+
+def _slab_order(owner: np.ndarray, degree: np.ndarray):
+    """Arcs as slabs. ``owner`` (E,) is each arc's node, numbered so
+    that ``degree`` (N,), the arcs a node owns, rises with the number.
+    Returns the arcs in slab order and the degree classes."""
+    values, first, counts = np.unique(degree, return_index=True,
+                                      return_counts=True)
+    by_owner = np.argsort(owner, kind="stable")
+    run_start = np.cumsum(degree) - degree  # of a node's arcs, by_owner
+    node = owner[by_owner]
+    k = np.arange(len(owner)) - run_start[node]     # which of its arcs
+    class_of = np.repeat(np.arange(len(values)), counts)[node]
+    slot = (run_start[first][class_of] + k * counts[class_of]
+            + node - first[class_of])
+    order = np.empty(len(owner), np.int64)
+    order[slot] = by_owner
+    return order, tuple((int(d), int(n)) for d, n in zip(values, counts))
+
+
+def graph_layout(senders: np.ndarray, receivers: np.ndarray,
+                 n_nodes: int) -> Optional[GraphLayout]:
+    """The layout of a graph, or None where its degrees do not suit (a
+    node with more than ``MAX_DEGREE`` arcs in or out). Numpy, a few
+    stable argsorts over the arcs."""
+    in_deg = np.bincount(receivers, minlength=n_nodes)
+    out_deg = np.bincount(senders, minlength=n_nodes)
+    if max(in_deg.max(initial=0), out_deg.max(initial=0)) > MAX_DEGREE:
+        return None
+    node_order = np.argsort(in_deg, kind="stable")
+    node_rank = _inverse(node_order)
+    arc_order, in_classes = _slab_order(node_rank[receivers],
+                                        in_deg[node_order])
+    laid_s, laid_r = (node_rank[ends][arc_order]
+                      for ends in (senders, receivers))
+    # the sender side: nodes by out-degree, which on a symmetric graph
+    # is the order they are already in
+    out_laid = out_deg[node_order]
+    by_out = np.argsort(out_laid, kind="stable")
+    out_rank = _inverse(by_out)
+    perm_s, out_classes = _slab_order(out_rank[laid_s], out_laid[by_out])
+    symmetric = bool((by_out == np.arange(n_nodes)).all())
+    return GraphLayout(
+        node_order=node_order, arc_order=arc_order,
+        arc_rank=_inverse(arc_order), senders=laid_s, receivers=laid_r,
+        slabs=ArcSlabs(perm_s=perm_s.astype(np.int32),
+                       out_unperm=None if symmetric else out_rank,
+                       in_classes=in_classes, out_classes=out_classes))
+
+
+def _split(x: jax.Array, sizes):
+    """``x`` cut into consecutive pieces of ``sizes`` rows."""
+    return jnp.split(x, np.cumsum(sizes)[:-1].tolist())
+
+
+def _sum_slabs(x: jax.Array, classes: Classes,
+               rows: Optional[jax.Array] = None) -> jax.Array:
+    """(Σ n·d, …) arc rows → (Σ n, …) node rows: each class's slabs
+    summed, first to last as a scatter-add by sorted index would.
+    ``rows`` (Σ n·d,) names the rows of ``x`` the slabs are made of,
+    where they are not ``x``'s own in order."""
+    sizes = [n for d, n in classes for _ in range(d)]
+    if rows is None:
+        slabs = iter(_split(x, sizes))
+    else:
+        slabs = (x[r] for r in _split(rows, sizes))
+    out = []
+    for d, n in classes:
+        if d == 0:
+            out.append(jnp.zeros((n,) + x.shape[1:], x.dtype))
+            continue
+        acc = next(slabs)
+        for _ in range(d - 1):
+            acc = acc + next(slabs)
+        out.append(acc)
+    return jnp.concatenate(out)
+
+
+def _spread_slabs(h: jax.Array, classes: Classes) -> jax.Array:
+    """(Σ n, …) → (Σ n·d, …): each class's rows d times over. The
+    transpose of ``_sum_slabs``, and autodiff turns either into the
+    other (a split transposes to a concatenation)."""
+    blocks = _split(h, [n for _, n in classes])
+    return jnp.concatenate([block for (d, _), block in zip(classes, blocks)
+                            for _ in range(d)])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _take_senders(out_classes: Classes, h, senders, perm_s, out_unperm):
+    """``h[senders]`` whose transpose sums slabs gathered through
+    ``perm_s`` (each row read once) instead of autodiff's scatter-add
+    by an unsorted index."""
+    return h[senders]
+
+
+def _take_senders_fwd(out_classes, h, senders, perm_s, out_unperm):
+    return h[senders], (perm_s, out_unperm)
+
+
+def _take_senders_bwd(out_classes, res, g):
+    perm_s, out_unperm = res
+    dh = _sum_slabs(g, out_classes, rows=perm_s)
+    if out_unperm is not None:
+        dh = dh[out_unperm]
+    return dh, None, None, None
+
+
+_take_senders.defvjp(_take_senders_fwd, _take_senders_bwd)
 
 
 def _hour_features(hour: np.ndarray) -> np.ndarray:
@@ -173,24 +339,35 @@ class RoadGNN:
             h = jax.nn.gelu(self._mlp(params["embed"], coords_n))
             ef = batch.edge_feats.astype(c)
             w = batch.weights.astype(c)
+        lay = batch.layout
+        if lay is None:
+            def to_nodes(x):        # per-arc rows summed by receiver
+                return jax.ops.segment_sum(x, batch.receivers,
+                                           num_segments=self.n_nodes)
+
+            def arc_ends(h):
+                return h[batch.senders], h[batch.receivers]
+        else:
+            def to_nodes(x):
+                return _sum_slabs(x, lay.in_classes)
+
+            def arc_ends(h):
+                return (_take_senders(lay.out_classes, h, batch.senders,
+                                      lay.perm_s, lay.out_unperm),
+                        _spread_slabs(h, lay.in_classes))
         # in-degree for mean aggregation (hub nodes would otherwise blow up
         # activations through the rounds and destabilize training)
         with jax.named_scope("gnn.degree"):
-            degree = combine(jax.ops.segment_sum(w, batch.receivers,
-                                                 num_segments=self.n_nodes))
+            degree = combine(to_nodes(w))
             inv_deg = (1.0 / jnp.maximum(degree, 1.0))[:, None]
         for i in range(self.n_rounds):
             with jax.named_scope(f"gnn.round{i}.gather"):
-                m_in = jnp.concatenate(
-                    [h[batch.senders], h[batch.receivers], ef], axis=-1
-                )
+                m_in = jnp.concatenate([*arc_ends(h), ef], axis=-1)
             with jax.named_scope(f"gnn.round{i}.message"):
                 # padded edges (weight 0) must not inject messages
                 messages = self._mlp(params["msg"], m_in) * w[:, None]
             with jax.named_scope(f"gnn.round{i}.scatter"):
-                agg = jax.ops.segment_sum(messages, batch.receivers,
-                                          num_segments=self.n_nodes)
-                agg = combine(agg) * inv_deg
+                agg = combine(to_nodes(messages)) * inv_deg
             with jax.named_scope(f"gnn.round{i}.update"):
                 h = h + jax.nn.gelu(
                     self._mlp(params["upd"],
@@ -202,8 +379,7 @@ class RoadGNN:
                 h = (h - h.mean(-1, keepdims=True)) / jnp.sqrt(
                     h.var(-1, keepdims=True) + 1e-6)
         with jax.named_scope("gnn.readout.gather"):
-            r_in = jnp.concatenate(
-                [h[batch.senders], h[batch.receivers], ef], axis=-1)
+            r_in = jnp.concatenate([*arc_ends(h), ef], axis=-1)
         with jax.named_scope("gnn.readout"):
             out = self._mlp(params["readout"], r_in).astype(
                 self.policy.output_dtype)
@@ -242,6 +418,7 @@ class RoadGNN:
         """Loss with edges sharded over the mesh data axis: senders/
         receivers/features split per device, node states replicated, one
         psum per round combining neighborhood aggregations."""
+        # no layout: sharding a laid-out graph is not built yet
         batch_spec = GraphBatch(*([P(data_axis)] * 7))
 
         @functools.partial(

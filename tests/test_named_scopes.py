@@ -13,7 +13,8 @@ import pytest
 from routest_tpu.core.dtypes import F32_POLICY
 from routest_tpu.data.road_graph import generate_road_graph
 from routest_tpu.models.eta_mlp import EtaMLP
-from routest_tpu.models.gnn import RoadGNN, graph_batch
+from routest_tpu.models.gnn import (GraphBatch, RoadGNN, graph_batch,
+                                    graph_layout)
 
 GNN_SCOPES = (["gnn.embed", "gnn.degree", "gnn.readout.gather",
                "gnn.readout"]
@@ -39,6 +40,19 @@ def _gnn_step():
                   jnp.asarray(g["node_coords"]), graph_batch(g))
 
 
+def _gnn_step_laid_out():
+    """The same step over a batch in a ``GraphLayout``'s order: the
+    dense path of ``RoadGNN._forward``, as the live trainer runs it."""
+    step, (params, opt_state, coords, batch) = _gnn_step()
+    lay = graph_layout(np.asarray(batch.senders),
+                       np.asarray(batch.receivers), 96)
+    laid = GraphBatch(
+        jnp.asarray(lay.senders), jnp.asarray(lay.receivers),
+        *(x[lay.arc_order] for x in batch[2:7]),
+        layout=jax.tree_util.tree_map(jnp.asarray, lay.slabs))
+    return step, (params, opt_state, coords[lay.node_order], laid)
+
+
 def _eta_quantiles():
     model = EtaMLP(quantiles=(0.1, 0.5, 0.9))
     params = model.init(jax.random.PRNGKey(0))
@@ -47,6 +61,7 @@ def _eta_quantiles():
 
 
 PROGRAMS = {"gnn-train-step": (_gnn_step, GNN_SCOPES),
+            "gnn-train-step-laid-out": (_gnn_step_laid_out, GNN_SCOPES),
             "eta-apply-quantiles": (_eta_quantiles, ETA_SCOPES)}
 
 
@@ -60,7 +75,7 @@ def test_lowered_text_holds_every_scope(name):
     missing = [s for s in scopes
                if f"{s}/" not in text and f"({s})" not in text]
     assert not missing, missing
-    if name == "gnn-train-step":        # the backward pass is named too
+    if name.startswith("gnn-train-step"):   # the backward pass is named too
         assert "transpose(jvp(gnn.round1.scatter))" in text
 
 
