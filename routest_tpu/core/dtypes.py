@@ -35,6 +35,9 @@ class Policy:
 DEFAULT_POLICY = Policy()
 # Full-f32 policy for CPU-emulated meshes and parity tests.
 F32_POLICY = Policy(compute_dtype=jnp.float32)
+# bf16 parameters too: models too wide to hold in float32 on one chip
+# (the route-sequence language model), inference only.
+BF16_POLICY = Policy(param_dtype=jnp.bfloat16)
 
 
 def backend_compute_policy(model):

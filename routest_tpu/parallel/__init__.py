@@ -17,11 +17,18 @@ O(S²) cost. This package scales that axis across the mesh:
   parallelism over the ``model`` mesh axis (forward, training, serving);
 - :mod:`routest_tpu.parallel.pipeline` — GPipe fill-drain pipeline
   parallelism mapping model stages onto a ``stage`` mesh axis;
-- :mod:`routest_tpu.parallel.expert` — Switch-style expert parallelism
-  (capacity-bounded all_to_all MoE dispatch) over an ``expert`` axis.
+- :mod:`routest_tpu.parallel.expert` — the expert layer: top-k routed
+  experts with a shared one, computed for the share of the experts one
+  chip holds (a grouped product over uneven groups), and Switch-style
+  expert parallelism (capacity-bounded all_to_all dispatch) over an
+  ``expert`` axis;
+- :mod:`routest_tpu.parallel.select` — causal attention over a window
+  of keys and over a learned selection of keys, a block of queries at a
+  time (one device; what the route-sequence language model runs).
 
-All are pure shard_map programs — XLA emits the collectives over ICI;
-gradients flow through them, so the same code paths train.
+All but the last two's one-chip parts are pure shard_map programs — XLA
+emits the collectives over ICI; gradients flow through them, so the
+same code paths train.
 """
 
 from routest_tpu.parallel.expert import (init_moe_params, make_moe_apply,

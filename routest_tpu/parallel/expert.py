@@ -1,40 +1,44 @@
-"""Expert parallelism: a Switch-style MoE layer over an ``expert`` axis.
+"""The expert layer: top-k routed experts with a shared one, computed for
+the share of the experts that this chip holds — and, for an ``expert``
+mesh axis with one expert a device, the all-to-all exchange.
 
-The last §2.4 row (SURVEY.md marks EP "n/a; keep mesh abstraction
-general" — the reference has no parallelism of any kind). Implemented
-rather than waived so the mesh abstraction is proven general: per-expert
-MLPs live on their own devices, tokens travel to their expert and back
-via ``all_to_all`` — the EP pattern that scales conditional-compute
-models past one chip's HBM.
+**The share** (:func:`route_top_k`, :func:`grouped_experts`,
+:func:`moe_share`). A layer of ``n_experts`` routed experts is divided
+over chips; this chip holds the experts ``first .. first + count - 1``
+(:class:`ExpertShare`). It routes every token over ALL experts
+(sigmoid scores in float32, top-k of score + correction bias, weights
+renormalised over the chosen, no capacity and no drops), and adds, for
+each token, the terms of the chosen experts it holds and the shared
+expert. What the experts held elsewhere would add is left out: on one
+chip the layer runs without its exchange, and the parts that all shares
+give, with the shared expert counted once, add up to the whole layer
+(``tests/test_route_lm_share.py``).
 
-Schedule (top-1 routing, capacity-bounded — the Switch Transformer
-recipe):
+The held experts' product is a grouped one with uneven groups: the
+(token, slot) assignments that land here are sorted by expert, each
+expert's group is cut into tiles of ``tile`` rows, and one loop runs
+over the tiles that exist (a dynamic trip count: no capacity, so no
+bound on a group but the number of tokens): gather the tile's tokens,
+the gated MLP with that expert's weights, scatter-add the weighted rows
+into a float32 sum. A tile holds one expert's tokens only, so a short
+group costs one mostly empty tile; ``tile`` follows the tokens an
+expert can expect.
 
-1. tokens are sharded over the ``expert`` axis (which doubles as the
-   data axis for the token batch, the standard EP layout);
-2. each device routes its local tokens (argmax over router logits) and
-   packs, per destination expert, up to ``capacity`` tokens into a
-   fixed-shape (E, C, D) dispatch buffer (overflow tokens are dropped —
-   their output is the zero vector, recorded in the combine mask);
-3. ONE ``all_to_all`` turns (dest_expert, C, D) into (source_device, C,
-   D) on every expert's device — each device now holds every token
-   routed to ITS expert;
-4. the local expert MLP runs on its (E·C, D) slab — dense matmuls, MXU
-   territory;
-5. a second ``all_to_all`` returns expert outputs to the tokens' home
-   devices, where they scatter back into sequence order, scaled by the
-   router gate (straight-through for top-1).
-
-Everything is fixed-shape; gradients flow through both all_to_alls and
-the gather/scatter (router grads via the gate multiplication). A
-``load_balance_loss`` (mean expert load × mean router prob, scaled E²)
-is returned for training, as in the Switch paper.
+**The exchange** (:func:`make_moe_apply`, with :func:`moe_apply_dense`
+as its oracle): Switch-style top-1 routing with a capacity over an
+``expert`` mesh axis, one expert a device. Tokens are sharded over the
+axis; each device packs up to ``capacity`` tokens per destination
+expert into a fixed (E, C, D) buffer (overflow is dropped and reported),
+one ``all_to_all`` brings every expert its tokens, the expert MLP runs,
+a second ``all_to_all`` takes the results home. It has run on virtual
+CPU devices only; the top-k share above has no exchange yet (ROADMAP
+M3).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Dict, Tuple
+from typing import Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -157,3 +161,104 @@ def make_moe_apply(mesh: Mesh, expert_axis: str = "expert",
         return y, {"load_balance_loss": lbl, "dropped_frac": dropped}
 
     return jax.jit(run)
+
+
+# ── the share of a top-k layer that one chip holds ───────────────────
+
+
+class ExpertShare(NamedTuple):
+    """The experts ``first .. first + count - 1`` of ``n_experts``."""
+    n_experts: int
+    first: int
+    count: int
+
+
+def route_top_k(x: jax.Array, router: jax.Array, bias: jax.Array,
+                top_k: int, scaling: float = 1.0):
+    """Every token over all experts: ``p = sigmoid(x @ router)`` in
+    float32, chosen = top-k of ``p + bias`` (ties to the lower expert),
+    weights ``p / sum over the chosen`` times ``scaling``.
+    → (chosen (T, k) int32, weights (T, k) float32)."""
+    prob = jax.nn.sigmoid(jnp.matmul(
+        x, router, preferred_element_type=jnp.float32))
+    _, chosen = jax.lax.top_k(prob + bias.astype(jnp.float32), top_k)
+    picked = jnp.take_along_axis(prob, chosen, axis=-1)
+    return chosen.astype(jnp.int32), (
+        picked / picked.sum(-1, keepdims=True) * scaling)
+
+
+def gated_mlp(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
+              w_down: jax.Array) -> jax.Array:
+    """``(silu(x w_gate) * x w_up) w_down``: products in the arrays'
+    dtype, accumulated and returned in float32."""
+    gate = jnp.matmul(x, w_gate, preferred_element_type=jnp.float32)
+    up = jnp.matmul(x, w_up, preferred_element_type=jnp.float32)
+    hidden = (jax.nn.silu(gate) * up).astype(x.dtype)
+    return jnp.matmul(hidden, w_down, preferred_element_type=jnp.float32)
+
+
+def expert_tile(tokens: int, top_k: int, n_experts: int) -> int:
+    """Rows of a tile: the tokens an expert can expect of ``tokens``,
+    as a power of two between 128 (below it the MXU idles) and 512."""
+    expect = max(1, tokens * top_k // n_experts)
+    return min(512, max(128, 1 << (expect - 1).bit_length()))
+
+
+def grouped_experts(x: jax.Array, chosen: jax.Array, weights: jax.Array,
+                    experts: Params, share: ExpertShare,
+                    valid: Optional[jax.Array] = None,
+                    tile: Optional[int] = None):
+    """The held experts' terms: ``sum over the chosen e held here of
+    weights_e * E_e(x)`` for every token, as float32 (T, D), and the
+    number of tokens each held expert got, (count,) int32. ``experts``
+    holds ``w_gate``, ``w_up`` (count, D, M) and ``w_down`` (count, M,
+    D); ``valid`` (T,) leaves padded tokens out."""
+    t, d = x.shape
+    k = chosen.shape[1]
+    n_held = share.count
+    tile = tile or expert_tile(t, k, share.n_experts)
+    local = chosen - share.first
+    held = (local >= 0) & (local < n_held)
+    if valid is not None:
+        held = held & valid[:, None]
+    local = jnp.where(held, local, n_held).reshape(-1)     # (T·k,)
+    order = jnp.argsort(local, stable=True).astype(jnp.int32)
+    counts = jnp.zeros((n_held + 1,), jnp.int32).at[local].add(1)[:n_held]
+    tiles = (counts + tile - 1) // tile
+    tile_end = jnp.cumsum(tiles)
+    row0 = jnp.cumsum(counts) - counts
+    offs = jnp.arange(tile, dtype=jnp.int32)
+
+    def one_tile(j, y):
+        e = jnp.searchsorted(tile_end, j, side="right").astype(jnp.int32)
+        within = (j - (tile_end[e] - tiles[e])) * tile + offs
+        live = within < counts[e]
+        flat = order[jnp.clip(row0[e] + within, 0, t * k - 1)]
+        token, slot = flat // k, flat % k
+        w = jnp.where(live, weights[token, slot], 0.0)
+        out = gated_mlp(x[token], experts["w_gate"][e], experts["w_up"][e],
+                        experts["w_down"][e])
+        return y.at[token].add(out * w[:, None])
+
+    y = jax.lax.fori_loop(0, tile_end[-1], one_tile,
+                          jnp.zeros((t, d), jnp.float32))
+    return y, counts
+
+
+def moe_share(params: Params, x: jax.Array, top_k: int, share: ExpertShare,
+              scaling: float = 1.0, valid: Optional[jax.Array] = None,
+              scope: str = "moe"):
+    """This chip's part of the layer for tokens ``x`` (T, D): the held
+    experts' terms plus the shared expert, float32. ``params``:
+    ``router`` (D, n_experts), ``bias`` (n_experts,), the held experts'
+    ``w_gate`` / ``w_up`` / ``w_down``, and ``shared`` (the shared
+    expert's three matrices). → (y, {"chosen", "counts"})."""
+    with jax.named_scope(scope + ".route"):
+        chosen, weights = route_top_k(x, params["router"], params["bias"],
+                                      top_k, scaling)
+    with jax.named_scope(scope + ".experts"):
+        y, counts = grouped_experts(x, chosen, weights, params, share, valid)
+    with jax.named_scope(scope + ".shared"):
+        s = params["shared"]
+        y = y + gated_mlp(x, s["w_gate"], s["w_up"], s["w_down"])
+    return y, {"chosen": chosen, "counts": counts}

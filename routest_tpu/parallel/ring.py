@@ -79,10 +79,13 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     keeping TRAINING memory at the same O(S·chunk) bound as inference
     (grad parity is tested against the full oracle).
 
-    Known trade under ``causal=True``: chunks wholly in a query's
-    future still pay their QK einsum before masking to zero (~2× FLOPs
-    at large S). The consumers here are non-causal route encoders, so
-    simplicity wins over a bounded scan until a causal consumer exists.
+    Under ``causal=True`` chunks wholly in a query's future still pay
+    their QK einsum here before masking to zero (~2× FLOPs at large S):
+    this function's consumers are non-causal route encoders. The causal
+    consumer, the route-sequence language model, runs
+    ``parallel/select.py``, whose blocks of queries visit only the
+    chunks of keys at or before them (and, under a window, only the
+    blocks the window touches).
 
     Same layouts and mask/causal semantics as :func:`full_attention`
     (the parity oracle)."""

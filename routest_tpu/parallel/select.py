@@ -1,0 +1,233 @@
+"""Causal attention over a window of keys, and over a selected set of
+keys, one block of queries at a time.
+
+Both take the keys in two parts, as latent attention makes them: a
+per-head part ``k`` (B, L, H, D) and a part ``k_shared`` (B, L, Dr)
+that every head scores against (the rotary part of a latent key). The
+queries are not taken as an array: ``q_fn(b, t0)`` makes the block of
+queries of route ``b`` from position ``t0`` on, ``(q (block, H, D),
+q_shared (block, H, Dr))``, inside the loop, so that the queries of all
+heads never exist at once (at 128 heads of width 192 they are as large
+as the keys). The score is ``(q.k + q_shared.k_shared) * scale``;
+products take the arrays' own dtype and accumulate in float32, the
+softmax is float32.
+
+- :func:`windowed_attention`: query t sees ``t - window + 1 <= s <= t``.
+  A block of queries is scored against the few blocks of keys that its
+  window touches and nothing else: the work is O(L * window), not
+  O(L^2).
+- :func:`selected_attention`: query t sees the ``top_k`` keys ``s <= t``
+  with the largest selector score ``I(t, s) = sum_j w_j(t) relu(qI_j(t)
+  . kI(s))`` (every ``s <= t`` while there are no more than ``top_k``;
+  equal scores go to the lower s). The selection is applied as a mask
+  over the causal blocks of keys (an online softmax over chunks of
+  keys, as ``ring.blockwise_attention``), not as a gather: a chunk
+  wholly in a block's future is not visited, by the selector or by the
+  attention. The threshold comes from a radix search over the scores'
+  bit patterns (:func:`top_k_mask`), not from a sort.
+
+Each returns the attention output and, per query, the number of keys it
+saw and the first of them: what the sequence scorer's counters and the
+benchmark's comparison of key sets read.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Callable
+
+import jax
+import jax.numpy as jnp
+
+_NEG = -1e30
+
+
+def _scores(q, q_shared, k, k_shared, scale):
+    """(H, Q, K) float32."""
+    s = jnp.einsum("qhd,khd->hqk", q, k,
+                   preferred_element_type=jnp.float32)
+    s = s + jnp.einsum("qhd,kd->hqk", q_shared, k_shared,
+                       preferred_element_type=jnp.float32)
+    return s * scale
+
+
+def _key_taps(keys):
+    return (keys.sum(-1).astype(jnp.int32),
+            jnp.argmax(keys, -1).astype(jnp.int32))
+
+
+def windowed_attention(q_fn: Callable, k, k_shared, v, *, window: int,
+                       scale: float, block: int = 512):
+    """→ (out (B, L, H, Dv), n_keys (B, L), first_key (B, L)). ``L``
+    must be a multiple of ``block`` (or smaller than it)."""
+    b_sz, length, heads, _ = k.shape
+    block = min(block, length)
+    if length % block:
+        raise ValueError(f"length {length} is not a multiple of {block}")
+    n_blk = length // block
+    span = min(((window - 2) // block + 2) * block, length)
+
+    def one(n):
+        b, i = n // n_blk, n % n_blk
+        q, q_shared = q_fn(b, i * block)
+        start = jnp.clip((i + 1) * block - span, 0, length - span)
+        kw = jax.lax.dynamic_slice_in_dim(k[b], start, span, 0)
+        ks = jax.lax.dynamic_slice_in_dim(k_shared[b], start, span, 0)
+        vw = jax.lax.dynamic_slice_in_dim(v[b], start, span, 0)
+        t = i * block + jnp.arange(block)[:, None]
+        s_pos = start + jnp.arange(span)[None, :]
+        keys = (s_pos <= t) & (s_pos > t - window)
+        s = jnp.where(keys[None], _scores(q, q_shared, kw, ks, scale), _NEG)
+        p = jnp.exp(s - s.max(-1, keepdims=True))
+        p = p / p.sum(-1, keepdims=True)
+        out = jnp.einsum("hqk,khd->qhd", p.astype(v.dtype), vw,
+                         preferred_element_type=jnp.float32)
+        n_keys, first = _key_taps(keys)
+        return out.astype(v.dtype), n_keys, first + start
+
+    out, n_keys, first = jax.lax.map(one, jnp.arange(b_sz * n_blk))
+    return (out.reshape(b_sz, length, heads, -1),
+            n_keys.reshape(b_sz, length), first.reshape(b_sz, length))
+
+
+# ── the selector ─────────────────────────────────────────────────────
+
+
+def _radix_search(holds: Callable, n_bits: int, rows: int):
+    """Per row the largest ``r < 2**n_bits`` for which ``holds(r)``,
+    where ``holds`` is true for 0 and, once false, stays false as ``r``
+    grows. Four bits a pass: ``holds`` is asked about 15 candidates
+    (rows, 15) at once, so the data behind it is read once a pass."""
+    r = jnp.zeros((rows,), jnp.uint32)
+    digits = jnp.arange(1, 16, dtype=jnp.uint32)
+    for shift in range(n_bits - 4, -1, -4):
+        ok = holds(r[:, None] | (digits << shift)[None, :])
+        r = r | (ok.sum(-1).astype(jnp.uint32) << shift)
+    return r
+
+
+def top_k_mask(scores, t_pos, top_k: int):
+    """(Q, K) bool: the ``top_k`` largest of ``scores[q, s]`` over ``s <=
+    t_pos[q]``, ties to the lower s; every such s where there are no
+    more than ``top_k``. Exact, without a sort: the float32 bit patterns
+    are mapped to unsigned integers of the same order, the value of the
+    k-th largest is found by a radix search on counts, then the cut
+    among the keys equal to it by a second search on their positions.
+    """
+    n_q, n_k = scores.shape
+    s_pos = jnp.arange(n_k, dtype=jnp.int32)[None, :]
+    causal = s_pos <= t_pos[:, None]
+    if n_k <= top_k:
+        return causal
+    scores = scores.astype(jnp.float32)
+    bits = jax.lax.bitcast_convert_type(
+        jnp.where(scores == 0, 0.0, scores), jnp.int32)   # -0.0 is 0.0
+    ordered = bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF))
+    u = jax.lax.bitcast_convert_type(ordered, jnp.uint32) ^ jnp.uint32(
+        0x80000000)
+    u = jnp.where(causal, u, jnp.uint32(0))     # no finite score maps to 0
+    want = jnp.minimum(top_k, t_pos + 1).astype(jnp.int32)
+
+    def at_least_k(cand):
+        n = (u[:, None, :] >= cand[:, :, None]).sum(-1, dtype=jnp.int32)
+        return n >= want[:, None]
+
+    kth = _radix_search(at_least_k, 32, n_q)[:, None]
+    above = u > kth
+    tie = (u == kth) & causal
+    need = want - above.sum(-1, dtype=jnp.int32)
+
+    def fewer_before(cand):
+        n = (tie[:, None, :] & (s_pos[None].astype(jnp.uint32)
+                                < cand[:, :, None])).sum(-1,
+                                                         dtype=jnp.int32)
+        return n < need[:, None]
+
+    pos_bits = 4 * math.ceil(max(n_k - 1, 1).bit_length() / 4)
+    last_tie = _radix_search(fewer_before, pos_bits, n_q)[:, None]
+    return (above & causal) | (tie & (s_pos.astype(jnp.uint32) <= last_tie))
+
+
+def selector_scores(q_idx, w_idx, k_idx):
+    """I(t, s) for a block: q_idx (Q, J, Di), w_idx (Q, J) float32,
+    k_idx (K, Di) → (Q, K) float32."""
+    s = jnp.einsum("qjd,kd->jqk", q_idx, k_idx,
+                   preferred_element_type=jnp.float32)
+    return (jax.nn.relu(s) * w_idx.T[:, :, None]).sum(0)
+
+
+def selected_attention(q_fn: Callable, k, k_shared, v, idx_fn: Callable,
+                       k_idx, *, top_k: int, scale: float, block: int = 256,
+                       chunk: int = 2048, scope: str = ""):
+    """→ (out (B, L, H, Dv), n_keys (B, L), first_key (B, L)).
+    ``idx_fn(b, t0)`` makes a block's selector queries ``(q_idx (block,
+    J, Di), w_idx (block, J))``; ``k_idx`` (B, L, Di) are the selector's
+    keys. ``L`` must be a multiple of ``block`` (or smaller)."""
+    b_sz, length, heads, _ = k.shape
+    d_v = v.shape[-1]
+    block = min(block, length)
+    chunk = math.gcd(length, max(chunk, block))
+    if length % block:
+        raise ValueError(f"length {length} is not a multiple of {block}")
+    n_blk = length // block
+
+    def one(n):
+        b, i = n // n_blk, n % n_blk
+        t_pos = i * block + jnp.arange(block, dtype=jnp.int32)
+        n_chunks = ((i + 1) * block + chunk - 1) // chunk
+        if length > top_k:
+            with jax.named_scope(scope + ".selector"):
+                q_idx, w_idx = idx_fn(b, i * block)
+
+                def score_chunk(j, buf):
+                    kc = jax.lax.dynamic_slice_in_dim(k_idx[b], j * chunk,
+                                                      chunk, 0)
+                    return jax.lax.dynamic_update_slice_in_dim(
+                        buf, selector_scores(q_idx, w_idx, kc), j * chunk, 1)
+
+                scores = jax.lax.fori_loop(
+                    0, n_chunks, score_chunk,
+                    jnp.full((block, length), -jnp.inf, jnp.float32))
+            with jax.named_scope(scope + ".topk"):
+                keys = top_k_mask(scores, t_pos, top_k)
+        else:
+            keys = jnp.arange(length, dtype=jnp.int32)[None, :] \
+                <= t_pos[:, None]
+        q, q_shared = q_fn(b, i * block)
+
+        def attend_chunk(j, carry):
+            acc, m, den = carry
+            kc = jax.lax.dynamic_slice_in_dim(k[b], j * chunk, chunk, 0)
+            ks = jax.lax.dynamic_slice_in_dim(k_shared[b], j * chunk, chunk,
+                                              0)
+            vc = jax.lax.dynamic_slice_in_dim(v[b], j * chunk, chunk, 0)
+            seen = jax.lax.dynamic_slice_in_dim(keys, j * chunk, chunk,
+                                                1)[None]
+            s = jnp.where(seen, _scores(q, q_shared, kc, ks, scale), _NEG)
+            m_new = jnp.maximum(m, s.max(-1))
+            # the mask multiply: in a chunk where a query sees nothing
+            # exp(NEG - NEG) = 1 would add mass that is not there
+            p = jnp.exp(s - m_new[..., None]) * seen
+            fix = jnp.exp(m - m_new)
+            acc = acc * fix[..., None] + jnp.einsum(
+                "hqk,khd->hqd", p.astype(v.dtype), vc,
+                preferred_element_type=jnp.float32)
+            return acc, m_new, den * fix + p.sum(-1)
+
+        acc, _, den = jax.lax.fori_loop(
+            0, n_chunks, attend_chunk,
+            (jnp.zeros((heads, block, d_v), jnp.float32),
+             jnp.full((heads, block), _NEG, jnp.float32),
+             jnp.zeros((heads, block), jnp.float32)))
+        out = (acc / den[..., None]).transpose(1, 0, 2).astype(v.dtype)
+        return (out,) + _key_taps(keys)
+
+    out, n_keys, first = jax.lax.map(one, jnp.arange(b_sz * n_blk))
+    return (out.reshape(b_sz, length, heads, d_v),
+            n_keys.reshape(b_sz, length), first.reshape(b_sz, length))
+
+
+def selected_rows(q_idx, w_idx, k_idx, t_pos, top_k: int):
+    """The key sets of a few named queries, (P, L) bool: the same scores
+    and the same cut as :func:`selected_attention` applies to them."""
+    return top_k_mask(selector_scores(q_idx, w_idx, k_idx), t_pos, top_k)
