@@ -55,8 +55,9 @@ import jax.numpy as jnp
 
 from routest_tpu.core.dtypes import BF16_POLICY, Policy
 from routest_tpu.parallel.expert import ExpertShare, gated_mlp, moe_share
-from routest_tpu.parallel.select import (selected_attention, selected_rows,
-                                         windowed_attention)
+from routest_tpu.parallel.select import (attention_path, block_and_chunk,
+                                         chunk_steps, selected_attention,
+                                         selected_rows, windowed_attention)
 
 Params = Dict
 
@@ -209,6 +210,18 @@ class RouteLM:
         return AttentionSizes(top_k=s["index_topk"],
                               index_heads=s["index_n_heads"],
                               index_dim=s["index_head_dim"], **common)
+
+    def selected_steps(self, length: int) -> Tuple[str, int]:
+        """For a route padded to ``length``, in one full layer: which
+        online-softmax step runs (``"fused"`` or ``"xla"``: what
+        ``select.attention_path`` says of this model's shapes here) and
+        how many steps over chunks of keys it takes."""
+        a = self.attention_sizes(FULL)
+        block, chunk = block_and_chunk(length, self.select_block,
+                                       self.key_chunk)
+        path = attention_path(a.heads, block, chunk, a.d_nope, a.d_rope,
+                              a.d_v, self.policy.compute_dtype)
+        return path, chunk_steps(length, self.select_block, self.key_chunk)
 
     # ── parameters ──────────────────────────────────────────────────
 

@@ -29,15 +29,45 @@ softmax is float32.
 Each returns the attention output and, per query, the number of keys it
 saw and the first of them: what the sequence scorer's counters and the
 benchmark's comparison of key sets read.
+
+**Which online-softmax step runs where.** The step of
+:func:`selected_attention` — score product of both key parts, scale,
+mask, running max, ``exp``, row sum, rescale of the accumulator, value
+product — has two forms with one set of semantics, and
+:func:`attention_path` chooses between them from shapes, dtype and
+backend alone:
+
+- ``"fused"``: one Pallas TPU kernel a block of queries
+  (:func:`_attend_fused`), a grid over groups of ``HEAD_TILE`` heads and
+  tiles of 1,024 or 512 keys (:func:`key_tile_for`), the float32 score
+  and probability tiles and the running max, sum and accumulator in
+  VMEM. It runs where the backend is a TPU, the arrays are bfloat16 and
+  the shapes tile: head widths multiples of 128, the shared width of
+  64, the heads a multiple of ``HEAD_TILE``, the chunk of a key tile,
+  the block of 32. Written in XLA the same step sends a float32 (heads,
+  block, chunk) tile through HBM five times (268 MB at 128 heads, 256
+  queries, 2,048 keys) and is bound by that; the kernel reads the
+  chunk's keys and values and writes the block's output, nothing else.
+- ``"xla"``: :func:`_attend_xla`, a ``fori_loop`` over chunks of keys.
+  It runs everywhere else — the CPU of the tests, toy widths, float32
+  arrays — and is the oracle of the kernel's parity test.
+
+Both visit every key tile that holds a key ``s <= t`` of the block and
+none wholly in its future (such a tile adds exactly nothing: its scores
+are all masked, so the running max, the sum and the accumulator stay as
+they are); a masked key contributes exactly zero mass in both.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG = -1e30
 
@@ -156,6 +186,172 @@ def selector_scores(q_idx, w_idx, k_idx):
     return (jax.nn.relu(s) * w_idx.T[:, :, None]).sum(0)
 
 
+# ── the online-softmax step of selected_attention, in two forms ──────
+
+HEAD_TILE = 8               # heads of one grid step of the kernel
+KEY_TILES = (1024, 512)     # keys of one: the first that divides the chunk
+_VMEM_BYTES = 64 * 2 ** 20  # (readings of both: PERF.md §6, PR 28)
+
+
+def key_tile_for(chunk: int) -> int:
+    """Keys of one grid step of the kernel where the loop steps by
+    ``chunk`` (which divides the length); 0 where no tile divides it."""
+    return next((t for t in KEY_TILES if chunk % t == 0), 0)
+
+
+def attention_path(heads: int, block: int, chunk: int, d: int,
+                   d_shared: int, d_v: int, dtype, backend: str = "") -> str:
+    """The step :func:`selected_attention` runs at these shapes:
+    ``"fused"`` (the Pallas kernel) on a TPU where bfloat16 arrays tile,
+    ``"xla"`` everywhere else. ``chunk`` is the chunk the loop really
+    steps by, ``block`` the block it really takes (both after the length
+    cut them); ``backend`` defaults to JAX's own."""
+    tiles = (heads % HEAD_TILE == 0 and block % 32 == 0
+             and key_tile_for(chunk) > 0 and d % 128 == 0 and d_v % 128 == 0
+             and d_shared % 64 == 0)
+    on_tpu = (backend or jax.default_backend()) == "tpu"
+    return ("fused" if on_tpu and tiles and jnp.dtype(dtype) == jnp.bfloat16
+            else "xla")
+
+
+def block_and_chunk(length: int, block: int, chunk: int):
+    """The block of queries and the chunk of keys that
+    :func:`selected_attention` steps by at this length."""
+    block = min(block, length)
+    return block, math.gcd(length, max(chunk, block))
+
+
+def chunk_steps(length: int, block: int, chunk: int) -> int:
+    """Online-softmax steps over chunks of keys that one route of this
+    (padded) length takes in one selecting layer: the sum over its
+    blocks of queries of the causal chunks each visits."""
+    block, chunk = block_and_chunk(length, block, chunk)
+    return sum(((i + 1) * block + chunk - 1) // chunk
+               for i in range(length // block))
+
+
+def _attend_xla(q, q_shared, k, k_shared, v, keys, b, n_chunks, *,
+                chunk: int, scale: float):
+    """One block of queries over the first ``n_chunks`` chunks of the
+    keys of route ``b``: q (Q, H, D), q_shared (Q, H, Dr), k (B, L, H,
+    D), k_shared (B, L, Dr), v (B, L, H, Dv), keys (Q, L) bool → (H, Q,
+    Dv) float32."""
+    block, heads, _ = q.shape
+
+    def attend_chunk(j, carry):
+        acc, m, den = carry
+        kc = jax.lax.dynamic_slice_in_dim(k[b], j * chunk, chunk, 0)
+        ks = jax.lax.dynamic_slice_in_dim(k_shared[b], j * chunk, chunk, 0)
+        vc = jax.lax.dynamic_slice_in_dim(v[b], j * chunk, chunk, 0)
+        seen = jax.lax.dynamic_slice_in_dim(keys, j * chunk, chunk, 1)[None]
+        s = jnp.where(seen, _scores(q, q_shared, kc, ks, scale), _NEG)
+        m_new = jnp.maximum(m, s.max(-1))
+        # the mask multiply: in a chunk where a query sees nothing
+        # exp(NEG - NEG) = 1 would add mass that is not there
+        p = jnp.exp(s - m_new[..., None]) * seen
+        fix = jnp.exp(m - m_new)
+        acc = acc * fix[..., None] + jnp.einsum(
+            "hqk,khd->hqd", p.astype(v.dtype), vc,
+            preferred_element_type=jnp.float32)
+        return acc, m_new, den * fix + p.sum(-1)
+
+    acc, _, den = jax.lax.fori_loop(
+        0, n_chunks, attend_chunk,
+        (jnp.zeros((heads, block, v.shape[-1]), jnp.float32),
+         jnp.full((heads, block), _NEG, jnp.float32),
+         jnp.zeros((heads, block), jnp.float32)))
+    return acc / den[..., None]
+
+
+def _attend_kernel(at_ref, q_ref, qs_ref, k_ref, ks_ref, v_ref, seen_ref,
+                   o_ref, acc_ref, m_ref, den_ref, *, scale: float):
+    """One grid step: ``HEAD_TILE`` heads of the block against one tile
+    of keys. ``at_ref`` (2,): the route and the number of key tiles the
+    block visits; beyond it a step does nothing and fetches nothing."""
+    j, n_tiles = pl.program_id(1), at_ref[1]
+
+    @pl.when(j == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _NEG)
+        den_ref[...] = jnp.zeros_like(den_ref)
+
+    @pl.when(j < n_tiles)
+    def _():
+        h, n_q, d_r = qs_ref.shape
+        seen = (seen_ref[...].astype(jnp.int32) != 0)[None]
+        s = jnp.einsum("hqd,hkd->hqk", q_ref[...], k_ref[...],
+                       preferred_element_type=jnp.float32)
+        shared = jax.lax.dot_general(        # all the tile's heads at once
+            qs_ref[...].reshape(h * n_q, d_r), ks_ref[...],
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        s = jnp.where(seen, (s + shared.reshape(s.shape)) * scale, _NEG)
+        m = m_ref[...]
+        m_new = jnp.maximum(m, s.max(-1, keepdims=True))
+        # a masked key adds exactly nothing, also where a query sees no
+        # key of the tile and exp(NEG - NEG) = 1
+        p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+        fix = jnp.exp(m - m_new)
+        acc_ref[...] = acc_ref[...] * fix + jnp.einsum(
+            "hqk,hkd->hqd", p.astype(v_ref.dtype), v_ref[...],
+            preferred_element_type=jnp.float32)
+        den_ref[...] = den_ref[...] * fix + p.sum(-1, keepdims=True)
+        m_ref[...] = m_new
+
+    @pl.when(j == n_tiles - 1)
+    def _():
+        o_ref[...] = (acc_ref[...] / den_ref[...]).astype(o_ref.dtype)
+
+
+def _attend_fused(q, q_shared, k, k_shared, v, keys, b, n_tiles, *,
+                  scale: float, key_tile: int, head_tile: int = HEAD_TILE,
+                  interpret: bool = False):
+    """The same block of queries as :func:`_attend_xla` over the first
+    ``n_tiles`` tiles of ``key_tile`` keys of route ``b``, as one kernel:
+    q (Q, H, D), q_shared (Q, H, Dr) as ``q_fn`` makes them; k (B, H, L,
+    D), v (B, H, L, Dv) laid out by head; k_shared (B, L, Dr); keys (Q,
+    L) bool → (H, Q, Dv) in ``v.dtype``. Keys and values are fetched
+    tile by tile straight from the whole arrays (``b`` is a prefetched
+    scalar: no route is sliced out in HBM)."""
+    n_q, heads, d = q.shape
+    d_r, d_v, length = q_shared.shape[-1], v.shape[-1], v.shape[2]
+    at = jnp.stack([b, n_tiles]).astype(jnp.int32)
+
+    def tile(j, at):                      # no tile beyond the last one
+        return jnp.minimum(j, at[1] - 1)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(heads // head_tile, length // key_tile),
+        in_specs=[
+            pl.BlockSpec((head_tile, n_q, d), lambda g, j, at: (g, 0, 0)),
+            pl.BlockSpec((head_tile, n_q, d_r), lambda g, j, at: (g, 0, 0)),
+            pl.BlockSpec((None, head_tile, key_tile, d),
+                         lambda g, j, at: (at[0], g, tile(j, at), 0)),
+            pl.BlockSpec((None, key_tile, d_r),
+                         lambda g, j, at: (at[0], tile(j, at), 0)),
+            pl.BlockSpec((None, head_tile, key_tile, d_v),
+                         lambda g, j, at: (at[0], g, tile(j, at), 0)),
+            pl.BlockSpec((n_q, key_tile),
+                         lambda g, j, at: (0, tile(j, at)))],
+        out_specs=pl.BlockSpec((head_tile, n_q, d_v),
+                               lambda g, j, at: (g, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((head_tile, n_q, d_v), jnp.float32),
+                        pltpu.VMEM((head_tile, n_q, 1), jnp.float32),
+                        pltpu.VMEM((head_tile, n_q, 1), jnp.float32)])
+    return pl.pallas_call(
+        functools.partial(_attend_kernel, scale=scale),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((heads, n_q, d_v), v.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_BYTES),
+        name="selected_attention_step",
+        interpret=interpret,
+    )(at, q.transpose(1, 0, 2), q_shared.transpose(1, 0, 2), k, k_shared, v,
+      keys.astype(jnp.int8))
+
+
 def selected_attention(q_fn: Callable, k, k_shared, v, idx_fn: Callable,
                        k_idx, *, top_k: int, scale: float, block: int = 256,
                        chunk: int = 2048, scope: str = ""):
@@ -165,11 +361,14 @@ def selected_attention(q_fn: Callable, k, k_shared, v, idx_fn: Callable,
     keys. ``L`` must be a multiple of ``block`` (or smaller)."""
     b_sz, length, heads, _ = k.shape
     d_v = v.shape[-1]
-    block = min(block, length)
-    chunk = math.gcd(length, max(chunk, block))
+    block, chunk = block_and_chunk(length, block, chunk)
     if length % block:
         raise ValueError(f"length {length} is not a multiple of {block}")
     n_blk = length // block
+    fused = attention_path(heads, block, chunk, k.shape[-1],
+                           k_shared.shape[-1], d_v, k.dtype) == "fused"
+    if fused:       # by head, once a layer: what the kernel tiles
+        k_heads, v_heads = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
 
     def one(n):
         b, i = n // n_blk, n % n_blk
@@ -194,33 +393,16 @@ def selected_attention(q_fn: Callable, k, k_shared, v, idx_fn: Callable,
             keys = jnp.arange(length, dtype=jnp.int32)[None, :] \
                 <= t_pos[:, None]
         q, q_shared = q_fn(b, i * block)
-
-        def attend_chunk(j, carry):
-            acc, m, den = carry
-            kc = jax.lax.dynamic_slice_in_dim(k[b], j * chunk, chunk, 0)
-            ks = jax.lax.dynamic_slice_in_dim(k_shared[b], j * chunk, chunk,
-                                              0)
-            vc = jax.lax.dynamic_slice_in_dim(v[b], j * chunk, chunk, 0)
-            seen = jax.lax.dynamic_slice_in_dim(keys, j * chunk, chunk,
-                                                1)[None]
-            s = jnp.where(seen, _scores(q, q_shared, kc, ks, scale), _NEG)
-            m_new = jnp.maximum(m, s.max(-1))
-            # the mask multiply: in a chunk where a query sees nothing
-            # exp(NEG - NEG) = 1 would add mass that is not there
-            p = jnp.exp(s - m_new[..., None]) * seen
-            fix = jnp.exp(m - m_new)
-            acc = acc * fix[..., None] + jnp.einsum(
-                "hqk,khd->hqd", p.astype(v.dtype), vc,
-                preferred_element_type=jnp.float32)
-            return acc, m_new, den * fix + p.sum(-1)
-
-        acc, _, den = jax.lax.fori_loop(
-            0, n_chunks, attend_chunk,
-            (jnp.zeros((heads, block, d_v), jnp.float32),
-             jnp.full((heads, block), _NEG, jnp.float32),
-             jnp.zeros((heads, block), jnp.float32)))
-        out = (acc / den[..., None]).transpose(1, 0, 2).astype(v.dtype)
-        return (out,) + _key_taps(keys)
+        if fused:
+            tile = key_tile_for(chunk)
+            out = _attend_fused(
+                q, q_shared, k_heads, k_shared, v_heads, keys, b,
+                ((i + 1) * block + tile - 1) // tile, scale=scale,
+                key_tile=tile)
+        else:
+            out = _attend_xla(q, q_shared, k, k_shared, v, keys, b,
+                              n_chunks, chunk=chunk, scale=scale)
+        return (out.transpose(1, 0, 2).astype(v.dtype),) + _key_taps(keys)
 
     out, n_keys, first = jax.lax.map(one, jnp.arange(b_sz * n_blk))
     return (out.reshape(b_sz, length, heads, d_v),

@@ -17,9 +17,12 @@ ends in one sync.
 
 Spans: ``seq.score_pass`` (root) with one ``seq.step`` child per step
 (the host's dispatch of it; attrs ``length_class``, ``routes``,
-``real_tokens``, ``padded_tokens``) and ``seq.wait`` (the sync). As
-every recorded span they are ``TraceAnnotation``s too. Counters:
-``rtpu_seq_tokens_total{kind=real|padded}`` from the plan; and, read
+``real_tokens``, ``padded_tokens``, ``attention``: the online-softmax
+step its full layers run, ``fused`` or ``xla``) and ``seq.wait`` (the
+sync). As every recorded span they are ``TraceAnnotation``s too.
+Counters: ``rtpu_seq_tokens_total{kind=real|padded}`` and
+``rtpu_seq_attention_chunks_total{path=fused|xla}`` (the full layers'
+steps over chunks of keys) from the plan; and, read
 from the device once a pass after its sync, ``rtpu_seq_expert_tokens
 {stat=max|mean}`` (tokens per held expert per step and layer),
 ``rtpu_seq_expert_load_max_over_mean``, ``rtpu_seq_held_assignment_
@@ -47,6 +50,11 @@ def _seq_metrics():
                 "rtpu_seq_tokens_total",
                 "Tokens of scored routes (real) and of the padding "
                 "computed beside them (padded).", ("kind",)),
+            "chunks": reg.counter(
+                "rtpu_seq_attention_chunks_total",
+                "Online-softmax steps over chunks of keys that the full "
+                "layers of the dispatched steps ran, by the form of the "
+                "step (fused: the Pallas kernel; xla).", ("path",)),
             "expert_tokens": reg.gauge(
                 "rtpu_seq_expert_tokens",
                 "Tokens a held expert got in one step of one expert "
@@ -262,7 +270,9 @@ class RouteScorer:
                 with trace_span("seq.step", length_class=step.length,
                                 routes=int((step.routes >= 0).sum()),
                                 real_tokens=step.real_tokens,
-                                padded_tokens=step.padded_tokens):
+                                padded_tokens=step.padded_tokens,
+                                attention=self.model.selected_steps(
+                                    step.length)[0]):
                     tables, st = self._step(
                         self.params, ids, lengths, rows_at,
                         jnp.asarray(step.routes, jnp.int32), tables,
@@ -280,6 +290,12 @@ class RouteScorer:
         m = _seq_metrics()
         m["tokens"].labels(kind="real").inc(real)
         m["tokens"].labels(kind="padded").inc(padded)
+        n_full = sum(1 for a, _ in self.model.layer_kinds()
+                     if a == "full_attention")
+        for step in plan:
+            path, chunks = self.model.selected_steps(step.length)
+            m["chunks"].labels(path=path).inc(
+                chunks * len(step.routes) * n_full)
         counts = [np.asarray(s["counts"], np.float64) for s in stats
                   if "counts" in s]
         if counts:
@@ -297,6 +313,4 @@ class RouteScorer:
         picked = [float(s["selected_keys"]) for s in stats
                   if "selected_keys" in s]
         if picked:
-            n_full = sum(1 for a, _ in self.model.layer_kinds()
-                         if a == "full_attention")
             m["selected"].set(sum(picked) / max(1, real * n_full))

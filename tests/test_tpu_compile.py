@@ -97,3 +97,28 @@ def test_oversized_tile_is_refused_by_the_kernel_not_by_mosaic(v5e):
     packed_f32 = _on(v5e, pack_eta_params(model, params, dtype="f32"))
     with pytest.raises(ValueError, match=f"{MAX_TILE_F32}"):
         fused_eta_forward.lower(packed_f32, x, n_q=3, tile=MAX_TILE)
+
+
+# The route-sequence model's full layers at the cell's widths: the
+# longest length class (one route) and the shortest (three routes, the
+# chunk cut to 512 keys).
+@pytest.mark.parametrize("routes,length", [(1, 26624), (3, 1536)])
+def test_selected_attention_step_compiles_for_v5e(v5e, routes, length):
+    from routest_tpu.parallel import select
+
+    heads, block, d, d_shared, d_v = 128, 256, 128, 64, 128
+    block, chunk = select.block_and_chunk(length, block, 2048)
+    assert select.attention_path(heads, block, chunk, d, d_shared, d_v,
+                                 jnp.bfloat16, backend="tpu") == "fused"
+
+    def on(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    compiled = jax.jit(functools.partial(
+        select._attend_fused, scale=192 ** -0.5,
+        key_tile=select.key_tile_for(chunk))).lower(
+        on((block, heads, d)), on((block, heads, d_shared)),
+        on((routes, heads, length, d)), on((routes, length, d_shared)),
+        on((routes, heads, length, d_v)), on((block, length), jnp.bool_),
+        on((), jnp.int32), on((), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()   # Mosaic, not interpret
