@@ -27,7 +27,7 @@ every phase is a child process, run one after another.
             at 131,072 rows; the ROUTEST_FUSED=1 serving path; the
             partition-overlay router on an 8,192-node metro extract; a
             few ``fit`` steps at batch 8192 with save -> load ->
-            identical predictions; ``bench.py``'s measurement once
+            identical predictions
 
 Each phase prints one JSON object per line; any failed phase makes the
 exit code non-zero. The LAST line of stdout on success is exactly
@@ -61,7 +61,7 @@ TOTAL_BUDGET_S = 1140
 
 TOL = {"float32": (1e-4, 1e-3), "bfloat16": (2e-2, 0.5)}
 BUCKETS = (8, 64, 512, 1024, 2048, 4096)   # ServeConfig.batch_buckets
-BIG_BATCH = 131072                         # bench.py's offline batch
+BIG_BATCH = 131072                         # one slice of the od-score cell
 # Log events that mean a device path was quietly replaced by another
 # (ISSUE 21 section 2): seeing one in a server's log fails the phase.
 FALLBACK_EVENTS = (
@@ -159,7 +159,7 @@ def main() -> int:
     if args.phase:
         return run_child(args)
 
-    missing = [p for p in ("routest_tpu", "bench.py",
+    missing = [p for p in ("routest_tpu",
                            os.path.join("artifacts", "eta_mlp.msgpack"))
                if not os.path.exists(os.path.join(REPO, p))]
     if missing:
@@ -216,7 +216,7 @@ def phase_device(args) -> dict:
     check(device["count"] == args.chips,
           f"--chips {args.chips} but JAX sees {device['count']} devices")
     sys.path.insert(0, REPO)
-    from bench import chip_peaks
+    from routest_tpu.core.mesh import chip_peaks
 
     chip_peaks(device["kind"])   # an unknown kind raises
     return {"device": device, "jax": jax.__version__}
@@ -818,8 +818,7 @@ def phase_programs(args) -> dict:
     for name, fn in (("fused_kernel", programs_fused_kernel),
                      ("fused_serving", programs_fused_serving),
                      ("overlay_router", programs_overlay_router),
-                     ("train", programs_train),
-                     ("bench", programs_bench)):
+                     ("train", programs_train)):
         t0 = time.time()
         out[name] = fn(args, rng)
         out[name]["wall_s"] = round(time.time() - t0, 1)
@@ -989,13 +988,6 @@ def programs_train(args, rng) -> dict:
                                     // cfg.batch_size),
             "epoch_losses": [round(float(v), 4) for v in losses],
             "eval_rmse_min": round(result.eval_rmse, 3), "artifact": path}
-
-
-def programs_bench(args, rng) -> dict:
-    """(e) bench.py's measurement body, once."""
-    import bench
-
-    return {"record": bench.measure()}
 
 
 # ── phase: mesh_serve (--chips 4) ────────────────────────────────────

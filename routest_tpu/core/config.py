@@ -76,7 +76,7 @@ class ServeConfig:
     # latency; the 1024/2048 steps bound pad waste for mid-size batches
     # (a 1024-row request used to pad 4× to the 4096 bucket).
     batch_buckets: Tuple[int, ...] = (8, 64, 512, 1024, 2048, 4096)
-    # AOT serving entry (docs/PERFORMANCE.md "Scoring artifact"): the
+    # AOT serving entry: the
     # full score program is ``jit().lower().compile()``d per bucket at
     # startup with the input slab donated, so no bucket ever pays
     # trace+compile (or jit dispatch overhead) on a customer request.
@@ -85,7 +85,7 @@ class ServeConfig:
     # Model hot-reload: poll the artifact every N seconds and swap a
     # changed file in without a restart. 0 (default) disables.
     reload_sec: float = 0.0
-    # Serving fast lane (docs/PERFORMANCE.md): a content-addressed
+    # Serving fast lane: a content-addressed
     # prediction cache + singleflight in front of the batcher, and an
     # adaptive flush window inside it. All RTPU_FASTLANE_* env-tunable.
     # The cache is semantically invisible — the model is a pure function
@@ -1247,12 +1247,10 @@ KNOWN_KNOBS: Mapping[str, str] = {
     "RTPU_PROCESS_ID": "this process's index in the multi-process world",
     # Serving kernel / scoring artifact.
     "ROUTEST_FUSED": "fused Pallas kernel opt-in/out for scoring",
-    "ROUTEST_KERNEL_BENCH": "kernel selection-table path (bench record)",
     "RTPU_KERNEL_DTYPE": "kernel weight/compute variant: bf16/f32/int8",
     "ROUTEST_WARM_BUCKETS": "batch buckets warmed at serving bring-up",
     # Road router / overlay / route fastlane (ROUTEST_HIER_* build
-    # knobs are part of the overlay cache fingerprint — see
-    # docs/PERFORMANCE.md §5).
+    # knobs are part of the overlay cache fingerprint).
     "ROUTEST_HIER_CACHE": "overlay cache directory (off = rebuild)",
     "ROUTEST_HIER_CELL_TARGET": "partition ladder base cell size",
     "ROUTEST_HIER_RATIO": "partition ladder growth ratio per level",

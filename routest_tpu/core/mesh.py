@@ -19,6 +19,33 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from routest_tpu.core.config import MeshConfig
 
 
+# Dense peak (TFLOP/s for bf16 matmul, HBM GB/s) by device_kind
+# substring, lowercase. Sources: public TPU spec sheets (one v5e chip:
+# Google Cloud documentation, "TPU v5e"). JAX reports a v5e as
+# "TPU v5 lite".
+_CHIP_PEAKS = {
+    "v5 lite": (197.0, 819.0), "v5e": (197.0, 819.0),
+    "v5p": (459.0, 2765.0),
+    "v4": (275.0, 1228.0),
+    "v3": (123.0, 900.0),
+    "v6": (918.0, 1640.0), "trillium": (918.0, 1640.0),
+}
+
+
+def chip_peaks(device_kind: str):
+    """(peak_tflops_bf16, peak_hbm_gbps) for a TPU ``device_kind``.
+
+    An unknown kind raises: a utilization computed against a guessed
+    peak is worse than none, so a new chip gets a table row first."""
+    kind = (device_kind or "").lower()
+    for key, peaks in _CHIP_PEAKS.items():
+        if key in kind:
+            return peaks
+    raise ValueError(
+        f"no peak-rate table row for device kind {device_kind!r}; add "
+        f"one to core.mesh._CHIP_PEAKS with its source")
+
+
 def create_mesh(cfg: Optional[MeshConfig] = None,
                 devices: Optional[Sequence[jax.Device]] = None) -> Mesh:
     cfg = cfg or MeshConfig()

@@ -9,9 +9,7 @@ structured reports with server-side registry deltas. Deterministic by
 contract: the same seed reproduces the same schedule and the same
 request sequence, so two benches can offer literally identical load.
 
-Consumers: ``scripts/load_test.py --open-loop``,
-``scripts/bench_autoscale.py``, and any later bench that needs to
-prove a latency claim under realistic traffic.
+Consumer: ``scripts/load_test.py --open-loop``.
 """
 
 from routest_tpu.loadgen.arrivals import (RateCurve, paced_schedule,

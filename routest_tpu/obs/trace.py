@@ -197,8 +197,7 @@ class Tracer:
     """Creates spans, owns the sampling decision and the span buffer.
 
     - ``enabled=False``: ``span()`` yields the shared no-op; nothing is
-      recorded or propagated (the measured-off mode of
-      ``scripts/bench_obs_overhead.py``).
+      recorded or propagated.
     - Root spans sample with probability ``sample_rate``; child spans
       inherit the root's decision (whole traces, never fragments).
     - ``export_path``: every finished sampled span is also appended as

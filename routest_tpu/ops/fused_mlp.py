@@ -4,14 +4,12 @@ This kernel runs the whole forward — feature expansion, normalization,
 the matmul chain, and the ``pace·dist + overhead`` epilogue — in ONE
 ``pallas_call``, so no activation ever round-trips HBM.
 
-**Selection is measured, not asserted.** SURVEY.md §7.1's rule is "a
+**XLA serves; the kernel is opt-in.** SURVEY.md §7.1's rule is "a
 Pallas kernel is justified only if XLA fails to fuse — benchmark
-first": ``scripts/bench_serving_kernel.py`` records a per-batch-size
-head-to-head on the real chip (``artifacts/kernel_bench.json``) and
-``serve/ml_service.py`` auto-serves the kernel exactly for the batch
-sizes where that record says it wins (``ROUTEST_FUSED`` unset = auto;
-``1``/``0`` force). ``bench.py`` measures both paths and reports the
-faster.
+first". No benchmark cell times this kernel, so ``serve/ml_service.py``
+serves the XLA path unless ``ROUTEST_FUSED=1`` forces the kernel on a
+TPU; its one chip reading is a loss at 131,072 rows (``PERF.md`` §6,
+PR 21).
 
 Bandwidth accounting (physical, not logical): TPU HBM stores f32
 arrays in (8, 128) tiles with the minor dim padded to 128 lanes, so
@@ -25,13 +23,10 @@ and when the batch divides the tile the input pad-copy is skipped
 entirely, so the kernel's HBM bill is one input read + one output
 write. The kernel's structural edge over XLA remains keeping every
 inter-layer activation in VMEM (XLA spills ~3 KB/row of bf16
-activations for this trunk at large batches — the measured
-bandwidth-bound regime in bench.py's roofline); its structural
+activations for this trunk at large batches); its structural
 overheads remain the 42→128 MXU row padding (~35% extra matmul FLOPs,
 irrelevant while bandwidth-bound) and Mosaic serializing the per-tile
 VPU expansion against the MXU chain, which XLA overlaps across tiles.
-The recorded kernel_bench table is the arbiter of where that nets out
-per batch size.
 
 Design notes:
 
@@ -347,7 +342,7 @@ def fused_eta_forward(packed: Packed, x: jax.Array, *, n_q: int = 0,
     b_pad = _round_up(b_rows, tile)
 
     # Row padding only, and none at all when the batch divides the tile
-    # (serving buckets and the bench batch do): the kernel then reads
+    # (serving buckets do): the kernel then reads
     # the caller's buffer directly instead of paying a pad-copy pass.
     if b_pad == b_rows:
         xp = x.astype(jnp.float32)

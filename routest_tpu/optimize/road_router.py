@@ -664,10 +664,9 @@ class RoadRouter:
                 info["aot_compile_s"] = self._aot_compile_s
         else:
             info = {"solver": "flat_bf", "max_iters_bound": self.max_iters}
-        # Routing fast-path provenance (docs/PERFORMANCE.md §7): the
-        # solve batcher's merged-dispatch stats and the route
-        # fastlane's hit/byte counters, for health and the serving
-        # bench artifact.
+        # Routing fast-path provenance: the solve batcher's
+        # merged-dispatch stats and the route fastlane's hit/byte
+        # counters, for health.
         if self._solve_batcher is not None:
             info["batch"] = self._solve_batcher.stats()
         if self._route_cache is not None:

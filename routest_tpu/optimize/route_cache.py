@@ -17,7 +17,7 @@ behind a request) keyed by::
   ``install_live_metric`` flip) and the router's model generation
   (bumped by every verified road-GNN swap) are IN the key, so no
   cached route can outlive either flip — the same coherency contract
-  the prediction cache carries (docs/PERFORMANCE.md "Cache coherency").
+  the prediction cache carries.
   TTL is a freshness backstop on top, not the correctness mechanism.
 - **Byte-budgeted LRU**: a cached solve pins (M, N) predecessor and
   distance rows — megabytes per entry at metro scale — so the budget

@@ -14,9 +14,8 @@ activations per device.
 
 Ring keeps even K/V residency at O(S/n) and overlaps its hops; Ulysses
 wins at moderate S where collective count dominates. Both are exposed
-so a sequence model can pick per workload
-(``artifacts/transformer_report.json`` ``seq_scaling`` carries the
-measured curve).
+so a sequence model can pick per workload (which wins where is not
+measured on the chip).
 """
 
 from __future__ import annotations

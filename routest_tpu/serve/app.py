@@ -26,6 +26,7 @@ import numpy as np
 from werkzeug.wrappers import Response
 
 from routest_tpu.core.config import Config, load_config, load_wire_config
+from routest_tpu.core.mesh import chip_peaks
 from routest_tpu.data.locations import locations_table
 from routest_tpu.obs import get_registry
 from routest_tpu.obs.ledger import record_change
@@ -645,7 +646,7 @@ def create_app(config: Optional[Config] = None,
         # was the single largest cost of serving quantile bands (a
         # measured ~18 ms per 4096-row response vs ~5 ms vectorized —
         # most of the old point-vs-quantile throughput gap lived HERE,
-        # not in the model's extra heads; docs/PERFORMANCE.md).
+        # not in the model's extra heads; a CPU reading).
         minutes = np.asarray(minutes, np.float64)
         finite = np.isfinite(minutes)
         all_finite = bool(finite.all())
@@ -1542,54 +1543,21 @@ def _device_memory(jax) -> dict:
     return out
 
 
-_roofline_cache: dict = {"mtime": None, "value": None}
-
-
 def _tpu_roofline(jax) -> dict:
-    """Chip identity + peak table + the last recorded bench roofline
-    (achieved TFLOP/s, MFU, HBM GB/s: these gauges must be readable from
-    the serving surface, not reconstructed by a reviewer). The bench artifact is the measurement of record; health
-    only surfaces it, never re-runs it — and caches the parse on the
-    file's mtime, because orchestrators poll health every few seconds
-    while the artifact changes once per bench run."""
+    """Chip identity + the peak-table row that utilization figures are
+    computed against: readable from the serving surface, not
+    reconstructed by a reviewer."""
     device = jax.devices()[0]
     out: dict = {"device_kind": device.device_kind}
     if device.platform != "cpu":
         # An accelerator the peak table does not know is reported, not
         # omitted: health stays up and says which row is missing.
         try:
-            from bench import chip_peaks  # repo-root bench owns the table
-
             out["peak_tflops_bf16"], out["peak_hbm_gbps"] = chip_peaks(
                 device.device_kind)
-        except (ImportError, ValueError) as e:
+        except ValueError as e:
             out["peaks_error"] = f"{type(e).__name__}: {e}"
             _log.warning("chip_peaks_unavailable", error=out["peaks_error"])
-    try:
-        import json as _json
-
-        path = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__)))), "artifacts", "bench_tpu.json")
-        mtime = os.stat(path).st_mtime_ns
-        if _roofline_cache["mtime"] != mtime:
-            with open(path) as f:
-                rec = _json.load(f)
-            roof = rec.get("roofline")
-            _roofline_cache["value"] = {
-                "preds_per_sec": rec.get("value"),
-                "recorded_unix": rec.get("recorded_unix"),
-                **{k: roof[k] for k in ("tflops", "mfu",
-                                        "hbm_gbps_lower_bound",
-                                        "hbm_gbps_upper_model")
-                   if k in roof},
-            } if roof else None
-            _roofline_cache["mtime"] = mtime
-        if _roofline_cache["value"]:
-            out["last_bench"] = _roofline_cache["value"]
-    except Exception as e:
-        # Missing/malformed bench artifact: gauge absent, health up.
-        _log.debug("bench_roofline_unavailable",
-                   error=f"{type(e).__name__}: {e}")
     return out
 
 

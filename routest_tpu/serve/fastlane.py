@@ -173,9 +173,9 @@ class FastLane:
             return compute(rows)
         # ONE tobytes for the whole batch, then per-row slices: a
         # per-row rows[i].tobytes() loop was measurable fixed overhead
-        # at the 1024-row request size (docs/PERFORMANCE.md "Scoring
-        # artifact" — the fast lane sits on the decomposition's fixed-
-        # cost side, so per-row python here is paid by every request).
+        # at the 1024-row request size (the fast lane sits on the
+        # fixed-cost side, so per-row python here is paid by every
+        # request).
         width = rows.shape[1] * rows.itemsize
         if blob is not None and len(blob) == n * width:
             self._m_wire_blob.inc(n)

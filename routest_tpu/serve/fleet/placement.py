@@ -3,7 +3,7 @@
 Until now the fleet layer was device-blind: the supervisor spawned N
 copies of one chip and the gateway assumed every replica had equal
 capacity, so the compute side's multi-chip serving paths (mesh batch
-shardings, DP/TP scoring — MULTICHIP_r05) had no fleet that could
+shardings, DP/TP scoring) had no fleet that could
 actually *spend* more than one chip. This module is the missing map
 from "what does this host have" to "what do we boot":
 
@@ -249,8 +249,8 @@ def model_rate(chips: int, mesh_eff: float) -> float:
 def measured_rates(record_path: str,
                    platform: Optional[str] = None
                    ) -> Optional[Dict[int, float]]:
-    """chips → preds/s from a recorded ``bench_fleet_chips.py``
-    artifact, or None when absent/unreadable (LOUDLY: a corrupt record
+    """chips → preds/s from a recorded ``fleet_chips.json``
+    curve, or None when absent/unreadable (LOUDLY: a corrupt record
     must not silently change placement). With ``platform``, a record
     measured on a DIFFERENT backend is refused — a CPU-virtual curve
     says nothing about real-chip scaling, so TPU placement falls back

@@ -21,7 +21,7 @@ import math
 import os
 import threading
 import time
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
@@ -52,7 +52,7 @@ class _ServingState:
     fast-lane prediction cache keys on it, so a hot-reload makes every
     cached prediction of the OLD model unreachable the instant the
     snapshot flips — cache coherency falls out of the same one-flip
-    design that prevents torn reads (docs/PERFORMANCE.md)."""
+    design that prevents torn reads."""
 
     __slots__ = ("model", "batcher", "quantiles", "generation")
 
@@ -81,10 +81,10 @@ _m_swaps = get_registry().counter(
 _m_generation = get_registry().gauge(
     "rtpu_model_generation",
     "Generation id of the live serving model (monotonic per process).")
-# Scoring-artifact observability (docs/PERFORMANCE.md "Scoring
-# artifact"): one observation per AOT bucket compile at bring-up. The
-# per-bucket COUNT doubles as the "no compile after startup" assertion —
-# if it ever grows while serving, a customer request paid a compile.
+# Scoring-artifact observability: one observation per AOT bucket compile
+# at bring-up. The per-bucket COUNT doubles as the "no compile after
+# startup" assertion — if it ever grows while serving, a customer request
+# paid a compile.
 _m_aot_compile = get_registry().histogram(
     "rtpu_replica_aot_compile_seconds",
     "AOT compile of the score program per batch bucket "
@@ -676,8 +676,8 @@ class DynamicBatcher:
                     # input): the detached slab re-enters circulation
                     # only HERE, after the flush's device call fully
                     # consumed its copy — an in-flight donated buffer
-                    # is never rewritten (docs/PERFORMANCE.md §6;
-                    # fuzzed in test_scoring_artifact.py).
+                    # is never rewritten (fuzzed in
+                    # test_scoring_artifact.py).
                     if batch_slab is not None and self._spare is None:
                         self._spare = batch_slab
                     more = self._queued_rows >= self._drain_cap
@@ -700,10 +700,9 @@ class EtaService:
         self._error: Optional[str] = None
         # Scoring-artifact introspection (scoring_info() / health):
         # which compute path serves, at what dtype, with which buckets
-        # AOT-compiled, selected by which measured record.
+        # AOT-compiled.
         self.kernel_dtype: Optional[str] = None
         self._aot_buckets: Tuple[int, ...] = ()
-        self._win_provenance: dict = {}
         self._path = model_path or default_model_path()
         self._loaded_mtime_ns = self._artifact_mtime_ns()
         self._reload_lock = threading.Lock()
@@ -1012,134 +1011,46 @@ class EtaService:
         self.kernel = "xla_tp"
         return score
 
-    @staticmethod
-    def _fused_selection() -> Tuple[int, Dict[int, int], dict]:
-        """(win_bucket, tile_by_batch, provenance) from the measured
-        kernel bench (``artifacts/kernel_bench.json``, written by
-        ``scripts/bench_serving_kernel.py`` — per-bucket slope-timed
-        head-to-head on the real chip). ``win_bucket`` is the largest
-        batch size where the Pallas path wins (0 = no recorded win);
-        ``tile_by_batch`` maps each measured batch size to the kernel
-        tile that won its sweep, so serving replays the measured
-        configuration instead of a hardcoded tile; ``provenance`` names
-        the record (path / backend / recorded_unix) so health can answer
-        "which measurement chose this kernel".
-        ``ROUTEST_KERNEL_BENCH`` relocates the record (deployments that
-        move artifacts out of the repo tree)."""
-        path = os.environ.get("ROUTEST_KERNEL_BENCH") or os.path.join(
-            os.path.dirname(os.path.dirname(os.path.dirname(
-                os.path.abspath(__file__)))), "artifacts", "kernel_bench.json")
-        try:
-            import json
-
-            with open(path) as f:
-                rec = json.load(f)
-            provenance = {"path": path,
-                          "backend": rec.get("backend")
-                          if isinstance(rec, dict) else None,
-                          "recorded_unix": rec.get("recorded_unix")
-                          if isinstance(rec, dict) else None}
-            if not isinstance(rec, dict) or rec.get("backend") != "tpu":
-                return 0, {}, provenance
-            tiles = {int(r["batch"]): int(r["pallas_tile"])
-                     for r in rec.get("rows", ())
-                     if isinstance(r, dict) and r.get("pallas_tile")}
-            return int(rec.get("pallas_wins_max_bucket") or 0), tiles, \
-                provenance
-        except Exception:  # rtpulint: disable=broad-except-unlogged -- a malformed bench record means "no recorded win"; provenance keeps the path
-            return 0, {}, {"path": path, "backend": None,
-                           "recorded_unix": None}
-
-    @staticmethod
-    def _fused_win_bucket() -> Tuple[int, Dict[int, int]]:
-        """(win_bucket, tile_by_batch) — the selection half of
-        ``_fused_selection`` (kept as the stable introspection point)."""
-        win, tiles, _prov = EtaService._fused_selection()
-        return win, tiles
-
     def _maybe_fused_score(self, fallback):
-        """Measured-selection swap to the fused Pallas kernel
-        (``ops/fused_mlp.py``).
+        """``ROUTEST_FUSED=1`` on a TPU forces the fused Pallas kernel
+        (``ops/fused_mlp.py``) at its own default tile for every batch;
+        anything else serves ``fallback``.
 
-        ``ROUTEST_FUSED``: "1" forces the kernel for every batch, "0"
-        forces XLA. Unset is AUTO: serve the kernel exactly for the
-        batch-size regime where the recorded head-to-head bench says it
-        wins (small buckets, where one fused dispatch beats XLA's
-        kernel chain) and XLA everywhere else — the per-size winner
-        table is ``artifacts/kernel_bench.json``, re-measured by
-        ``scripts/bench_serving_kernel.py``. Probed eagerly with one
-        row: in AUTO mode any pack/compile failure (unexpected param
-        shapes, Mosaic regressions) keeps the XLA path with a
-        ``fused_kernel_unavailable`` warning; a kernel FORCED on a TPU
-        that fails raises instead.
+        Probed eagerly with one row: the operator forced the kernel, so
+        a pack/compile failure (unexpected param shapes, Mosaic
+        regressions) raises — serving XLA under that setting would hide
+        it. Off the TPU the switch is ignored with a
+        ``fused_kernel_ignored`` warning.
         """
-        mode = os.environ.get("ROUTEST_FUSED", "auto")
-        if mode == "0":
-            return fallback
-        recorded_bucket, tile_by_batch, provenance = self._fused_selection()
-        self._win_provenance = dict(provenance,
-                                    pallas_wins_max_bucket=recorded_bucket)
-        win_bucket = None if mode == "1" else recorded_bucket
-        if win_bucket == 0:
+        if os.environ.get("ROUTEST_FUSED") != "1":
             return fallback
         if jax.default_backend() != "tpu":
             # Compiled Mosaic needs a TPU; interpreter mode would "work"
             # but orders of magnitude slower — never serve it.
-            if mode == "1":
-                from routest_tpu.utils.logging import get_logger
-
-                get_logger("routest_tpu.serve").warning(
-                    "fused_kernel_ignored",
-                    reason=f"ROUTEST_FUSED=1 needs the TPU backend, "
-                           f"have {jax.default_backend()}; serving XLA")
-            return fallback
-        try:
-            from routest_tpu.ops import (fused_eta_forward, pack_eta_params,
-                                         resolve_kernel_dtype)
-
-            variant = resolve_kernel_dtype(self._model)
-            packed = jax.device_put(
-                pack_eta_params(self._model, self._params, dtype=variant))
-            n_q = len(self.quantiles)
-            # Replay the measured tile: smallest benched batch that
-            # covers this request's rows (bench batches are the serving
-            # buckets, so warm paths hit exact matches); default to the
-            # kernel's built-in tile when nothing matches.
-            tile_sizes = sorted(tile_by_batch)
-
-            def fused(x: np.ndarray) -> np.ndarray:
-                tile = next((tile_by_batch[b] for b in tile_sizes
-                             if len(x) <= b), None)
-                kw = {} if tile is None else {"tile": tile}
-                return fused_eta_forward(packed, jax.numpy.asarray(x),
-                                         n_q=n_q, **kw)
-
-            if win_bucket is None:
-                score = fused                       # forced: all batches
-                self.kernel = "pallas_fused"
-            else:
-                def score(x: np.ndarray) -> np.ndarray:
-                    if len(x) <= win_bucket:
-                        return fused(x)
-                    return fallback(x)
-
-                self.kernel = f"pallas_fused(<= {win_bucket})+xla"
-            probe = np.zeros((1, self._model.n_features), np.float32)
-            if not np.isfinite(np.asarray(fused(probe))).all():
-                raise ValueError("fused kernel probe produced non-finite output")
-            self.kernel_dtype = variant
-            return score
-        except Exception as e:  # pragma: no cover - depends on backend
-            if mode == "1":
-                # The operator forced the kernel on a TPU: serving XLA
-                # under that setting would hide the failure.
-                raise
             from routest_tpu.utils.logging import get_logger
 
             get_logger("routest_tpu.serve").warning(
-                "fused_kernel_unavailable", error=f"{type(e).__name__}: {e}")
-            self.kernel = "xla"
+                "fused_kernel_ignored",
+                reason=f"ROUTEST_FUSED=1 needs the TPU backend, "
+                       f"have {jax.default_backend()}; serving XLA")
             return fallback
+        from routest_tpu.ops import (fused_eta_forward, pack_eta_params,
+                                     resolve_kernel_dtype)
+
+        variant = resolve_kernel_dtype(self._model)
+        packed = jax.device_put(
+            pack_eta_params(self._model, self._params, dtype=variant))
+        n_q = len(self.quantiles)
+
+        def fused(x: np.ndarray) -> np.ndarray:
+            return fused_eta_forward(packed, jax.numpy.asarray(x), n_q=n_q)
+
+        probe = np.zeros((1, self._model.n_features), np.float32)
+        if not np.isfinite(np.asarray(fused(probe))).all():
+            raise ValueError("fused kernel probe produced non-finite output")
+        self.kernel = "pallas_fused"
+        self.kernel_dtype = variant
+        return fused
 
     def _load(self, path: str) -> None:
         # Chaos fault point: a bad deploy's first observable failure is
@@ -1258,7 +1169,6 @@ class EtaService:
             self.kernel = fresh.kernel
             self.kernel_dtype = fresh.kernel_dtype
             self._aot_buckets = fresh._aot_buckets
-            self._win_provenance = fresh._win_provenance
             self._error = None
             self._loaded_mtime_ns = fresh._loaded_mtime_ns
             self.fingerprint = fresh.fingerprint
@@ -1376,18 +1286,13 @@ class EtaService:
     def scoring_info(self) -> dict:
         """The scoring artifact's identity card (health's model block,
         mirroring the road_router block): which compute path serves
-        (kernel), at what dtype, which buckets are AOT-compiled, and —
-        when measured selection is in play — which recorded bench chose
-        the win bucket (provenance: record path/backend/timestamp)."""
-        info = {
+        (kernel), at what dtype, and which buckets are AOT-compiled."""
+        return {
             "kernel": self.kernel,
             "dtype": self.kernel_dtype,
             "aot": bool(self._aot_buckets),
             "aot_buckets": list(self._aot_buckets),
         }
-        if self._win_provenance:
-            info["win_bucket"] = self._win_provenance
-        return info
 
     def mesh_info(self) -> dict:
         """The replica's device topology at a glance (health's

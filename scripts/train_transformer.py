@@ -179,11 +179,10 @@ def main() -> None:
         report["osm"] = args.osm
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     out = os.path.join(repo, "artifacts", "transformer_report.json")
-    # Preserve cross-run sections: the SP seq-scaling curve
-    # (scripts/bench_sp_scaling.py) and the polyline-length training run
-    # land in the same report under their own keys, so the serving-graph
-    # run and the long-sequence run document each other rather than
-    # overwriting.
+    # Preserve cross-run sections: the recorded SP seq-scaling curve
+    # and the polyline-length training run land in the same report
+    # under their own keys, so the serving-graph run and the
+    # long-sequence run document each other rather than overwriting.
     prior = {}
     if os.path.exists(out):
         try:

@@ -627,3 +627,49 @@ def test_rule_catalog_metadata():
                 "ledger-kind-unregistered", "ledger-kind-undocumented",
                 "ledger-kind-stale-doc", "bad-suppression"):
         assert rid in rules, rid
+
+
+# ---------------------------------------------------------------------------
+# The arrow between the program and what measures it points one way
+
+# Records under artifacts/ the package may name: the CPU-baseline golden,
+# the training reports of committed models, and the two frozen records
+# placement and the efficiency watchdog read (ROADMAP D3).
+_ARTIFACT_RECORDS = {
+    "baseline.json", "training_report.json", "transformer_report.json",
+    "gnn_report.json", "gnn_report_osm.json", "gnn_report_manila.json",
+    "fleet_chips.json", "serving_kernel.json"}
+
+
+def test_the_package_reads_no_benchmark():
+    """No module of the package imports a benchmark, a script or the
+    bring-up proof, and none names a record under artifacts/ beyond
+    the allowed ones: what measures the program imports the program,
+    never the other way."""
+    import ast
+    import re
+
+    outside = {"bench", "benchmark", "scripts", "chip_smoke"}
+    imports, records = [], []
+    for src in load_corpus().files:
+        for node in src.nodes():
+            if isinstance(node, ast.Import):
+                mods = [a.name for a in node.names]
+            elif isinstance(node, ast.ImportFrom) and not node.level:
+                mods = [node.module or ""]
+            else:
+                mods = []
+            imports += [(src.relpath, node.lineno, m) for m in mods
+                        if m.split(".")[0] in outside]
+            # os.path.join(..., "artifacts", "<name>.json")
+            if isinstance(node, ast.Call):
+                consts = [a.value for a in node.args
+                          if isinstance(a, ast.Constant)
+                          and isinstance(a.value, str)]
+                if "artifacts" in consts:
+                    records += [(src.relpath, c) for c in consts
+                                if c.endswith(".json")]
+        records += [(src.relpath, m + ".json") for m in
+                    re.findall(r"artifacts/([\w.*{},-]+)\.json", src.text)]
+    assert not imports, imports
+    assert not [r for r in records if r[1] not in _ARTIFACT_RECORDS], records

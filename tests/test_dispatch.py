@@ -4,14 +4,10 @@ batch merging, the /api/dispatch serving surface, the re-optimization
 loop's coherency rules (one epoch one pass, exactly the degraded,
 chaos degrade-don't-fail), SSE plan_update delivery, the loadgen
 ``dispatch`` component's determinism, and the prober's ``dispatch``
-kind. The full-stack measured counterpart is
-``scripts/bench_dispatch.py`` → ``artifacts/dispatch.json``."""
+kind."""
 
 import dataclasses
 import json
-import os
-import subprocess
-import sys
 import threading
 
 import jax
@@ -34,9 +30,6 @@ from routest_tpu.serve.app import create_app
 from routest_tpu.serve.bus import InMemoryBus
 from routest_tpu.serve.ml_service import EtaService
 from routest_tpu.train.checkpoint import save_model
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
 
 def _matrix(n, seed=0, scale=60.0):
     """(n+1, n+1) random symmetric cost matrix, zero diagonal."""
@@ -691,28 +684,3 @@ def test_loadgen_dispatch_component_deterministic(client):
         r.get_json()["plan"]["spill_lane"]
     assert "dispatch" in MixedWorkload.KINDS
     assert a.describe()["dispatch_stops"] == 4
-
-
-# ── bench guardband (slow) ───────────────────────────────────────────
-
-
-@pytest.mark.slow
-def test_dispatch_bench_quick(tmp_path):
-    out = tmp_path / "dispatch.json"
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts",
-                                      "bench_dispatch.py"),
-         "--quick", "--out", str(out),
-         "--cache-dir", str(tmp_path / "cache")],
-        cwd=REPO, timeout=2400, capture_output=True, text=True)
-    assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-2000:]
-    record = json.loads(out.read_text())
-    assert record["all_pass"], record["checks"]
-    for row in record["batch_scaling"]["rows"]:
-        assert row["oracle_parity"], row
-    jam = record["scenarios"]["corridor_jam"]
-    assert jam["checks"]["exactly_the_affected"], jam
-    assert jam["checks"]["plan_update_within_bound"], jam
-    assert jam["checks"]["user_slo_ok"], jam
-    fault = record["scenarios"]["wrong_plan_fault"]
-    assert fault["checks"]["dispatch_probe_paged"], fault

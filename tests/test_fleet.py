@@ -6,8 +6,7 @@ Fast tests run the gateway in-process against stub replica HTTP servers
 the ``tests/test_cross_process.py`` pattern for anything that needs a
 real subprocess (supervisor restart-after-crash). The full-stack fleet
 (real ``python -m routest_tpu.serve`` workers behind the gateway) is
-exercised by ``scripts/bench_fleet.py`` → ``artifacts/fleet_scale.json``
-and the ``slow``-marked integration test at the bottom.
+exercised by the ``slow``-marked integration test at the bottom.
 """
 
 import json
@@ -589,8 +588,7 @@ def test_full_fleet_real_workers_end_to_end():
     """Two real ``python -m routest_tpu.serve`` replicas behind the
     gateway: predictions flow, metrics aggregate, and killing one
     replica mid-traffic stays client-invisible. >30 s (two server
-    boots), hence slow-marked; ``scripts/bench_fleet.py`` records the
-    measured counterpart in ``artifacts/fleet_scale.json``."""
+    boots), hence slow-marked."""
     ports = [_free_port() for _ in range(2)]
     env = dict(os.environ)
     env.update({

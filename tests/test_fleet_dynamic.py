@@ -7,9 +7,7 @@ leaves drain outstanding work before the upstream is dropped. The
 policy tests drive ``Autoscaler.decide`` with synthetic ``Signals`` so
 hysteresis/cooldown/bounds are pinned without any processes; the
 integration tests run the real control loop over stub multi-process
-workers (same harness as ``tests/test_fleet.py``). The full-stack
-measured counterpart is ``scripts/bench_autoscale.py`` →
-``artifacts/autoscale.json``.
+workers (same harness as ``tests/test_fleet.py``).
 """
 
 import http.server

@@ -2,8 +2,8 @@
 map, the gateway's capacity-weighted routing, capacity-weighted
 autoscaler signals, and the invariant that a rolling restart preserves
 each replica's device overlay (stub multi-process workers, same
-harness as ``tests/test_rollout.py``). The measured counterpart is
-``scripts/bench_fleet_chips.py`` → ``artifacts/fleet_chips.json``.
+harness as ``tests/test_rollout.py``). ``artifacts/fleet_chips.json``
+is a frozen CPU-backend record the planner tests read as a fixture.
 """
 
 import json
@@ -512,9 +512,8 @@ def test_rolling_restart_preserves_device_overlay(monkeypatch):
 def test_replica_health_exposes_mesh_topology():
     """The stub mirrors the real replica's ``checks.engine.mesh``
     contract; the REAL implementation is exercised by
-    ``scripts/bench_fleet_chips.py`` (which fails loudly when a pinned
-    replica reports the wrong device count) and surfaced here through
-    the gateway passthrough."""
+    ``chip_smoke.py --chips 4`` (its ``fleet`` phase) and surfaced
+    here through the gateway passthrough."""
     plan = plan_placement(DeviceInventory("tpu", 2, "env"), spec="1x2",
                           record_path="")
     sup, gw, base, ports = _boot_placed_fleet(plan)

@@ -146,9 +146,9 @@ def test_solver_info_shapes(force_hier, monkeypatch):
     flat_info = flat.solver_info
     assert flat_info["solver"] == "flat_bf"
     assert flat_info["max_iters_bound"] == flat.max_iters
-    # The routing fast path's provenance rides along on every regime
-    # (docs/PERFORMANCE.md §7): batcher dispatch stats + route-cache
-    # counters, JSON-serializable for the health row.
+    # The routing fast path's provenance rides along on every regime:
+    # batcher dispatch stats + route-cache counters, JSON-serializable
+    # for the health row.
     assert flat_info["batch"]["dispatches"] == 0
     assert flat_info["route_cache"]["entries"] == 0
     json.dumps(flat_info)

@@ -201,33 +201,3 @@ def test_resolve_kernel_dtype_env(monkeypatch):
     monkeypatch.setenv("RTPU_KERNEL_DTYPE", "fp7")
     with pytest.raises(ValueError):  # unknown variants stay LOUD
         resolve_kernel_dtype(model)
-
-
-def test_fused_win_bucket_parses_measured_record(tmp_path, monkeypatch):
-    """Serving's measured-selection reads (win bucket, tile table) from
-    the kernel bench record; non-TPU or malformed records mean "no
-    recorded win" so auto mode keeps the XLA path."""
-    import json
-
-    from routest_tpu.serve.ml_service import EtaService
-
-    rec = {"backend": "tpu", "pallas_wins_max_bucket": 512, "rows": [
-        {"batch": 8, "pallas_tile": 8, "winner": "pallas"},
-        {"batch": 512, "pallas_tile": 256, "winner": "pallas"},
-        {"batch": 4096, "pallas_tile": 2048, "winner": "xla"},
-        {"batch": 131072, "pallas_us": None},      # errored row: no tile
-    ]}
-    p = tmp_path / "kernel_bench.json"
-    p.write_text(json.dumps(rec))
-    monkeypatch.setenv("ROUTEST_KERNEL_BENCH", str(p))
-    assert EtaService._fused_win_bucket() == (512, {8: 8, 512: 256,
-                                                    4096: 2048})
-
-    p.write_text(json.dumps(dict(rec, backend="cpu", interpret_mode=True)))
-    assert EtaService._fused_win_bucket() == (0, {})
-
-    p.write_text("{not json")
-    assert EtaService._fused_win_bucket() == (0, {})
-
-    monkeypatch.setenv("ROUTEST_KERNEL_BENCH", str(tmp_path / "missing.json"))
-    assert EtaService._fused_win_bucket() == (0, {})

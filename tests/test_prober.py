@@ -2,8 +2,7 @@
 re-derivation across metric-epoch flips, fan-out skew detection over
 stub replicas, correctness-page bundle embedding, probe-rate backoff
 under a down fleet, and the tag-and-exclude plumbing (probe traffic
-must never burn user SLO budget). The full-stack measured counterpart
-is ``scripts/bench_probing.py`` → ``artifacts/probing.json``."""
+must never burn user SLO budget)."""
 
 import http.server
 import json

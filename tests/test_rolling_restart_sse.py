@@ -19,7 +19,7 @@ from routest_tpu.core.config import FleetConfig
 from routest_tpu.serve.fleet.gateway import Gateway
 from routest_tpu.serve.fleet.rollout import rolling_restart
 from routest_tpu.serve.fleet.supervisor import ReplicaSupervisor
-from routest_tpu.serve.netbus import NetBus, start_broker
+from routest_tpu.serve.netbus import Broker, NetBus, start_broker
 
 # A worker that serves the REAL SSE path (bus subscribe with
 # Last-Event-ID resume → sse_stream) without the model stack: what a
@@ -154,6 +154,7 @@ def test_rolling_restart_with_live_sse_zero_dropped_events():
         env=env, probe_interval_s=0.2, backoff_base_s=0.2,
         backoff_cap_s=1.0)
     gw = None
+    publish_stop = threading.Event()
     try:
         sup.start()
         assert sup.ready(timeout=60)
@@ -164,13 +165,22 @@ def test_rolling_restart_with_live_sse_zero_dropped_events():
 
         bus = NetBus(env["REDIS_URL"])
         published = 0
-        publish_stop = threading.Event()
 
         def publish():
+            # Until told to stop, and never further ahead of the
+            # subscriber than half the broker's replay ring. A fixed
+            # count ran out on a loaded host before the roll reached
+            # the subscriber's replica (a resumed stream with nothing
+            # left to send never answers, so no reconnect was seen),
+            # and a free-running publisher outruns the ring whenever a
+            # resume takes longer than the ring is long: both lose to
+            # the host's speed, not to the roll.
             nonlocal published
-            while not publish_stop.is_set() and published < 400:
-                bus.publish("roll", {"seq": published})
-                published += 1
+            while not publish_stop.is_set():
+                seen = client.seqs[-1] + 1 if client.seqs else 0
+                if published - seen < Broker.HISTORY // 2:
+                    bus.publish("roll", {"seq": published})
+                    published += 1
                 time.sleep(0.04)
 
         with _ResumingSseClient(base, "roll") as client:
@@ -189,10 +199,16 @@ def test_rolling_restart_with_live_sse_zero_dropped_events():
                 health_timeout_s=10.0)
             assert out["ok"], out
             assert len(out["replaced"]) == 2
-            # Keep publishing for a beat so the resumed stream proves
-            # it is LIVE (not just replayed), then stop and let the
-            # tail flush.
-            time.sleep(1.0)
+            # Keep publishing until the resumed stream has delivered an
+            # event published AFTER the roll — it is LIVE, not just
+            # replayed — then stop and let the tail flush.
+            live_from = published
+            deadline = time.time() + 20
+            while time.time() < deadline and not (
+                    client.seqs and client.seqs[-1] > live_from):
+                time.sleep(0.05)
+            assert client.seqs[-1] > live_from, \
+                "the resumed stream delivered nothing published after the roll"
             publish_stop.set()
             pub_thread.join(timeout=10)
             deadline = time.time() + 20
@@ -218,6 +234,7 @@ def test_rolling_restart_with_live_sse_zero_dropped_events():
         # its replica) — otherwise this test proved nothing.
         assert client.reconnects >= 1
     finally:
+        publish_stop.set()
         if gw is not None:
             gw.drain(timeout=5)
         sup.drain(timeout=15)

@@ -1,8 +1,7 @@
 """Safe change delivery, hermetic: verified hot-swap at the replica,
 canary routing at the gateway, and the canary → bake → promote state
 machine with automatic rollback over stub multi-process workers (same
-harness as ``tests/test_fleet_dynamic.py``). The full-stack measured
-counterpart is ``scripts/bench_rollout.py`` → ``artifacts/rollout.json``.
+harness as ``tests/test_fleet_dynamic.py``).
 """
 
 import json

@@ -109,9 +109,6 @@ def test_scoring_info_surface(quantile_artifact):
     assert info["kernel"] == "xla"
     assert info["dtype"] == "float32"
     assert info["aot"] is True and info["aot_buckets"] == [8, 64]
-    # measured-selection provenance is attached whenever auto mode
-    # consulted the record (even when the verdict was "serve XLA")
-    assert "win_bucket" in info and "path" in info["win_bucket"]
 
 
 def test_health_reports_scoring_block(quantile_artifact, monkeypatch):
@@ -129,7 +126,6 @@ def test_health_reports_scoring_block(quantile_artifact, monkeypatch):
     assert scoring["kernel"] == "xla"
     assert scoring["dtype"] == "float32"
     assert scoring["aot"] is True and scoring["aot_buckets"]
-    assert "win_bucket" in scoring
 
 
 def test_donation_safe_staging_slab_fuzz():

@@ -59,7 +59,7 @@ def _model(n_q: int):
     return model, model.init(jax.random.PRNGKey(0))
 
 
-# The serving buckets' largest (4,096) and bench.py's offline batch. The
+# The serving buckets' largest (4,096) and one slice of od-score. The
 # f32 variant's HIGHEST-precision matmuls take three times as long to
 # compile, so it is held to the larger batch: the per-tile program is
 # the same at both, only the grid differs.

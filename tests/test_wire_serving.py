@@ -5,8 +5,7 @@ bitwise — the format's contract is exact parity, not closeness), the
 multiplexed gateway↔replica channel (concurrency, deadline
 propagation, dead-socket recovery, HTTP fallback), the loadgen
 ``wire_format`` knob's byte-stability, and the prober's ``wire``
-parity kind. Codec-level fuzzing lives in ``tests/test_wirecodec.py``;
-the measured twin is ``scripts/bench_wire.py`` → ``artifacts/wire.json``.
+parity kind. Codec-level fuzzing lives in ``tests/test_wirecodec.py``.
 """
 
 import datetime as dt

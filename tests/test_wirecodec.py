@@ -2,9 +2,7 @@
 encode→decode round-trips across shapes/dtypes/NaN payloads, loud
 rejection of truncated/corrupt/oversized frames (a partial batch must
 never decode silently), and the typed eta/matrix/error helpers'
-contracts. The served parity twin is ``tests/test_wire_serving.py``;
-the measured counterpart is ``scripts/bench_wire.py`` →
-``artifacts/wire.json``."""
+contracts. The served parity twin is ``tests/test_wire_serving.py``."""
 
 import numpy as np
 import pytest
