@@ -75,7 +75,20 @@ def _phase(phase: str, **attrs) -> Iterator:
 
 
 class ContinuousTrainer:
-    """Periodic re-fit of the road-GNN on the observation window."""
+    """Periodic re-fit of the road-GNN on the observation window.
+
+    A trainer's graph is fixed at construction, so what a cycle's batch
+    holds of it is handed to the device once, by the first cycle that
+    trains, and stays there between cycles: the arcs' ends, lengths and
+    speed limits, the node coordinates, the message weights, the
+    layout's slabs and the ``(E, 13)`` feature table: about 90 bytes
+    an arc (a TPU keeps the table column by column, 16 columns), 0.25
+    GB at 2.7 M arcs. Every cycle held as much for the length of its
+    steps anyway; a replica that trains beside a router now holds it
+    between cycles too. A cycle sends its window's targets, loss
+    weights and hours (12 bytes an arc) and rewrites the table's hour
+    columns on the device, in place. A cycle that raises drops the
+    resident state, and the next one rebuilds it from the host's."""
 
     def __init__(self, router, state: CongestionState,
                  artifact_path: Optional[str] = None, *,
@@ -96,11 +109,15 @@ class ContinuousTrainer:
         self._graph = router.graph_dict()
         self._layout = None
         self._static: Optional[Dict[str, np.ndarray]] = None
+        # the device's copy of what _static and _layout hold of a batch
+        self._resident: Optional[Dict] = None
         self._model = None
         self._params = None
         self._opt = None
         self._opt_state = None
         self._step_fn = None
+        self._apply_fn = None
+        self._hours_fn = None
         self.cycles = 0
         self.last_result: Dict = {}
         self._stop = threading.Event()
@@ -143,6 +160,8 @@ class ContinuousTrainer:
         import jax
         import optax
 
+        from routest_tpu.models.gnn import set_hour_columns
+
         self._opt = optax.adamw(self.lr, weight_decay=1e-4)
         self._opt_state = self._opt.init(self._params)
         model, opt = self._model, self._opt
@@ -155,6 +174,8 @@ class ContinuousTrainer:
             return optax.apply_updates(params, updates), opt_state, loss
 
         self._step_fn = step
+        self._apply_fn = jax.jit(model.apply)
+        self._hours_fn = jax.jit(set_hour_columns, donate_argnums=0)
 
     def _ensure_layout(self, root) -> None:
         """Once per trainer: the graph's ``GraphLayout`` where it gets
@@ -183,6 +204,31 @@ class ContinuousTrainer:
                           round((time.perf_counter() - t0) * 1e3, 3))
         self._layout, self._static = lay, static
 
+    def _upload_static(self) -> int:
+        """Hands the device what no window changes, in the order of
+        ``_static``; returns the bytes sent. The feature table goes up
+        with hour 0 in its hour columns: every cycle rewrites them."""
+        import jax
+        import jax.numpy as jnp
+
+        from routest_tpu.models.gnn import edge_feature_array, hour_table
+
+        g = self._static
+        host = {
+            "senders": np.asarray(g["senders"], np.int32),
+            "receivers": np.asarray(g["receivers"], np.int32),
+            "edge_feats": edge_feature_array(
+                g["length_m"], g["speed_limit"], g["road_class"], 0),
+            "length_m": np.asarray(g["length_m"], np.float32),
+            "speed_limit": np.asarray(g["speed_limit"], np.float32),
+            "coords": np.asarray(g["node_coords"], np.float32),
+            "hour_table": hour_table(),
+            "slabs": self._layout and self._layout.slabs}
+        self._resident = jax.tree_util.tree_map(jnp.asarray, host)
+        self._resident["weights"] = jnp.ones((len(g["senders"]),),
+                                             jnp.float32)
+        return sum(a.nbytes for a in jax.tree_util.tree_leaves(host))
+
     # ── one cycle ─────────────────────────────────────────────────────
 
     def run_once(self) -> Dict:
@@ -200,6 +246,9 @@ class ContinuousTrainer:
             try:
                 result, self.last_result = self._cycle(root)
             except Exception as e:
+                # the cycle may have died between donating the feature
+                # table and getting it back
+                self._resident = None
                 get_logger("routest_tpu.live").error(
                     "live_retrain_failed", error=f"{type(e).__name__}: {e}")
                 result, self.last_result = "failed", {
@@ -210,10 +259,9 @@ class ContinuousTrainer:
 
     def _cycle(self, root) -> Tuple[str, Dict]:
         """The cycle proper: (result label, result dict)."""
-        import jax
         import jax.numpy as jnp
 
-        from routest_tpu.models.gnn import GraphBatch, edge_feature_array
+        from routest_tpu.models.gnn import GraphBatch
         from routest_tpu.utils.logging import get_logger
 
         t0 = time.perf_counter()
@@ -247,34 +295,33 @@ class ContinuousTrainer:
                                  / counts[observed]).astype(np.float32)
             hours = np.full(E, time.localtime().tm_hour, np.int32)
             hours[edge] = win["hour"]
-            # everything the device is handed, in the order of upload
-            host = {
-                "senders": np.asarray(g["senders"], np.int32),
-                "receivers": np.asarray(g["receivers"], np.int32),
-                "edge_feats": edge_feature_array(
-                    g["length_m"], g["speed_limit"], g["road_class"],
-                    hours),
-                "length_m": np.asarray(g["length_m"], np.float32),
-                "speed_limit": np.asarray(g["speed_limit"], np.float32),
-                "targets": targets,
-                "loss_w": observed.astype(np.float32),
-                "coords": np.asarray(g["node_coords"], np.float32)}
-            slabs = lay and lay.slabs
-        with _phase("upload", bytes=sum(
-                a.nbytes for a in jax.tree_util.tree_leaves((host, slabs)))):
+            # what the window changed: all a cycle sends once the
+            # static arrays are on the device
+            changed = {"targets": targets,
+                       "loss_w": observed.astype(np.float32),
+                       "hours": hours}
+        with _phase("upload") as span:
             self._ensure_model()
             self._ensure_step()
+            resident = self._resident is not None
+            sent = sum(a.nbytes for a in changed.values())
+            if not resident:
+                sent += self._upload_static()
+            span.set_attr("static_resident", resident)
+            span.set_attr("bytes", sent)
+            dev = self._resident
+            changed = {k: jnp.asarray(a) for k, a in changed.items()}
+            # the table is donated: until the program hands the new one
+            # back the resident state holds none
+            dev["edge_feats"] = self._hours_fn(
+                dev.pop("edge_feats"), changed.pop("hours"),
+                dev["hour_table"])
             batch = GraphBatch(
-                senders=jnp.asarray(host["senders"]),
-                receivers=jnp.asarray(host["receivers"]),
-                edge_feats=jnp.asarray(host["edge_feats"]),
-                length_m=jnp.asarray(host["length_m"]),
-                speed_limit=jnp.asarray(host["speed_limit"]),
-                targets=jnp.asarray(host["targets"]),
-                weights=jnp.ones((E,), jnp.float32),
-                layout=jax.tree_util.tree_map(jnp.asarray, slabs))
-            loss_w = jnp.asarray(host["loss_w"])
-            coords = jnp.asarray(host["coords"])
+                senders=dev["senders"], receivers=dev["receivers"],
+                edge_feats=dev["edge_feats"], length_m=dev["length_m"],
+                speed_limit=dev["speed_limit"], targets=changed["targets"],
+                weights=dev["weights"], layout=dev["slabs"])
+            loss_w, coords = changed["loss_w"], dev["coords"]
         root.set_attr("steps", self.steps)
         with _phase("steps", steps=self.steps):
             params, opt_state = self._params, self._opt_state
@@ -287,7 +334,7 @@ class ContinuousTrainer:
                 return "rejected", {"trained": False,
                                     "reason": f"non-finite loss {loss}"}
         with _phase("apply") as span:
-            pred = np.asarray(self._model.apply(params, coords, batch))
+            pred = np.asarray(self._apply_fn(params, coords, batch))
             span.set_attr("bytes", pred.nbytes)
             if not np.isfinite(pred).all():
                 return "rejected", {

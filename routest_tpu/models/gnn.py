@@ -259,6 +259,24 @@ def edge_feature_array(length_m: np.ndarray, speed_limit: np.ndarray,
     return out
 
 
+def hour_table() -> np.ndarray:
+    """(24, 8) float32: ``_hour_features`` of every hour of the day.
+    Row ``h`` is bit for bit what ``_hour_features`` gives an arc whose
+    hour is ``h``, so a table indexed by (E,) hours is the host's own
+    hour columns wherever the lookup runs."""
+    return _hour_features(np.arange(24))
+
+
+def set_hour_columns(edge_feats: jax.Array, hours: jax.Array,
+                     table: jax.Array) -> jax.Array:
+    """An ``edge_feature_array`` table with its hour columns rewritten
+    for ``hours`` (E,) int32 in 0..23, from ``hour_table()``: a row
+    lookup, no arithmetic, so the values are the host's. For a caller
+    that keeps the table on the device and jits this with the table
+    donated, the rewrite is in place."""
+    return edge_feats.at[:, 2 + _N_CLASSES:].set(table[hours])
+
+
 def edge_features(graph: Dict[str, np.ndarray]) -> np.ndarray:
     return edge_feature_array(graph["length_m"], graph["speed_limit"],
                               graph["road_class"], graph["hour"])

@@ -3,10 +3,12 @@
 import jax
 import numpy as np
 import optax
+import pytest
 
 from routest_tpu.core.dtypes import F32_POLICY
 from routest_tpu.data.road_graph import generate_road_graph
-from routest_tpu.models.gnn import GraphBatch, RoadGNN, graph_batch
+from routest_tpu.models.gnn import (GraphBatch, RoadGNN, edge_feature_array,
+                                    graph_batch)
 
 
 def _small_graph(n=256, seed=0):
@@ -74,3 +76,31 @@ def test_padding_does_not_change_loss():
     a = float(model.loss(params, g["node_coords"], graph_batch(g)))
     b = float(model.loss(params, g["node_coords"], graph_batch(g, pad_to=64)))
     assert abs(a - b) < 1e-3 * max(1.0, a)
+
+
+@pytest.mark.parametrize("hours", [*range(24), "mixed"])
+def test_the_hour_table_indexed_by_hours_is_the_hosts_own_hour_features(
+        hours):
+    """``live/trainer.py`` writes a batch's hour columns on the device
+    by row lookup in ``hour_table()``: bit for bit what
+    ``_hour_features`` computes on the host for the same hours."""
+    from routest_tpu.models.gnn import (_hour_features, hour_table,
+                                        set_hour_columns)
+
+    if hours == "mixed":
+        hours = np.random.default_rng(3).integers(0, 24, 4096)
+    hours = np.broadcast_to(np.asarray(hours, np.int32), (4096,))
+    want = _hour_features(hours)
+    table = hour_table()
+    assert table.shape == (24, 8) and table.dtype == np.float32
+    assert table[hours].tobytes() == want.tobytes()
+    # and through the program the trainer jits, into a table whose
+    # hour columns held another hour's
+    rng = np.random.default_rng(5)
+    args = (rng.uniform(5.0, 900.0, 4096), rng.uniform(3.0, 30.0, 4096),
+            rng.integers(0, 3, 4096))
+    got = jax.jit(set_hour_columns, donate_argnums=0)(
+        jax.numpy.asarray(edge_feature_array(*args, 0)),
+        jax.numpy.asarray(hours), jax.numpy.asarray(table))
+    assert np.asarray(got).tobytes() == edge_feature_array(
+        *args, hours).tobytes()

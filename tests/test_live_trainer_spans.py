@@ -3,6 +3,7 @@ sequential children, the same five durations in
 ``rtpu_live_retrain_phase_seconds{phase}``; and the two things the
 benchmark's driver leans on (a tracer that is off, a pinned clock)."""
 
+import os
 import time
 import types
 
@@ -33,6 +34,15 @@ def _trainer(tmp_path, n_probes=600, **kw):
     kw.setdefault("steps", 3)
     kw.setdefault("min_obs", 100)
     return trainer_mod.ContinuousTrainer(router, state, **kw)
+
+
+def _pin_hour(monkeypatch, hour):
+    """The module's ``time`` as the benchmark's driver replaces it
+    (``benchmark/drivers/refit_cycles.py``): ``perf_counter`` and a
+    ``localtime`` with the hour pinned, nothing else."""
+    monkeypatch.setattr(trainer_mod, "time", types.SimpleNamespace(
+        perf_counter=time.perf_counter,
+        localtime=lambda *_: types.SimpleNamespace(tm_hour=hour)))
 
 
 def _phase_counts():
@@ -144,10 +154,7 @@ def test_a_module_clock_with_only_perf_counter_and_localtime_still_trains(
     an object (``benchmark/drivers/refit_cycles.py``): any other clock
     function reached for in ``live/trainer.py`` turns every cycle of
     its cell into ``failed``."""
-    pinned = types.SimpleNamespace(
-        perf_counter=time.perf_counter,
-        localtime=lambda *_: types.SimpleNamespace(tm_hour=8))
-    monkeypatch.setattr(trainer_mod, "time", pinned)
+    _pin_hour(monkeypatch, 8)
     tr = _trainer(tmp_path)
     result = tr.run_once()
     assert result["trained"] is True, result
@@ -213,3 +220,187 @@ def test_the_step_the_trainer_jits_holds_no_scatter_under_the_layout(
     text = step.lower(*seen[0]).as_text()
     assert "module @jit_step" in text
     assert "stablehlo.scatter" not in text
+
+
+# ── what a cycle hands the device ─────────────────────────────────────
+
+
+def _record_steps(tr):
+    """Wraps the trainer's step; returns the list that gets each call's
+    ``(coords, batch, loss_w)`` as numpy (the next cycle donates the
+    feature table, so a device array kept here would be deleted)."""
+    import jax
+
+    tr._ensure_model()
+    tr._ensure_step()
+    step, seen = tr._step_fn, []
+
+    def recording(params, opt_state, *rest):
+        seen.append(jax.tree_util.tree_map(np.asarray, rest))
+        return step(params, opt_state, *rest)
+
+    tr._step_fn = recording
+    return seen
+
+
+def _as_the_parent_built_it(tr, hour_now):
+    """``(coords, batch, loss_w)`` of the trainer's current window the
+    way every cycle built them before the static arrays stayed on the
+    device: everything from the host's arrays, the whole feature table
+    by ``edge_feature_array`` at the window's hours."""
+    from routest_tpu.models.gnn import GraphBatch, edge_feature_array
+
+    win, g, lay = tr._state.window(), tr._static, tr._layout
+    edge = win["edge"] if lay is None else lay.arc_rank[win["edge"]]
+    E = len(g["senders"])
+    sums, counts = np.zeros(E, np.float64), np.zeros(E, np.float64)
+    np.add.at(sums, edge, win["time_s"])
+    np.add.at(counts, edge, 1.0)
+    observed = counts > 0
+    targets = np.zeros(E, np.float32)
+    targets[observed] = (sums[observed] / counts[observed]).astype(
+        np.float32)
+    hours = np.full(E, hour_now, np.int32)
+    hours[edge] = win["hour"]
+    batch = GraphBatch(
+        senders=np.asarray(g["senders"], np.int32),
+        receivers=np.asarray(g["receivers"], np.int32),
+        edge_feats=edge_feature_array(g["length_m"], g["speed_limit"],
+                                      g["road_class"], hours),
+        length_m=np.asarray(g["length_m"], np.float32),
+        speed_limit=np.asarray(g["speed_limit"], np.float32),
+        targets=targets, weights=np.ones((E,), np.float32),
+        layout=lay and lay.slabs)
+    return (np.asarray(g["node_coords"], np.float32), batch,
+            observed.astype(np.float32))
+
+
+def _assert_same_bits(got, want):
+    import jax
+
+    got_leaves, got_tree = jax.tree_util.tree_flatten(got)
+    want_leaves, want_tree = jax.tree_util.tree_flatten(want)
+    assert got_tree == want_tree
+    for a, b in zip(got_leaves, want_leaves):
+        b = np.asarray(b)
+        assert (a.dtype, a.shape) == (b.dtype, b.shape)
+        assert a.tobytes() == b.tobytes()
+
+
+def _upload_attrs(tracer):
+    return [s["attrs"] for s in tracer.buffer.snapshot()
+            if s["name"] == "live.retrain.upload"]
+
+
+@pytest.mark.parametrize("layout", ["dense", "segment_sum"])
+def test_three_cycles_hand_the_step_the_parents_batch_bit_for_bit(
+        layout, tmp_path, tracer, monkeypatch):
+    """With the static arrays resident and the hour columns written on
+    the device, the step still gets, leaf by leaf, what a cycle that
+    rebuilt and re-sent everything gave it; and only the first cycle
+    sends the static arrays."""
+    if layout == "segment_sum":
+        from routest_tpu.models import gnn
+
+        monkeypatch.setattr(gnn, "graph_layout", lambda *_: None)
+    tr = _trainer(tmp_path)
+    seen = _record_steps(tr)
+    freeflow = tr._state._val.copy()
+    rng = np.random.default_rng(11)
+    for cycle, (hour_now, probe_hour) in enumerate(((3, 8), (15, 17),
+                                                    (22, 0))):
+        if cycle:
+            edges = rng.integers(0, len(freeflow), 400)
+            tr._state.fold(edges, freeflow[edges] * rng.uniform(1.0, 3.0, 400),
+                           t=1.0 + cycle, hour=probe_hour)
+        _pin_hour(monkeypatch, hour_now)
+        del seen[:]
+        assert tr.run_once()["trained"] is True
+        assert len(seen) == 3                   # steps=3: one call a step
+        want = _as_the_parent_built_it(tr, hour_now)
+        for got in seen:
+            _assert_same_bits(got, want)
+    assert [r["attrs"]["layout"] for r in _roots(tracer)] == [layout] * 3
+    sent = _upload_attrs(tracer)
+    assert [a["static_resident"] for a in sent] == [False, True, True]
+    E = len(tr._static["senders"])
+    assert sent[1]["bytes"] == sent[2]["bytes"] == 3 * 4 * E
+    assert sent[1]["bytes"] < sent[0]["bytes"] / 5
+
+
+@pytest.mark.parametrize("dies_in", ["step", "hour-program"])
+def test_a_cycle_that_dies_after_the_table_was_donated_does_not_poison_the_next(
+        dies_in, tmp_path, tracer, monkeypatch):
+    _pin_hour(monkeypatch, 9)       # two trainers, one hour
+    (tmp_path / "a").mkdir()
+    (tmp_path / "b").mkdir()
+    tr = _trainer(tmp_path / "a")
+    seen = _record_steps(tr)
+    assert tr.run_once()["trained"] is True     # the table is resident
+    step, hours_fn = tr._step_fn, tr._hours_fn
+
+    def dying(*args):
+        # the hour program consumes the table it is donated
+        (step if dies_in == "step" else hours_fn)(*args)
+        raise RuntimeError("lost the device")
+
+    if dies_in == "step":
+        tr._step_fn = dying
+    else:
+        tr._hours_fn = dying
+    assert tr.run_once() == {"trained": False,
+                             "reason": "RuntimeError: lost the device"}
+    assert _roots(tracer)[-1]["attrs"]["result"] == "failed"
+    tr._step_fn, tr._hours_fn = step, hours_fn
+    del seen[:]
+    again = tr.run_once()
+    assert again["trained"] is True, again
+    assert _roots(tracer)[-1]["attrs"]["result"] == "saved"
+    # rebuilt from the host's arrays: the static state went up again
+    assert [a["static_resident"] for a in _upload_attrs(tracer)] == [
+        False, True, False]
+    fresh = _trainer(tmp_path / "b")
+    fresh_seen = _record_steps(fresh)
+    assert fresh.run_once()["trained"] is True
+    _assert_same_bits(seen[0], fresh_seen[0])
+
+
+def test_apply_is_one_program_that_reads_what_the_eager_forward_reads(
+        tmp_path, tracer):
+    import jax
+
+    tr = _trainer(tmp_path)
+    seen = _record_steps(tr)
+    assert tr.run_once()["trained"] is True
+    coords, batch, _ = jax.tree_util.tree_map(jax.numpy.asarray, seen[0])
+    got = np.asarray(tr._apply_fn(tr._params, coords, batch))
+    want = np.asarray(tr._model.apply(tr._params, coords, batch))
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    # a module of its own, so that a trace tells it from the step
+    text = tr._apply_fn.lower(tr._params, coords, batch).as_text()
+    assert "module @jit_apply" in text and "module @jit_step" not in text
+
+
+def test_non_finite_predictions_are_still_rejected_and_nothing_is_saved(
+        tmp_path, tracer):
+    tr = _trainer(tmp_path)
+    assert tr.run_once()["trained"] is True
+    saved_at = os.path.getmtime(tr._path)
+    params, apply_fn = tr._params, tr._apply_fn
+    E = len(tr._static["senders"])
+
+    def poisoned(*args):
+        pred = np.array(apply_fn(*args))
+        pred[E // 2] = np.nan
+        return pred
+
+    tr._apply_fn = poisoned
+    assert tr.run_once() == {"trained": False,
+                             "reason": "non-finite predictions after fit"}
+    assert _roots(tracer)[-1]["attrs"]["result"] == "rejected"
+    assert tr._params is params and os.path.getmtime(tr._path) == saved_at
+    tr._apply_fn = apply_fn
+    assert tr.run_once()["trained"] is True
+    # a rejected cycle is no failure: the static arrays stayed
+    assert [a["static_resident"] for a in _upload_attrs(tracer)] == [
+        False, True, True]
