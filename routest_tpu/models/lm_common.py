@@ -1,0 +1,83 @@
+"""What the route-sequence language models share (``route_lm.RouteLM``
+and ``route_lm_sala.RouteLMSala``): the norm, the rotary embedding, the
+float32-accumulating product and the chunked next-arc head."""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+
+
+def rms_norm(x, w, eps: float):
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
+    return (y * w.astype(jnp.float32)).astype(x.dtype)
+
+
+def rope(x, pos, theta: float):
+    """Rotate-half RoPE over the last axis; ``pos`` has the shape of
+    ``x`` less its last axis, or broadcasts to it from the left."""
+    half = x.shape[-1] // 2
+    freq = jnp.float32(theta) ** (-jnp.arange(half, dtype=jnp.float32)
+                                  / half)
+    ang = pos.astype(jnp.float32)[..., None] * freq
+    ang = ang.reshape(pos.shape + (1,) * (x.ndim - 1 - pos.ndim) + (half,))
+    xf = x.astype(jnp.float32)
+    a, b = xf[..., :half], xf[..., half:]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    return jnp.concatenate([a * cos - b * sin, a * sin + b * cos], -1)
+
+
+def dot32(x, w):
+    return jnp.matmul(x, w, preferred_element_type=jnp.float32)
+
+
+def next_arc_head(params, h, ids, lengths, rows_at, eps: float,
+                  logit_scale: float = 1.0):
+    """``params["final_norm"]`` and ``params["head"]`` over the trunk's
+    output h (B, L, d) → next_logit (B, L) (the logit of ids[t + 1]; 0
+    where there is none), lse (B, L), rows (B, P, V): the whole logit
+    rows at ``rows_at``. The logits are ``logit_scale`` times the
+    product; they exist 4,096 tokens at a time (or the largest divisor
+    of the tokens below that)."""
+    b_sz, length, d = h.shape
+    x = rms_norm(h, params["final_norm"], eps)
+    nxt = jnp.concatenate([ids[:, 1:], ids[:, :1]], 1).reshape(-1)
+    tokens = b_sz * length
+    rows = math.gcd(tokens, 4096)
+
+    def chunk(i):
+        xc = jax.lax.dynamic_slice_in_dim(x.reshape(tokens, d), i * rows,
+                                          rows, 0)
+        nc = jax.lax.dynamic_slice_in_dim(nxt, i * rows, rows, 0)
+        logits = dot32(xc, params["head"])
+        if logit_scale != 1.0:
+            logits = logits * logit_scale
+        return (jnp.take_along_axis(logits, nc[:, None], -1)[:, 0],
+                jax.nn.logsumexp(logits, axis=-1))
+
+    with jax.named_scope("lm.head"):
+        next_logit, lse = jax.lax.map(chunk, jnp.arange(tokens // rows))
+        named = jnp.take_along_axis(x, rows_at[..., None], axis=1)
+        full_rows = dot32(named, params["head"])
+        if logit_scale != 1.0:
+            full_rows = full_rows * logit_scale
+    has_next = (jnp.arange(length)[None, :] + 1) < lengths[:, None]
+    next_logit = jnp.where(has_next, next_logit.reshape(b_sz, length), 0.0)
+    return next_logit, lse.reshape(b_sz, length), full_rows
+
+
+def map_rows(fn, x, block: int):
+    """``fn`` over blocks of ``block`` rows of x (T, d), one after the
+    other, the results joined: what ``fn(x)`` gives where ``fn`` works
+    row by row, without its intermediates for all T rows at once. T is
+    padded to whole blocks (the padding's rows are dropped)."""
+    t = x.shape[0]
+    if t <= block:
+        return fn(x)
+    n = -(-t // block)
+    xp = jnp.pad(x, ((0, n * block - t), (0, 0)))
+    out = jax.lax.map(fn, xp.reshape(n, block, -1))
+    return out.reshape(n * block, -1)[:t]

@@ -7,7 +7,10 @@ and a fleet planner ranks candidate tours by their likelihood. The
 architecture is the one published as ``dots3-note-prev`` (its
 ``config.json`` keys are this model's ``sizes``; the vision and audio
 towers and the multi-token-prediction module of that family are not in
-those keys and are not built):
+those keys and are not built). Every line of this file speaks of that
+architecture; the second model behind the same scorer (``MiniCPM-SALA``)
+is ``models/route_lm_sala.py``, and what the two share — the norm, RoPE,
+the chunked next-arc head — lives in ``models/lm_common.py``:
 
 - pre-norm residual blocks, RMSNorm, ``hidden_size`` wide;
 - two kinds of latent attention in one model (``layer_types``): a
@@ -54,6 +57,8 @@ import jax
 import jax.numpy as jnp
 
 from routest_tpu.core.dtypes import BF16_POLICY, Policy
+from routest_tpu.models.lm_common import dot32 as _dot
+from routest_tpu.models.lm_common import next_arc_head, rms_norm, rope
 from routest_tpu.parallel.expert import ExpertShare, gated_mlp, moe_share
 from routest_tpu.parallel.select import (attention_path, block_and_chunk,
                                          chunk_steps, selected_attention,
@@ -77,36 +82,12 @@ SIZE_KEYS = (
     "swa_v_head_dim", "v_head_dim", "vocab_size")
 
 
-def rms_norm(x, w, eps: float):
-    xf = x.astype(jnp.float32)
-    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
-    return (y * w.astype(jnp.float32)).astype(x.dtype)
-
-
 def layer_norm(x, w, b, eps: float = LN_EPS):
     xf = x.astype(jnp.float32)
     mu = jnp.mean(xf, -1, keepdims=True)
     var = jnp.mean((xf - mu) ** 2, -1, keepdims=True)
     return ((xf - mu) * jax.lax.rsqrt(var + eps) * w.astype(jnp.float32)
             + b.astype(jnp.float32))
-
-
-def rope(x, pos, theta: float):
-    """Rotate-half RoPE over the last axis; ``pos`` has the shape of
-    ``x`` less its last axis, or broadcasts to it from the left."""
-    half = x.shape[-1] // 2
-    freq = jnp.float32(theta) ** (-jnp.arange(half, dtype=jnp.float32)
-                                  / half)
-    ang = pos.astype(jnp.float32)[..., None] * freq
-    ang = ang.reshape(pos.shape + (1,) * (x.ndim - 1 - pos.ndim) + (half,))
-    xf = x.astype(jnp.float32)
-    a, b = xf[..., :half], xf[..., half:]
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    return jnp.concatenate([a * cos - b * sin, a * sin + b * cos], -1)
-
-
-def _dot(x, w):
-    return jnp.matmul(x, w, preferred_element_type=jnp.float32)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -179,6 +160,15 @@ class RouteLM:
                 "vocab_held": self.vocab_held,
                 "chips_per_layer": self.chips_per_layer}
 
+    def holds(self, params: Params) -> bool:
+        """Whether the arrays are this share: as many layers, the held
+        experts in each expert layer, the held rows of the vocabulary."""
+        held = [p["ffn"]["w_gate"].shape[0] for p in params["layers"]
+                if "router" in p["ffn"]]
+        return (len(params["layers"]) == self.layers_held
+                and params["embed"].shape[0] == self.vocab_held
+                and all(n == self.experts_held for n in held))
+
     def layer_kinds(self) -> List[Tuple[str, str]]:
         dense = int(self.sizes["first_k_dense_replace"])
         return [(self.sizes["layer_types"][l],
@@ -222,6 +212,80 @@ class RouteLM:
         path = attention_path(a.heads, block, chunk, a.d_nope, a.d_rope,
                               a.d_v, self.policy.compute_dtype)
         return path, chunk_steps(length, self.select_block, self.key_chunk)
+
+    # ── what the scorer asks of a model (serve/seq_score.py) ────────
+
+    @property
+    def length_quantum(self) -> int:
+        return int(math.lcm(self.select_block, self.window_block))
+
+    def tap_tables(self, n_rows: int, width: int, n_named: int) -> Dict:
+        """name → (shape, dtype, axis of the length, tokens an entry of
+        that axis)."""
+        kinds = self.layer_kinds()
+        n_moe = sum(1 for _, f in kinds if f == "moe")
+        n_full = sum(1 for a, _ in kinds if a == FULL)
+        out = {"n_keys": ((len(kinds), n_rows, width), jnp.int32, 2, 1),
+               "first_key": ((len(kinds), n_rows, width), jnp.int32, 2, 1)}
+        if n_moe:
+            out["chosen"] = ((n_moe, n_rows, width,
+                              int(self.sizes["num_experts_per_tok"])),
+                             jnp.int32, 2, 1)
+        if n_full:
+            out["selected"] = ((n_full, n_rows, n_named, width), jnp.bool_,
+                               3, 1)
+        return out
+
+    def step_attrs(self, length: int) -> Dict[str, str]:
+        path = self.selected_steps(length)[0]
+        return {"attention": path, "mixers": f"full={path},sliding=xla"}
+
+    def step_stats(self, out: Dict, lengths) -> Dict:
+        """Device values of one step for the pass's counters."""
+        stats = {}
+        if "counts" in out:
+            stats["counts"] = out["counts"]
+        if "selected" in out:        # the model has selecting layers
+            full = [l for l, (a, _) in enumerate(self.layer_kinds())
+                    if a == FULL]
+            real = (jnp.arange(out["n_keys"].shape[2])[None, :]
+                    < lengths[:, None])
+            stats["selected_keys"] = jnp.sum(
+                jnp.where(real[None], out["n_keys"][jnp.asarray(full)], 0))
+        return stats
+
+    def pass_counts(self, steps, stats, real: int) -> List[Tuple]:
+        """(family, labels, value) of one pass for the scorer's
+        counters and gauges: from the plan, and ``stats`` fetched once
+        after the pass's sync."""
+        import numpy as np
+
+        n_full = sum(1 for a, _ in self.layer_kinds() if a == FULL)
+        out = []
+        for step in steps:
+            path, chunks = self.selected_steps(step.length)
+            out.append(("chunks", {"path": path},
+                        chunks * len(step.routes) * n_full))
+        counts = [np.asarray(s["counts"], np.float64) for s in stats
+                  if "counts" in s]
+        if counts:
+            per_layer = np.concatenate(counts, 0)       # (steps·layers, E)
+            means = per_layer.mean(1)
+            busy = means > 0
+            out += [("expert_tokens", {"stat": "max"}, per_layer.max()),
+                    ("expert_tokens", {"stat": "mean"}, per_layer.mean())]
+            if busy.any():
+                out.append(("load", {}, float(np.mean(
+                    per_layer[busy].max(1) / means[busy]))))
+            k = int(self.sizes["num_experts_per_tok"])
+            n_moe = counts[0].shape[0]
+            out.append(("held_share", {},
+                        per_layer.sum() / max(1, k * real * n_moe)))
+        picked = [float(s["selected_keys"]) for s in stats
+                  if "selected_keys" in s]
+        if picked:
+            out.append(("selected", {}, sum(picked) / max(1, real * n_full)))
+        return out
 
     # ── parameters ──────────────────────────────────────────────────
 
@@ -397,28 +461,8 @@ class RouteLM:
 
     def head(self, params: Params, h, ids, lengths, rows_at):
         """→ next_logit (B, L), lse (B, L), rows (B, P, V)."""
-        b_sz, length, d = h.shape
-        x = rms_norm(h, params["final_norm"], self.sizes["rms_norm_eps"])
-        nxt = jnp.concatenate([ids[:, 1:], ids[:, :1]], 1).reshape(-1)
-        tokens = b_sz * length
-        rows = math.gcd(tokens, 4096)
-
-        def chunk(i):
-            xc = jax.lax.dynamic_slice_in_dim(x.reshape(tokens, d), i * rows,
-                                              rows, 0)
-            nc = jax.lax.dynamic_slice_in_dim(nxt, i * rows, rows, 0)
-            logits = _dot(xc, params["head"])
-            return (jnp.take_along_axis(logits, nc[:, None], -1)[:, 0],
-                    jax.nn.logsumexp(logits, axis=-1))
-
-        with jax.named_scope("lm.head"):
-            next_logit, lse = jax.lax.map(chunk, jnp.arange(tokens // rows))
-            named = jnp.take_along_axis(x, rows_at[..., None], axis=1)
-            full_rows = _dot(named, params["head"])
-        has_next = (jnp.arange(length)[None, :] + 1) < lengths[:, None]
-        next_logit = jnp.where(has_next, next_logit.reshape(b_sz, length),
-                               0.0)
-        return next_logit, lse.reshape(b_sz, length), full_rows
+        return next_arc_head(params, h, ids, lengths, rows_at,
+                             self.sizes["rms_norm_eps"])
 
     # ── the model ───────────────────────────────────────────────────
 

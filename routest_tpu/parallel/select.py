@@ -413,3 +413,190 @@ def selected_rows(q_idx, w_idx, k_idx, t_pos, top_k: int):
     """The key sets of a few named queries, (P, L) bool: the same scores
     and the same cut as :func:`selected_attention` applies to them."""
     return top_k_mask(selector_scores(q_idx, w_idx, k_idx), t_pos, top_k)
+
+
+# ── block selection: grouped-query heads over a learned choice of blocks ─
+#
+# Keys and values have G heads, queries G * Hg (a group of Hg query heads
+# shares one key-value head). A query sees the keys s <= t of ``top``
+# blocks of ``block`` keys, chosen per (query, group) in two stages:
+#
+# 1. Compressed keys ``kc_j = mean(k[stride * j : stride * j + window])``,
+#    visible to query t iff ``stride * j + window - 1 <= t``
+#    (:func:`compressed_visible`). Each query head's softmax over the
+#    visible ones, summed over the group's heads, max-pooled to blocks
+#    (block m takes j in [r m - 1, r m + r - 1], r = block // stride:
+#    kernel r + 1, stride r, one pad on the left); the first ``init``
+#    blocks and every block that meets the keys t - local + 1 .. t are
+#    forced, blocks past t // block are never chosen, ties go to the
+#    lower block (:func:`top_k_mask` on the block scores).
+# 2. The softmax over the chosen blocks' keys s <= t, applied as a mask
+#    over the causal chunks of keys (:func:`_attend_group_xla`), the
+#    mask shared by the group's heads. One form, XLA: at 16 heads a
+#    group its float32 tiles cost 0.199 s a layer on a 47k route, a
+#    Pallas kernel of the same step 0.153 s (PERF.md §6, PR 32): 0.25% of
+#    a pass, not worth a second form.
+#
+# A route shorter than ``dense_len`` skips stage 1: every causal key.
+
+
+def compress_keys(k, window: int, stride: int):
+    """k (L, G, d) → (L // stride, G, d) in ``k.dtype``: the means of
+    overlapping windows (float32 sums), ``window`` a multiple of
+    ``stride`` which divides L. The last ``window // stride - 1`` rows
+    would reach past the end: they are zero and never visible."""
+    length, groups, d = k.shape
+    n, per = length // stride, window // stride
+    part = k.astype(jnp.float32).reshape(n, stride, groups, d).sum(1)
+    part = jnp.pad(part, ((0, per - 1), (0, 0), (0, 0)))
+    total = sum(part[i:i + n] for i in range(per))
+    whole = (jnp.arange(n) + per <= n)[:, None, None]
+    return jnp.where(whole, total / window, 0.0).astype(k.dtype)
+
+
+def compressed_visible(t_pos, n_comp: int, window: int, stride: int):
+    """(Q, J) bool: compressed key j lies wholly at or before query t."""
+    j = jnp.arange(n_comp, dtype=jnp.int32)[None, :]
+    return stride * j + (window - 1) <= t_pos[:, None]
+
+
+def forced_blocks(t_pos, n_blocks: int, block: int, init: int, local: int):
+    """(Q, M) bool: the first ``init`` blocks and those that meet the
+    keys ``t - local + 1 .. t``."""
+    m = jnp.arange(n_blocks, dtype=jnp.int32)[None, :]
+    t = t_pos[:, None]
+    near = (m <= t // block) & (m >= jnp.maximum(t - (local - 1), 0) // block)
+    return near | (m < init)
+
+
+def block_scores(q, kc, t_pos, *, scale: float, window: int, stride: int,
+                 block: int):
+    """Stage 1 up to the pooling: q (Q, G, Hg, d), kc (J, G, d) →
+    ((G, Q, M) float32 block scores, (Q,) int32 visible compressed
+    keys), M = J * stride // block."""
+    n_comp, per = kc.shape[0], block // stride
+    vis = compressed_visible(t_pos, n_comp, window, stride)
+    s = jnp.einsum("qghd,jgd->ghqj", q, kc,
+                   preferred_element_type=jnp.float32) * scale
+    s = jnp.where(vis[None, None], s, _NEG)
+    p = jnp.where(vis[None, None], jnp.exp(s - s.max(-1, keepdims=True)), 0.0)
+    p = p / jnp.maximum(p.sum(-1, keepdims=True), 1e-30)
+    a = p.sum(1)                                              # (G, Q, J)
+    a = jnp.pad(a, ((0, 0), (0, 0), (1, per - 1)))
+    n_blk = n_comp // per
+    first = a[..., :n_comp].reshape(a.shape[:2] + (n_blk, per)).max(-1)
+    return (jnp.maximum(first, a[..., per:n_comp + per:per]),
+            vis.sum(-1).astype(jnp.int32))
+
+
+def choose_blocks(scores, t_pos, *, top: int, block: int, init: int,
+                  local: int):
+    """(G, Q, M) block scores → (G, Q, M) bool: the ``top`` blocks a
+    (query, group) among those at or before ``t // block``, the forced
+    ones first."""
+    groups, n_q, n_blk = scores.shape
+    forced = forced_blocks(t_pos, n_blk, block, init, local)
+    scores = jnp.where(forced[None], jnp.inf, scores)
+    chosen = top_k_mask(scores.reshape(groups * n_q, n_blk),
+                        jnp.tile(t_pos // block, groups), top)
+    return chosen.reshape(groups, n_q, n_blk)
+
+
+def _attend_group_xla(q, k, v, keys, b, n_chunks, *, chunk: int,
+                      scale: float):
+    """One block of queries over the first ``n_chunks`` chunks of the
+    keys of route ``b``, the mask shared by a group's heads: q (Q, G,
+    Hg, d), k (B, L, G, d), v (B, L, G, dv), keys (G, Q, L) bool → (G,
+    Hg, Q, dv) float32."""
+    n_q, groups, per, _ = q.shape
+
+    def attend_chunk(j, carry):
+        acc, m, den = carry
+        kc = jax.lax.dynamic_slice_in_dim(k[b], j * chunk, chunk, 0)
+        vc = jax.lax.dynamic_slice_in_dim(v[b], j * chunk, chunk, 0)
+        seen = jax.lax.dynamic_slice_in_dim(keys, j * chunk, chunk,
+                                            2)[:, None]
+        s = jnp.einsum("qghd,kgd->ghqk", q, kc,
+                       preferred_element_type=jnp.float32) * scale
+        s = jnp.where(seen, s, _NEG)
+        m_new = jnp.maximum(m, s.max(-1))
+        p = jnp.exp(s - m_new[..., None]) * seen
+        fix = jnp.exp(m - m_new)
+        acc = acc * fix[..., None] + jnp.einsum(
+            "ghqk,kgd->ghqd", p.astype(v.dtype), vc,
+            preferred_element_type=jnp.float32)
+        return acc, m_new, den * fix + p.sum(-1)
+
+    acc, _, den = jax.lax.fori_loop(
+        0, n_chunks, attend_chunk,
+        (jnp.zeros((groups, per, n_q, v.shape[-1]), jnp.float32),
+         jnp.full((groups, per, n_q), _NEG, jnp.float32),
+         jnp.zeros((groups, per, n_q), jnp.float32)))
+    return acc / den[..., None]
+
+
+def block_sparse_attention(q, k, v, lengths, rows_at, *, scale: float,
+                           dense_len: int, top: int, block: int, window: int,
+                           stride: int, init: int, local: int,
+                           q_block: int = 128, chunk: int = 2048,
+                           scope: str = ""):
+    """q (B, L, G, Hg, d), k (B, L, G, d), v (B, L, G, dv), lengths (B,),
+    rows_at (B, P) → (out (B, L, G, Hg, dv) in ``v.dtype``, n_keys (B,
+    L, G) int32: the keys each (query, group) saw, n_visible (B, L)
+    int32: the compressed keys visible to each query, blocks (B, P, G,
+    M) bool: the blocks of the queries named in ``rows_at``). ``L`` must
+    be a multiple of ``q_block`` and of ``block`` (or smaller than
+    ``q_block``)."""
+    b_sz, length, groups, per, _ = q.shape
+    d_v = v.shape[-1]
+    q_block, chunk = block_and_chunk(length, q_block, chunk)
+    if length % q_block or length % block:
+        raise ValueError(f"length {length} is not a multiple of {q_block} "
+                         f"and {block}")
+    n_blk, n_key_blocks = length // q_block, length // block
+    selecting = length >= dense_len           # else no route here selects
+    s_pos = jnp.arange(length, dtype=jnp.int32)
+    pick = dict(top=top, block=block, init=init, local=local)
+    sizes = dict(scale=scale, window=window, stride=stride, block=block)
+    if selecting:
+        with jax.named_scope(scope + ".compress"):
+            kc = jax.vmap(lambda x: compress_keys(x, window, stride))(k)
+
+    def blocks_of(b, q_rows, t_pos):
+        """(G, Q, M) bool and (Q,) visible compressed keys."""
+        causal = jnp.broadcast_to(
+            jnp.arange(n_key_blocks)[None, None, :] <= (t_pos // block)[
+                None, :, None], (groups, len(t_pos), n_key_blocks))
+        if not selecting:
+            return causal, compressed_visible(
+                t_pos, length // stride, window, stride).sum(-1).astype(
+                    jnp.int32)
+        with jax.named_scope(scope + ".select"):
+            scores, n_vis = block_scores(q_rows, kc[b], t_pos, **sizes)
+            chosen = choose_blocks(scores, t_pos, **pick)
+        return jnp.where(lengths[b] >= dense_len, chosen, causal), n_vis
+
+    def one(n):
+        b, i = n // n_blk, n % n_blk
+        t_pos = i * q_block + jnp.arange(q_block, dtype=jnp.int32)
+        qb = jax.lax.dynamic_slice_in_dim(q[b], i * q_block, q_block, 0)
+        chosen, n_vis = blocks_of(b, qb, t_pos)
+        keys = (jnp.repeat(chosen, block, axis=-1)
+                & (s_pos[None, None, :] <= t_pos[None, :, None]))
+        with jax.named_scope(scope + ".attend"):
+            out = _attend_group_xla(
+                qb, k, v, keys, b, ((i + 1) * q_block + chunk - 1) // chunk,
+                chunk=chunk, scale=scale)
+        return (out.transpose(2, 0, 1, 3).astype(v.dtype),
+                keys.sum(-1).astype(jnp.int32).T, n_vis)
+
+    out, n_keys, n_vis = jax.lax.map(one, jnp.arange(b_sz * n_blk))
+
+    def named(b, at):
+        return blocks_of(b, q[b][at], at)[0].transpose(1, 0, 2)
+
+    blocks = jax.vmap(named)(jnp.arange(b_sz), rows_at)
+    return (out.reshape(b_sz, length, groups, per, d_v),
+            n_keys.reshape(b_sz, length, groups),
+            n_vis.reshape(b_sz, length), blocks)
+

@@ -1,6 +1,8 @@
-"""Scoring a resident table of route histories with the route-sequence
+"""Scoring a resident table of route histories with a route-sequence
 language model: the table-scoring entry that takes and returns device
-arrays.
+arrays. One scorer for every such model (``models/route_lm.RouteLM``,
+the ``dots3-note-prev`` architecture, and ``models/route_lm_sala
+.RouteLMSala``, the ``MiniCPM-SALA`` one).
 
 A caller holds ``ids`` (R, L_max) and ``lengths`` (R,) on the device
 and asks for every route's next-arc logits, log-sum-exps and
@@ -15,19 +17,44 @@ results into the result tables, which are donated), so a pass compiles
 one program per class. Steps are dispatched without waiting; a pass
 ends in one sync.
 
+**What the scorer asks of a model** (nothing else is read off it):
+
+- ``apply(params, ids (B, L), lengths (B,), rows_at (B, P))`` → a dict
+  with ``next_logit`` and ``lse`` (B, L) float32, ``loglik`` (B,),
+  ``rows`` (B, P, vocab_held) and the model's own taps; a route's
+  outputs depend on nothing but its own tokens;
+- ``vocab_held``; ``length_quantum``: padded lengths are its multiples;
+- ``tap_tables(n_rows, width, n_named)`` → for each tap of ``apply``
+  that is kept over the table: (shape, dtype, axis, unit). The table's
+  axis 1 is the route (``apply``'s too); ``axis`` is the one that
+  follows the padded length, an entry of it ``unit`` tokens (``None``:
+  no such axis);
+- ``step_attrs(length)`` → attributes of the ``seq.step`` span (which
+  path each mixer runs at this padded length);
+- ``step_stats(out, lengths)`` → small device values of one step, and
+  ``pass_counts(steps, stats, real_tokens)`` → [(family, labels,
+  value)] for the families of :func:`_seq_metrics`, from the plan and
+  from the steps' stats, which are fetched once a pass after its sync.
+
 Spans: ``seq.score_pass`` (root) with one ``seq.step`` child per step
 (the host's dispatch of it; attrs ``length_class``, ``routes``,
-``real_tokens``, ``padded_tokens``, ``attention``: the online-softmax
-step its full layers run, ``fused`` or ``xla``) and ``seq.wait`` (the
-sync). As every recorded span they are ``TraceAnnotation``s too.
-Counters: ``rtpu_seq_tokens_total{kind=real|padded}`` and
+``real_tokens``, ``padded_tokens``, ``mixers`` and, for ``RouteLM``,
+``attention``: the online-softmax step its full layers run, ``fused`` or
+``xla``) and ``seq.wait`` (the sync). As every recorded span they are
+``TraceAnnotation``s too. Counters: ``rtpu_seq_tokens_total{kind=real|
+padded}`` from the plan, for every model. ``RouteLM``:
 ``rtpu_seq_attention_chunks_total{path=fused|xla}`` (the full layers'
-steps over chunks of keys) from the plan; and, read
-from the device once a pass after its sync, ``rtpu_seq_expert_tokens
-{stat=max|mean}`` (tokens per held expert per step and layer),
-``rtpu_seq_expert_load_max_over_mean``, ``rtpu_seq_held_assignment_
-share`` (the share of a step's k·T assignments that land on held
-experts) and ``rtpu_seq_selected_keys_per_query``.
+steps over chunks of keys) from the plan; and, read from the device
+once a pass after its sync, ``rtpu_seq_expert_tokens{stat=max|mean}``
+(tokens per held expert per step and layer), ``rtpu_seq_expert_load_
+max_over_mean``, ``rtpu_seq_held_assignment_share`` (the share of a
+step's k·T assignments that land on held experts) and
+``rtpu_seq_selected_keys_per_query``. ``RouteLMSala``:
+``rtpu_seq_sparse_keys_total{kind=chosen|visited}`` (keys in chosen
+blocks at or before the query, from the device; keys the second stage
+multiplied, from the plan), ``rtpu_seq_sparse_blocks_per_query`` (the
+first over the real (query, group) pairs and the block's keys) and
+``rtpu_seq_linear_chunks_total`` (steps of the linear mixers' scans).
 """
 
 from __future__ import annotations
@@ -70,8 +97,24 @@ def _seq_metrics():
             "selected": reg.gauge(
                 "rtpu_seq_selected_keys_per_query",
                 "Mean keys a query of a selecting layer saw, last pass."),
+            "sparse_keys": reg.counter(
+                "rtpu_seq_sparse_keys_total",
+                "Keys of the block-selecting layers: in chosen blocks at "
+                "or before the query (chosen), and multiplied by the "
+                "second stage, masked or not (visited).", ("kind",)),
+            "sparse_blocks": reg.gauge(
+                "rtpu_seq_sparse_blocks_per_query",
+                "Chosen keys a (query, group) of a block-selecting layer "
+                "saw, in blocks, last pass."),
+            "linear_chunks": reg.counter(
+                "rtpu_seq_linear_chunks_total",
+                "Steps of the linear mixers' chunked scans that the "
+                "dispatched steps ran."),
         }
     return _metrics
+
+
+_COUNTERS = ("tokens", "chunks", "sparse_keys", "linear_chunks")
 
 
 class Step(NamedTuple):
@@ -166,7 +209,7 @@ class RouteScorer:
         self.params = jax.device_put(params)
         self.max_step_tokens = int(max_step_tokens)
         self.max_classes = int(max_classes)
-        self.quantum = int(np.lcm(model.select_block, model.window_block))
+        self.quantum = int(model.length_quantum)
         self._step = jax.jit(self._run_step, static_argnums=(6,),
                              donate_argnums=(5,))
 
@@ -203,46 +246,30 @@ class RouteScorer:
         for name in ("next_logit", "lse"):
             new[name] = tables[name].at[dst, :take].set(
                 out[name][:, :take], mode="drop")
-        for name in ("n_keys", "first_key", "chosen"):    # layers first
-            if name in out:
-                new[name] = tables[name].at[:, dst, :take].set(
-                    out[name][:, :, :take], mode="drop")
-        if "selected" in out:
-            new["selected"] = tables["selected"].at[:, dst, :, :take].set(
-                out["selected"][..., :take], mode="drop")
+        for name, (_, _, axis, unit) in self.model.tap_tables(
+                n_rows, width, rows_at.shape[1]).items():
+            where = [slice(None)] * out[name].ndim
+            if axis is not None:
+                where[axis] = slice(0, -(-take // unit))
+            value = out[name][tuple(where)]
+            where[1] = dst
+            new[name] = tables[name].at[tuple(where)].set(value, mode="drop")
         new["loglik"] = tables["loglik"].at[dst].set(out["loglik"],
                                                      mode="drop")
         new["rows"] = tables["rows"].at[dst].set(out["rows"], mode="drop")
-        stats = {}
-        if "counts" in out:
-            stats["counts"] = out["counts"]
-        if "selected" in out:        # the model has selecting layers
-            kinds = self.model.layer_kinds()
-            full = [l for l, (a, _) in enumerate(kinds)
-                    if a == "full_attention"]
-            real = (jnp.arange(length)[None, :] < step_len[:, None])
-            stats["selected_keys"] = jnp.sum(
-                jnp.where(real[None], out["n_keys"][jnp.asarray(full)], 0))
-        return new, stats
+        return new, self.model.step_stats(out, step_len)
 
     def _empty_tables(self, n_rows: int, width: int, n_named: int) -> Dict:
         import jax.numpy as jnp
 
-        m = self.model
-        kinds = m.layer_kinds()
-        n_moe = sum(1 for _, f in kinds if f == "moe")
-        n_full = sum(1 for a, _ in kinds if a == "full_attention")
-        k = int(m.sizes["num_experts_per_tok"])
         t = {"next_logit": jnp.zeros((n_rows, width), jnp.float32),
              "lse": jnp.zeros((n_rows, width), jnp.float32),
              "loglik": jnp.zeros((n_rows,), jnp.float32),
-             "rows": jnp.zeros((n_rows, n_named, m.vocab_held), jnp.float32),
-             "n_keys": jnp.zeros((len(kinds), n_rows, width), jnp.int32),
-             "first_key": jnp.zeros((len(kinds), n_rows, width), jnp.int32)}
-        if n_moe:
-            t["chosen"] = jnp.zeros((n_moe, n_rows, width, k), jnp.int32)
-        if n_full:
-            t["selected"] = jnp.zeros((n_full, n_rows, n_named, width), bool)
+             "rows": jnp.zeros((n_rows, n_named, self.model.vocab_held),
+                               jnp.float32)}
+        for name, (shape, dtype, _, _) in self.model.tap_tables(
+                n_rows, width, n_named).items():
+            t[name] = jnp.zeros(shape, dtype)
         return t
 
     # ── a pass ──────────────────────────────────────────────────────
@@ -271,8 +298,7 @@ class RouteScorer:
                                 routes=int((step.routes >= 0).sum()),
                                 real_tokens=step.real_tokens,
                                 padded_tokens=step.padded_tokens,
-                                attention=self.model.selected_steps(
-                                    step.length)[0]):
+                                **self.model.step_attrs(step.length)):
                     tables, st = self._step(
                         self.params, ids, lengths, rows_at,
                         jnp.asarray(step.routes, jnp.int32), tables,
@@ -281,8 +307,8 @@ class RouteScorer:
             with trace_span("seq.wait"):
                 jax.block_until_ready(tables)
             self._count(plan, jax.device_get(stats), real, padded)
-        taps = {k: tables[k] for k in ("n_keys", "first_key", "chosen",
-                                       "selected") if k in tables}
+        taps = {k: v for k, v in tables.items()
+                if k not in ("next_logit", "lse", "loglik", "rows")}
         return SeqScores(tables["next_logit"], tables["lse"],
                          tables["loglik"], tables["rows"], taps)
 
@@ -290,27 +316,7 @@ class RouteScorer:
         m = _seq_metrics()
         m["tokens"].labels(kind="real").inc(real)
         m["tokens"].labels(kind="padded").inc(padded)
-        n_full = sum(1 for a, _ in self.model.layer_kinds()
-                     if a == "full_attention")
-        for step in plan:
-            path, chunks = self.model.selected_steps(step.length)
-            m["chunks"].labels(path=path).inc(
-                chunks * len(step.routes) * n_full)
-        counts = [np.asarray(s["counts"], np.float64) for s in stats
-                  if "counts" in s]
-        if counts:
-            per_layer = np.concatenate(counts, 0)       # (steps·layers, E)
-            means = per_layer.mean(1)
-            busy = means > 0
-            m["expert_tokens"].labels(stat="max").set(per_layer.max())
-            m["expert_tokens"].labels(stat="mean").set(per_layer.mean())
-            if busy.any():
-                m["load"].set(float(np.mean(
-                    per_layer[busy].max(1) / means[busy])))
-            k = int(self.model.sizes["num_experts_per_tok"])
-            n_moe = counts[0].shape[0]
-            m["held_share"].set(per_layer.sum() / max(1, k * real * n_moe))
-        picked = [float(s["selected_keys"]) for s in stats
-                  if "selected_keys" in s]
-        if picked:
-            m["selected"].set(sum(picked) / max(1, real * n_full))
+        for family, labels, value in self.model.pass_counts(plan, stats,
+                                                            real):
+            child = m[family].labels(**labels) if labels else m[family]
+            (child.inc if family in _COUNTERS else child.set)(value)

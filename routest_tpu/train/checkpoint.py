@@ -409,15 +409,26 @@ def default_transformer_path() -> str:
 ROUTE_LM_ARTIFACT_VERSION = 1
 
 
+def _route_lm_types():
+    """Model type in an artifact's header → class. ``RouteLM`` is what
+    a header without the key holds."""
+    from routest_tpu.models.route_lm import RouteLM
+    from routest_tpu.models.route_lm_sala import RouteLMSala
+
+    return {"RouteLM": RouteLM, "RouteLMSala": RouteLMSala}
+
+
 def save_route_lm(path: str, model, params) -> None:
-    """Route-LM serving artifact: the header carries the published sizes
-    the model was built from, the share of the deployment these
-    parameters are (layers, experts and vocabulary rows held, chips a
-    layer) and the dtype policy; the blob is the params pytree (bfloat16
-    leaves travel as they are)."""
+    """Route-LM serving artifact: the header carries the model's type,
+    the published sizes it was built from, the share of the deployment
+    these parameters are (``RouteLM``: layers, experts and vocabulary
+    rows held, chips a layer; ``RouteLMSala``: a run of layers from
+    ``layers_first`` on) and the dtype policy; the blob is the params
+    pytree (bfloat16 leaves travel as they are)."""
     _write_artifact(path, MAGIC, {
         "format": "routest_tpu.route_lm",
         "version": ROUTE_LM_ARTIFACT_VERSION,
+        "model": type(model).__name__,
         "sizes": dict(model.sizes),
         "share": model.share_header(),
         "param_dtype": np.dtype(model.policy.param_dtype).name,
@@ -426,17 +437,15 @@ def save_route_lm(path: str, model, params) -> None:
 
 
 def load_route_lm(path: str, expect_share: Optional[dict] = None):
-    """→ (RouteLM, params). The parameters of a share are only that
-    share's: where the loader states the share it serves
-    (``expect_share``: any of ``layers_held``, ``experts_held``,
-    ``experts_first``, ``vocab_held``, ``chips_per_layer``) and the
-    artifact's disagrees, or where the arrays are not the header's
-    share, the load raises — as the feature-count gates of the other
-    artifacts do."""
+    """→ (the model the header names, params). The parameters of a
+    share are only that share's: where the loader states the share it
+    serves (``expect_share``: any key of the model's ``share_header``)
+    and the artifact's disagrees, or where the arrays are not the
+    header's share, the load raises — as the feature-count gates of the
+    other artifacts do."""
     import jax.numpy as jnp
 
     from routest_tpu.core.dtypes import Policy
-    from routest_tpu.models.route_lm import RouteLM
 
     header, blob = _read_artifact(
         path, MAGIC, "routest_tpu.route_lm", (ROUTE_LM_ARTIFACT_VERSION,),
@@ -449,11 +458,13 @@ def load_route_lm(path: str, expect_share: Optional[dict] = None):
                 f"{path}: the artifact holds {key}={share.get(key)}, this "
                 f"replica serves {key}={want}; load the artifact of its "
                 f"own share")
-    model = RouteLM(
-        sizes=header["sizes"], layers_held=share["layers_held"],
-        experts_held=share["experts_held"], vocab_held=share["vocab_held"],
-        experts_first=share["experts_first"],
-        chips_per_layer=share["chips_per_layer"],
+    types = _route_lm_types()
+    kind = header.get("model", "RouteLM")
+    if kind not in types:
+        raise ValueError(f"{path}: no route-sequence model {kind!r} here "
+                         f"(known: {sorted(types)})")
+    model = types[kind](
+        sizes=header["sizes"], **share,
         policy=Policy(param_dtype=jnp.dtype(header["param_dtype"]).type,
                       compute_dtype=jnp.dtype(header["compute_dtype"]).type))
     params = serialization.msgpack_restore(blob)
@@ -462,15 +473,11 @@ def load_route_lm(path: str, expect_share: Optional[dict] = None):
     if isinstance(layers, dict):     # msgpack keeps a list as a dict
         layers = [layers[str(i)] for i in range(len(layers))]
         params["layers"] = layers
-    held = [p["ffn"]["w_gate"].shape[0] for p in layers
-            if "router" in p["ffn"]]
-    if (len(layers) != model.layers_held
-            or params["embed"].shape[0] != model.vocab_held
-            or any(n != model.experts_held for n in held)):
+    if not model.holds(params):
         raise ValueError(
             f"{path}: the arrays are not the share the header states "
             f"({len(layers)} layers, {params['embed'].shape[0]} vocabulary "
-            f"rows, {sorted(set(held))} experts a layer against {share})")
+            f"rows against {share})")
     return model, params
 
 
