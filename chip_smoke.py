@@ -256,8 +256,8 @@ def eta_reference(params, n_q: int, x, int8_weights: bool = False):
 
     ``int8_weights`` gives the reference for the kernel's int8 variant:
     the same forward over weights rounded to 8 bits per output column
-    (symmetric, scale = max|column| / 127, the distance/age normalizer
-    folded into layer 0 first, as the variant's documentation states).
+    (symmetric, scale = max|column| / 127, as the variant's
+    documentation states; the normalizer stays outside the weights).
     What is left between it and the kernel is bfloat16 arithmetic."""
     import numpy as np
 
@@ -269,14 +269,6 @@ def eta_reference(params, n_q: int, x, int8_weights: bool = False):
     ws = [np.asarray(layer["w"], f32) for layer in params["layers"]]
     bs = [np.asarray(layer["b"], f32) for layer in params["layers"]]
     if int8_weights:
-        # (d - mean)/std feeding a linear layer == raw d into a row
-        # scaled by 1/std, bias shifted by -mean/std * row.
-        bs[0] = bs[0] - mean[10] / std[10] * ws[0][39] \
-            - mean[11] / std[11] * ws[0][41]
-        ws[0] = ws[0].copy()
-        ws[0][39] /= std[10]
-        ws[0][41] /= std[11]
-        mean, std = np.zeros(12, f32), np.ones(12, f32)
         for i, w in enumerate(ws):
             scale = np.abs(w).max(axis=0) / 127.0
             scale[scale < 1e-12] = 1.0
@@ -856,7 +848,8 @@ def programs_fused_kernel(args, rng) -> dict:
         n_q = len(model.quantiles)
         want_all = eta_reference(params, n_q, x_all)
         want_int8 = eta_reference(params, n_q, x_all, int8_weights=True)
-        xla = jax.jit(model.apply_quantiles if n_q else model.apply)
+        xla = jax.jit(model.apply_quantiles_xla if n_q else model.apply_xla)
+        chosen = jax.jit(model.apply_quantiles if n_q else model.apply)
         # variant -> (reference, tolerance class). The int8 variant is
         # held to ITS weights' forward at bfloat16 tolerance: 8-bit
         # weights move the shipped point model by up to 7.6% from the
@@ -882,6 +875,14 @@ def programs_fused_kernel(args, rng) -> dict:
         worst[f"n_q={n_q} xla bfloat16"] = close_to(
             xla(jax.device_put(params), x_all[:BUCKETS[-1]]),
             want_all[:BUCKETS[-1]], "bfloat16", f"xla path n_q={n_q}")
+        # what EtaMLP itself runs at 131,072 rows on this chip: the
+        # kernel, chosen by eta_path with no switch set
+        on_chip = jax.device_put(params)
+        check("eta_mlp_fused" in chosen.lower(on_chip, x).compile().as_text(),
+              f"EtaMLP did not choose the fused kernel at {BIG_BATCH} rows")
+        worst[f"n_q={n_q} chosen bfloat16"] = close_to(
+            chosen(on_chip, x), want_all, "bfloat16",
+            f"the chosen path n_q={n_q} batch={BIG_BATCH}")
     return {"error_share_of_tolerance": worst,
             "shapes": list(BUCKETS + (BIG_BATCH,))}
 

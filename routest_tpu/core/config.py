@@ -1246,7 +1246,7 @@ KNOWN_KNOBS: Mapping[str, str] = {
     "RTPU_NUM_PROCESSES": "multi-process world size",
     "RTPU_PROCESS_ID": "this process's index in the multi-process world",
     # Serving kernel / scoring artifact.
-    "ROUTEST_FUSED": "fused Pallas kernel opt-in/out for scoring",
+    "ROUTEST_FUSED": "1 forces the fused Pallas kernel for every served batch",
     "RTPU_KERNEL_DTYPE": "kernel weight/compute variant: bf16/f32/int8",
     "ROUTEST_WARM_BUCKETS": "batch buckets warmed at serving bring-up",
     # Road router / overlay / route fastlane (ROUTEST_HIER_* build

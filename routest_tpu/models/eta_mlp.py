@@ -204,10 +204,21 @@ class EtaMLP:
         """(B, 12) ABI features → (B,) ETA minutes. bf16 trunk, f32 out.
 
         For a quantile model this is the median head — the reference ABI's
-        single number (``Flaskr/ml.py:53``)."""
+        single number (``Flaskr/ml.py:53``). Runs the path
+        :func:`eta_path` names; :meth:`apply_xla` is the XLA body."""
         if self.quantiles:
             q50 = self.quantiles.index(0.5)
             return self.apply_quantiles(params, x)[..., q50]
+        if self._path(x) == "fused":
+            return self._fused(params, x)[:, 0]
+        return self.apply_xla(params, x)
+
+    def apply_xla(self, params: Params, x: jax.Array) -> jax.Array:
+        """:meth:`apply` as plain XLA, whatever the backend and size: the
+        differentiable form (training, export) and the kernel's oracle."""
+        if self.quantiles:
+            q50 = self.quantiles.index(0.5)
+            return self.apply_quantiles_xla(params, x)[..., q50]
         out, dist_km = self._trunk(params, x)
         with jax.named_scope("eta.heads"):
             pace = jax.nn.softplus(out[..., 0])       # min/km, positive
@@ -220,10 +231,20 @@ class EtaMLP:
         pace/overhead for quantile 0 are softplus-positive; each later
         quantile adds a softplus-positive increment (cumulative sum), so
         ``eta[:, i] <= eta[:, i+1]`` holds for every input and parameter
-        setting — crossing quantiles are unrepresentable. The epilogue
-        runs in the fused matmul form (:func:`quantile_heads`) — same
-        sums, one fusable dot instead of two scans.
+        setting — crossing quantiles are unrepresentable. Runs the path
+        :func:`eta_path` names: the fused kernel (``ops/fused_mlp.py``)
+        for large bfloat16 batches on a TPU, :meth:`apply_quantiles_xla`
+        everywhere else.
         """
+        if self.quantiles and self._path(x) == "fused":
+            return self._fused(params, x)
+        return self.apply_quantiles_xla(params, x)
+
+    def apply_quantiles_xla(self, params: Params, x: jax.Array) -> jax.Array:
+        """:meth:`apply_quantiles` as plain XLA: the differentiable form
+        and the kernel's oracle. The epilogue runs in the fused matmul
+        form (:func:`quantile_heads`) — same sums, one fusable dot
+        instead of two scans."""
         if not self.quantiles:
             raise ValueError("apply_quantiles on a point model; "
                              "construct EtaMLP(quantiles=...)")
@@ -231,6 +252,46 @@ class EtaMLP:
         out, dist_km = self._trunk(params, x)
         with jax.named_scope("eta.heads"):
             return quantile_heads(out, dist_km, n_q)
+
+    def _path(self, x: jax.Array) -> str:
+        rows = x.shape[0] if x.ndim == 2 else 0
+        return eta_path(jax.default_backend(), self.policy.compute_dtype,
+                        self.hidden, rows)
+
+    def _fused(self, params: Params, x: jax.Array) -> jax.Array:
+        """(B, 12) → (B, Q | 1) through the kernel. The table's layout is
+        feature-major on a TPU, so both transposes are bitcasts there;
+        the weights are packed in the traced program (loop-invariant)."""
+        from routest_tpu.ops.fused_mlp import (fused_eta_forward_t,
+                                               pack_eta_params)
+
+        packed = pack_eta_params(self, params, dtype="bfloat16")
+        with jax.named_scope("eta.fused"):
+            return fused_eta_forward_t(packed, x.T, len(self.quantiles)).T
+
+
+# Rows from which EtaMLP takes the fused kernel. On a v5e the kernel beat
+# the XLA body at every slice size measured, 4,096 to 131,072 rows, by
+# x 2.2-2.7 (PERF.md §5, the crossover table of PR 33), so no crossover
+# sets this: it is the smallest batch of whole kernel tiles, which keeps
+# the serving buckets (8-4,096 rows, host-bound) on the XLA body.
+FUSED_MIN_ROWS = 8192
+
+
+def eta_path(backend: str, compute_dtype, hidden, n_rows) -> str:
+    """The forward :class:`EtaMLP` runs at these shapes: ``"fused"``
+    (one Pallas kernel, ``ops/fused_mlp.py``) on a TPU at bfloat16
+    compute where every hidden width tiles the MXU and the batch is a
+    whole number of kernel tiles and large enough for the kernel to
+    win; ``"xla"`` everywhere else (the CPU, a float32 policy, toy
+    widths, small or symbolic batches)."""
+    if (backend != "tpu" or jnp.dtype(compute_dtype) != jnp.bfloat16
+            or not hidden or any(h % 128 for h in hidden)
+            or not isinstance(n_rows, int) or n_rows < FUSED_MIN_ROWS):
+        return "xla"
+    from routest_tpu.ops.fused_mlp import TILE   # pallas: 0.7 s to import
+
+    return "xla" if n_rows % TILE else "fused"
 
 
 def fit_normalizer(features: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:

@@ -8,6 +8,7 @@ callers must degrade to the XLA path when Pallas is unavailable.
 
 from routest_tpu.ops.fused_mlp import (  # noqa: F401
     fused_eta_forward,
+    fused_eta_forward_t,
     pack_eta_params,
     resolve_kernel_dtype,
 )

@@ -29,7 +29,7 @@ import numpy as np
 from routest_tpu.core.config import ServeConfig
 from routest_tpu.core.mesh import MeshRuntime, pad_rows
 from routest_tpu.data.features import encode_requests
-from routest_tpu.models.eta_mlp import EtaMLP, Params
+from routest_tpu.models.eta_mlp import EtaMLP, Params, eta_path
 from routest_tpu.obs import get_registry
 from routest_tpu.obs.efficiency import get_ledger
 from routest_tpu.obs.export import maybe_device_trace
@@ -724,7 +724,9 @@ class EtaService:
                 cache=cfg.fastlane_cache,
                 singleflight=cfg.fastlane_singleflight,
                 max_rows=cfg.fastlane_max_rows)
-        self.kernel = "xla"  # which forward path serves: xla | pallas_fused
+        # which forward path serves: xla | pallas_fused |
+        # xla+pallas_fused(>=N) | xla_tp | stablehlo_aot[_sharded]
+        self.kernel = "xla"
         # Hot-reload watcher (cfg.reload_sec > 0): the SERVICE owns it,
         # so embedders constructing EtaService directly get it too —
         # not only `python -m routest_tpu.serve`. Suppressed inside a
@@ -797,6 +799,11 @@ class EtaService:
             # models/eta_mlp.quantile_heads).
             forward = (self._model.apply_quantiles if self.quantiles
                        else self._model.apply)
+            if runtime is not None:
+                # over a mesh the batch is sharded, and a Mosaic kernel
+                # cannot be partitioned: the XLA body by name
+                forward = (self._model.apply_quantiles_xla if self.quantiles
+                           else self._model.apply_xla)
             apply_jit = jax.jit(forward)
             if self.kernel_dtype is None and hasattr(self._model, "policy"):
                 self.kernel_dtype = np.dtype(
@@ -841,6 +848,7 @@ class EtaService:
                         jax.jit(forward, donate_argnums=(1,)),
                         (params,), None, jit_score)
                     score = aot or score
+                self.kernel = self._chosen_kernel()
                 score = self._maybe_fused_score(score)
             self._finish_init(
                 score, align=runtime.n_data if runtime is not None else 1)
@@ -1010,6 +1018,24 @@ class EtaService:
 
         self.kernel = "xla_tp"
         return score
+
+    def _chosen_kernel(self) -> str:
+        """What ``EtaMLP`` runs at this replica's buckets on one device
+        (``models/eta_mlp.eta_path``): ``xla``, ``pallas_fused``, or
+        ``xla+pallas_fused(>=N)`` where only the buckets from N rows up
+        take the kernel."""
+        if not isinstance(self._model, EtaMLP):   # an imported booster
+            return "xla"
+        buckets = sorted(self._cfg.batch_buckets)
+        fused = [b for b in buckets
+                 if eta_path(jax.default_backend(),
+                             self._model.policy.compute_dtype,
+                             self._model.hidden, b) == "fused"]
+        if not fused:
+            return "xla"
+        if len(fused) == len(buckets):
+            return "pallas_fused"
+        return f"xla+pallas_fused(>={fused[0]})"
 
     def _maybe_fused_score(self, fallback):
         """``ROUTEST_FUSED=1`` on a TPU forces the fused Pallas kernel

@@ -160,7 +160,9 @@ def export_serving_fn(path: str, model: EtaMLP, params: Params,
     from jax import export as jax_export
 
     quantiles = tuple(getattr(model, "quantiles", ()) or ())
-    forward = model.apply_quantiles if quantiles else model.apply
+    # the XLA body by name: the artifact has to run on every platform it
+    # lists, and a Mosaic kernel runs on one
+    forward = model.apply_quantiles_xla if quantiles else model.apply_xla
     host_params = jax.tree_util.tree_map(np.asarray, params)
 
     def fn(x):
@@ -169,6 +171,8 @@ def export_serving_fn(path: str, model: EtaMLP, params: Params,
     (batch,) = jax_export.symbolic_shape("b")
     spec = jax.ShapeDtypeStruct((batch, model.n_features), np.float32)
     exported = jax_export.export(jax.jit(fn), platforms=tuple(platforms))(spec)
+    if "tpu_custom_call" in exported.mlir_module():
+        raise ValueError("the exported program holds a Mosaic kernel")
     _write_artifact(path, EXPORT_MAGIC, {
         "format": "routest_tpu.eta_stablehlo",
         "version": EXPORT_VERSION,

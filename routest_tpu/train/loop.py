@@ -74,12 +74,14 @@ def loss_fn(model: EtaMLP, params: Params, batch: Batch) -> jax.Array:
         # Pinball (quantile) loss, averaged over the head axis: the unique
         # proper scoring rule whose minimizer is the target quantile, so
         # calibration is a property of convergence, not a regularizer.
-        pred = model.apply_quantiles(params, batch.features)   # (B, Q)
+        # the XLA body by name: the fused kernel EtaMLP.apply_quantiles
+        # may choose on a TPU has no VJP
+        pred = model.apply_quantiles_xla(params, batch.features)   # (B, Q)
         q = jnp.asarray(model.quantiles, pred.dtype)
         err = batch.targets[:, None] - pred
         per_row = jnp.maximum(q * err, (q - 1.0) * err).mean(axis=-1)
     else:
-        pred = model.apply(params, batch.features)
+        pred = model.apply_xla(params, batch.features)
         # Huber on minutes: robust to the log-normal noise tail.
         per_row = optax.huber_loss(pred, batch.targets, delta=10.0)
     return (per_row * batch.weights).sum() / denom
@@ -117,7 +119,9 @@ def make_eval_fn(model: EtaMLP, runtime: Optional[MeshRuntime] = None) -> Callab
     """Masked sum-of-squared-error + count, for exact RMSE over padded shards."""
 
     def sse(params: Params, batch: Batch) -> Tuple[jax.Array, jax.Array]:
-        pred = model.apply(params, batch.features)
+        # XLA by name here too: under a mesh the batch is sharded, and a
+        # Mosaic kernel cannot be partitioned
+        pred = model.apply_xla(params, batch.features)
         err = (pred - batch.targets) ** 2 * batch.weights
         return err.sum(), batch.weights.sum()
 

@@ -70,7 +70,7 @@ def main() -> None:
 
     exported = load_exported_serving_fn(out)
     x = batch_from_mapping(generate_dataset(64, seed=9))
-    forward = model.apply_quantiles if model.quantiles else model.apply
+    forward = model.apply_quantiles_xla if model.quantiles else model.apply_xla
     want = np.asarray(forward(params, x))
     got = np.asarray(exported(x))
     # bf16-trunk models tolerate bf16-scale differences: the exported

@@ -71,8 +71,7 @@ def test_fused_kernel_compiles_for_v5e(v5e, dtype, n_q, batch):
     model, params = _model(n_q)
     packed = _on(v5e, pack_eta_params(model, params, dtype=dtype))
     x = jax.ShapeDtypeStruct((batch, 12), jnp.float32, sharding=v5e)
-    compiled = fused_eta_forward.lower(packed, x, n_q=n_q,
-                                       tile=2048).compile()
+    compiled = fused_eta_forward.lower(packed, x, n_q=n_q).compile()
     assert "tpu_custom_call" in compiled.as_text()   # Mosaic, not interpret
 
 
@@ -86,9 +85,62 @@ def test_xla_scorer_compiles_for_v5e(v5e):
     assert compiled.memory_analysis().output_size_in_bytes > 0
 
 
+def test_table_scan_program_compiles_for_v5e(v5e, monkeypatch):
+    """od-score's pass as ``benchmark/drivers/table_scan.py`` builds it:
+    a ``fori_loop`` over 131,072-row slices of a resident (R, 12) table
+    through ``jax.jit(model.apply_quantiles)``, the answers written in
+    place. On a TPU ``eta_path`` takes the kernel there (here it sees
+    the CPU, so the test answers for it). The slice has to reach the
+    kernel in the table's own feature-major layout and leave it the same
+    way: no array of the loop is wider than the tables' padded 16
+    columns, and nothing of the weights' packing runs per slice."""
+    import re
+
+    from routest_tpu.models import eta_mlp
+
+    real = eta_mlp.eta_path
+    monkeypatch.setattr(eta_mlp, "eta_path",
+                        lambda backend, *rest: real("tpu", *rest))
+    model, params = _model(3)
+    size, n_slices = 131072, 8
+    rows = size * n_slices
+
+    def score_pass(params, feats, answers):
+        forward = jax.jit(lambda x: model.apply_quantiles(params, x))
+
+        def score_slice(i, answers):
+            x = jax.lax.dynamic_slice_in_dim(feats, i * size, size, 0)
+            return jax.lax.dynamic_update_slice_in_dim(
+                answers, forward(x), i * size, 0)
+
+        return jax.lax.fori_loop(0, n_slices, score_slice, answers)
+
+    text = jax.jit(score_pass, donate_argnums=(2,)).lower(
+        _on(v5e, params),
+        jax.ShapeDtypeStruct((rows, 12), jnp.float32, sharding=v5e),
+        jax.ShapeDtypeStruct((rows, 3), jnp.float32, sharding=v5e),
+    ).compile().as_text()
+    body = [c for c in text.split("\n\n")
+            if "custom-call(" in c and "eta_mlp_fused" in c]
+    assert len(body) == 1, "one computation, the loop's body, holds the kernel"
+    ops = re.findall(r" = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\(", body[0])
+    for dtype, dims, op in ops:
+        dims = [int(d) for d in dims.split(",") if d]
+        if size in dims:      # a slice: features, answers, or wider?
+            assert max(d for d in dims if d != size) <= 16, (op, dtype, dims)
+    # the slice's own copy, the kernel, the in-place write: nothing else
+    # of array size (XLA's prefetches of the biases apart)
+    work = [op for dtype, dims, op in ops if dims and op not in (
+        "get-tuple-element", "bitcast", "parameter", "copy-start",
+        "copy-done")]
+    assert sorted(work) == ["custom-call", "dynamic-update-slice", "fusion"], work
+
+
 def test_oversized_tile_is_refused_by_the_kernel_not_by_mosaic(v5e):
-    """8192-row tiles exhaust v5e VMEM (4096-row ones in f32); the
-    kernel's own bound names the limit before the compiler is asked."""
+    """The kernel unrolls its tile chain by chain, so a larger tile is a
+    longer program (22 s of Mosaic at 32,768 rows, three times that in
+    f32) for no gain past 8,192; its own bound names the limit before
+    the compiler is asked."""
     model, params = _model(3)
     packed = _on(v5e, pack_eta_params(model, params))
     x = jax.ShapeDtypeStruct((131072, 12), jnp.float32, sharding=v5e)
@@ -96,7 +148,7 @@ def test_oversized_tile_is_refused_by_the_kernel_not_by_mosaic(v5e):
         fused_eta_forward.lower(packed, x, n_q=3, tile=2 * MAX_TILE)
     packed_f32 = _on(v5e, pack_eta_params(model, params, dtype="f32"))
     with pytest.raises(ValueError, match=f"{MAX_TILE_F32}"):
-        fused_eta_forward.lower(packed_f32, x, n_q=3, tile=MAX_TILE)
+        fused_eta_forward.lower(packed_f32, x, n_q=3, tile=2 * MAX_TILE_F32)
 
 
 # The route-sequence model's full layers at the cell's widths: the
