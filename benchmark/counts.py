@@ -1,6 +1,6 @@
 """Operations and bytes the algorithms need, from shapes alone.
 
-Matrix-multiply FLOPs only (2 per multiply-add), as ``bench.py`` counts
+Matrix-multiply FLOPs only (2 per multiply-add), as an MFU counts
 them; elementwise work is left out, so a share of the peak computed
 from these is a lower reading of the useful work, never over 100%.
 """

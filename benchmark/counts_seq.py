@@ -50,6 +50,27 @@ def attention_flops(cfg: Dict, kind: str, length: int) -> int:
     return flops
 
 
+def full_attention_products(cfg: Dict, lengths: Sequence[int],
+                            bytes_per: int = 2) -> tuple[int, int]:
+    """(FLOPs, bytes) of the score and value products of the full
+    layers in one pass over routes of these lengths: what a kernel that
+    does that step and nothing else has to do. FLOPs as
+    :func:`attention_flops` counts them: a query is charged the keys it
+    may SEE, ``min(t + 1, index_topk)``, not the keys a mask
+    multiplies. Bytes: the least any blocking of the queries can move,
+    a layer's queries, keys (the rotary part once a key, not once a
+    head), values and outputs of every real token once."""
+    a = attention_sizes(cfg, "full_attention")
+    layers = sum(kind == "full_attention" for kind, _ in layer_kinds(cfg))
+    seen = sum(keys_seen(int(n), a["top_k"]) for n in lengths)
+    flops = 2 * a["heads"] * (a["d_nope"] + a["d_rope"] + a["d_v"]) * seen
+    per_token = (a["heads"] * (a["d_nope"] + a["d_rope"])      # queries
+                 + a["heads"] * a["d_nope"] + a["d_rope"]       # keys
+                 + 2 * a["heads"] * a["d_v"])                   # values, out
+    nbytes = bytes_per * per_token * sum(int(n) for n in lengths)
+    return layers * flops, layers * nbytes
+
+
 def mlp_flops(d: int, width: int) -> int:
     return 2 * 3 * d * width
 

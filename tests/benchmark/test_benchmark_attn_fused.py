@@ -3,7 +3,7 @@ registry a toy ``route-lm-score`` pass leaves, and in the manifest."""
 
 import pytest
 
-from _toy import R, manifest
+from _toy import R, both_manifests, entry_of, reported
 
 from routest_tpu.obs import MetricsRegistry
 from routest_tpu.obs import registry as reg_mod
@@ -62,14 +62,14 @@ def test_a_toy_pass_on_the_cpu_reads_zero(registry):
         seq_score._metrics = None
 
 
-def test_the_manifest_lists_it_for_route_lm_score_alone():
-    m = manifest()
-    entry = [e for e in m["per_layer"] if e["name"] == NAME]
-    assert entry == [{"name": NAME, "unit": "%", "better": "higher",
+@both_manifests
+def test_the_manifest_lists_it_for_route_lm_score_and_no_older_cell(m):
+    """Its fields and its own cell; nothing about its place in the list
+    nor about which later cells join it."""
+    fields, cells = entry_of(m, NAME)
+    assert fields == {"name": NAME, "unit": "%", "better": "higher",
                       "source": "program_counter", "layer": "attention",
-                      "moves": "od_rows_per_s",
-                      "workloads": ["route-lm-score"]}]
-    assert m["per_layer"][-1]["name"] == NAME       # appended, not put in
+                      "moves": "od_rows_per_s"}
+    assert "route-lm-score" in cells
     for cell in ("od-score", "gnn-refit"):
-        assert NAME not in [e["name"] for e in R.metrics_of(
-            m, "per_layer", cell, ["od_rows_per_s", "gnn_edges_per_s"])]
+        assert NAME not in reported(m, cell)

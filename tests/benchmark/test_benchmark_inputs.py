@@ -83,6 +83,21 @@ def test_graph_has_the_configured_counts_and_repeats_for_a_seed():
     assert not np.array_equal(g["senders"], other["senders"])
 
 
+def test_every_seed_draws_a_graph_with_the_same_degree_counts():
+    """The trainer's step program holds the number of nodes of each
+    degree in its shapes: were they drawn from the seed, the seed would
+    choose the program that is timed."""
+    _, cfg, _ = cell_files("gnn-refit")
+    counts = []
+    for seed in (5, 6, 2 ** 31 + 11):
+        g = graphgen.road_graph(cfg["n_nodes"], cfg["n_arcs"], seed,
+                                cfg["bbox"])
+        degree = np.bincount(g["receivers"], minlength=cfg["n_nodes"])
+        counts.append(np.bincount(degree, minlength=5).tolist())
+    assert counts[0] == counts[1] == counts[2]
+    assert len(counts[0]) == 5 and min(counts[0][1:]) > 0
+
+
 def test_graph_refuses_counts_no_grid_gives():
     with pytest.raises(ValueError):
         graphgen.plan(1000, 1999)

@@ -7,7 +7,8 @@ import time
 import jax
 import pytest
 
-from _toy import R, cell_files, manifest
+from _toy import (ACCEPTED_CELLS, R, both_manifests, cell_files, entry_of,
+                  manifest, reported)
 
 from benchmark import program_spans
 from routest_tpu.obs import Tracer, configure_tracer
@@ -82,3 +83,13 @@ def test_a_toy_refit_runs_window_cycles_send_the_windows_three_vectors(
     assert [a["static_resident"] for a in uploads] == [False] + [True] * cycles
     # the set-up cycle, which sends the static arrays, is left out
     assert got < uploads[0]["bytes"] / 1e6 / 5
+
+
+@both_manifests
+def test_the_manifest_lists_it_for_gnn_refit_alone_of_the_accepted(m):
+    assert entry_of(m, NAME)[0] == {
+        "name": NAME, "unit": "MB", "better": "lower",
+        "source": "program_span", "layer": "refit cycle (host)",
+        "moves": "gnn_edges_per_s"}
+    assert [c for c in ACCEPTED_CELLS if NAME in reported(m, c)] == [
+        "gnn-refit"]

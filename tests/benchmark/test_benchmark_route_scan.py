@@ -39,7 +39,7 @@ def test_a_run_is_correct_and_reports_the_cells_metrics():
     assert result["correct"] is True, result["checks"]
     assert result["failed"] == 0 and result["attempted"] >= 1
     want = {m["name"] for m in R.metrics_of(manifest(), "end_to_end", CELL)}
-    assert set(result["metrics"]) == want == {"od_rows_per_s", "setup_s"}
+    assert set(result["metrics"]) == want >= {"od_rows_per_s", "setup_s"}
     assert all(v["value"] > 0 for v in result["metrics"].values())
     assert result["compiles"]["window"] == 0
     assert set(result["checks"]) == set(mix["limits"])
