@@ -1,10 +1,13 @@
-"""What the route-sequence language models share (``route_lm.RouteLM``
-and ``route_lm_sala.RouteLMSala``): the norm, the rotary embedding, the
-float32-accumulating product and the chunked next-arc head."""
+"""What the route-sequence language models share (``route_lm.RouteLM``,
+``route_lm_sala.RouteLMSala`` and ``route_lm_kexaone.RouteLMKExaone``):
+the norm, the rotary embedding, the float32-accumulating product, the
+chunked next-arc head, the settled stream, the row-wise map and the
+expert layers' pass counts."""
 
 from __future__ import annotations
 
 import math
+from typing import List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -35,16 +38,18 @@ def dot32(x, w):
 
 
 def next_arc_head(params, h, ids, lengths, rows_at, eps: float,
-                  logit_scale: float = 1.0):
+                  logit_scale: float = 1.0, shift: int = 1,
+                  scope: str = "lm.head"):
     """``params["final_norm"]`` and ``params["head"]`` over the trunk's
-    output h (B, L, d) → next_logit (B, L) (the logit of ids[t + 1]; 0
-    where there is none), lse (B, L), rows (B, P, V): the whole logit
+    output h (B, L, d) → next_logit (B, L) (the logit of ids[t + shift];
+    0 where there is none), lse (B, L), rows (B, P, V): the whole logit
     rows at ``rows_at``. The logits are ``logit_scale`` times the
     product; they exist 4,096 tokens at a time (or the largest divisor
-    of the tokens below that)."""
+    of the tokens below that). ``shift`` 2 is a prediction module's
+    head: the arc after next."""
     b_sz, length, d = h.shape
     x = rms_norm(h, params["final_norm"], eps)
-    nxt = jnp.concatenate([ids[:, 1:], ids[:, :1]], 1).reshape(-1)
+    nxt = jnp.concatenate([ids[:, shift:], ids[:, :shift]], 1).reshape(-1)
     tokens = b_sz * length
     rows = math.gcd(tokens, 4096)
 
@@ -58,15 +63,23 @@ def next_arc_head(params, h, ids, lengths, rows_at, eps: float,
         return (jnp.take_along_axis(logits, nc[:, None], -1)[:, 0],
                 jax.nn.logsumexp(logits, axis=-1))
 
-    with jax.named_scope("lm.head"):
+    with jax.named_scope(scope):
         next_logit, lse = jax.lax.map(chunk, jnp.arange(tokens // rows))
         named = jnp.take_along_axis(x, rows_at[..., None], axis=1)
         full_rows = dot32(named, params["head"])
         if logit_scale != 1.0:
             full_rows = full_rows * logit_scale
-    has_next = (jnp.arange(length)[None, :] + 1) < lengths[:, None]
+    has_next = (jnp.arange(length)[None, :] + shift) < lengths[:, None]
     next_logit = jnp.where(has_next, next_logit.reshape(b_sz, length), 0.0)
     return next_logit, lse.reshape(b_sz, length), full_rows
+
+
+def settled(h):
+    """The stream written out where it is updated: left to itself XLA
+    keeps every block's addend and sums them anew at each use, so all
+    of them (368 MiB each at 47k tokens of width 4,096) stay live to
+    the end."""
+    return jax.lax.optimization_barrier(h)
 
 
 def map_rows(fn, x, block: int):
@@ -81,3 +94,24 @@ def map_rows(fn, x, block: int):
     xp = jnp.pad(x, ((0, n * block - t), (0, 0)))
     out = jax.lax.map(fn, xp.reshape(n, block, -1))
     return out.reshape(n * block, -1)[:t]
+
+
+def expert_pass_counts(counts: Sequence, assignments: float) -> List[Tuple]:
+    """(family, labels, value) of one pass's expert layers for the
+    scorer's gauges. ``counts``: for each step the tokens every held
+    expert got in each expert layer, (layers, experts_held);
+    ``assignments``: the (token, slot) choices the pass's real tokens
+    made in those layers, on held experts or not."""
+    import numpy as np
+
+    per_layer = np.concatenate([np.asarray(c, np.float64) for c in counts],
+                               0)                    # (steps·layers, E)
+    means = per_layer.mean(1)
+    busy = means > 0
+    out = [("expert_tokens", {"stat": "max"}, per_layer.max()),
+           ("expert_tokens", {"stat": "mean"}, per_layer.mean())]
+    if busy.any():
+        out.append(("load", {}, float(np.mean(
+            per_layer[busy].max(1) / means[busy]))))
+    out.append(("held_share", {}, per_layer.sum() / max(1, assignments)))
+    return out

@@ -9,8 +9,10 @@ architecture is the one published as ``dots3-note-prev`` (its
 towers and the multi-token-prediction module of that family are not in
 those keys and are not built). Every line of this file speaks of that
 architecture; the second model behind the same scorer (``MiniCPM-SALA``)
-is ``models/route_lm_sala.py``, and what the two share — the norm, RoPE,
-the chunked next-arc head — lives in ``models/lm_common.py``:
+is ``models/route_lm_sala.py``, the third (``K-EXAONE-236B-A23B``)
+``models/route_lm_kexaone.py``, and what the three share — the norm,
+RoPE, the chunked next-arc head, the expert layers' pass counts — lives
+in ``models/lm_common.py``:
 
 - pre-norm residual blocks, RMSNorm, ``hidden_size`` wide;
 - two kinds of latent attention in one model (``layer_types``): a
@@ -58,7 +60,8 @@ import jax.numpy as jnp
 
 from routest_tpu.core.dtypes import BF16_POLICY, Policy
 from routest_tpu.models.lm_common import dot32 as _dot
-from routest_tpu.models.lm_common import next_arc_head, rms_norm, rope
+from routest_tpu.models.lm_common import (expert_pass_counts, next_arc_head,
+                                          rms_norm, rope)
 from routest_tpu.parallel.expert import ExpertShare, gated_mlp, moe_share
 from routest_tpu.parallel.select import (attention_path, block_and_chunk,
                                          chunk_steps, selected_attention,
@@ -266,21 +269,11 @@ class RouteLM:
             path, chunks = self.selected_steps(step.length)
             out.append(("chunks", {"path": path},
                         chunks * len(step.routes) * n_full))
-        counts = [np.asarray(s["counts"], np.float64) for s in stats
-                  if "counts" in s]
+        counts = [s["counts"] for s in stats if "counts" in s]
         if counts:
-            per_layer = np.concatenate(counts, 0)       # (steps·layers, E)
-            means = per_layer.mean(1)
-            busy = means > 0
-            out += [("expert_tokens", {"stat": "max"}, per_layer.max()),
-                    ("expert_tokens", {"stat": "mean"}, per_layer.mean())]
-            if busy.any():
-                out.append(("load", {}, float(np.mean(
-                    per_layer[busy].max(1) / means[busy]))))
             k = int(self.sizes["num_experts_per_tok"])
-            n_moe = counts[0].shape[0]
-            out.append(("held_share", {},
-                        per_layer.sum() / max(1, k * real * n_moe)))
+            out += expert_pass_counts(counts,
+                                      k * real * np.shape(counts[0])[0])
         picked = [float(s["selected_keys"]) for s in stats
                   if "selected_keys" in s]
         if picked:

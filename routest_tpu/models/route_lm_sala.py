@@ -51,6 +51,7 @@ import jax.numpy as jnp
 from routest_tpu.core.dtypes import BF16_POLICY, Policy
 from routest_tpu.models.lm_common import (dot32, map_rows, next_arc_head,
                                           rms_norm, rope)
+from routest_tpu.models.lm_common import settled as _settled
 from routest_tpu.parallel import linear_attn
 from routest_tpu.parallel.expert import gated_mlp
 from routest_tpu.parallel.select import (block_and_chunk,
@@ -68,13 +69,6 @@ SIZE_KEYS = (
     "qk_norm", "rms_norm_eps", "rope_theta", "scale_depth", "scale_emb",
     "sparse", "use_output_gate", "use_output_norm", "vocab_size")
 MLP_ROWS = 2048         # tokens of one MLP product
-
-
-def _settled(h):
-    """The stream written out where it is updated: left to itself XLA
-    keeps every block's addend and sums them anew at each use, so all
-    16 of them (368 MiB each at 47k tokens) stay live to the end."""
-    return jax.lax.optimization_barrier(h)
 
 
 @dataclasses.dataclass(frozen=True)

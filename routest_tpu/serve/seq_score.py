@@ -1,8 +1,11 @@
 """Scoring a resident table of route histories with a route-sequence
 language model: the table-scoring entry that takes and returns device
 arrays. One scorer for every such model (``models/route_lm.RouteLM``,
-the ``dots3-note-prev`` architecture, and ``models/route_lm_sala
-.RouteLMSala``, the ``MiniCPM-SALA`` one).
+the ``dots3-note-prev`` architecture, ``models/route_lm_sala
+.RouteLMSala``, the ``MiniCPM-SALA`` one, and ``models/route_lm_kexaone
+.RouteLMKExaone``, the ``K-EXAONE-236B-A23B`` one, whose prediction
+module gives a second likelihood column — the arc after next — as taps
+``mtp_next_logit``, ``mtp_lse`` and ``mtp_loglik``).
 
 A caller holds ``ids`` (R, L_max) and ``lengths`` (R,) on the device
 and asks for every route's next-arc logits, log-sum-exps and
@@ -28,7 +31,8 @@ ends in one sync.
   that is kept over the table: (shape, dtype, axis, unit). The table's
   axis 1 is the route (``apply``'s too); ``axis`` is the one that
   follows the padded length, an entry of it ``unit`` tokens (``None``:
-  no such axis);
+  no such axis: a value a route, as a linear layer's last state or a
+  prediction module's log-likelihood);
 - ``step_attrs(length)`` → attributes of the ``seq.step`` span (which
   path each mixer runs at this padded length);
 - ``step_stats(out, lengths)`` → small device values of one step, and
@@ -55,6 +59,14 @@ blocks at or before the query, from the device; keys the second stage
 multiplied, from the plan), ``rtpu_seq_sparse_blocks_per_query`` (the
 first over the real (query, group) pairs and the block's keys) and
 ``rtpu_seq_linear_chunks_total`` (steps of the linear mixers' scans).
+``RouteLMKExaone``: the expert gauges as ``RouteLM`` (the module's
+block among the expert layers), ``rtpu_seq_gqa_keys_total{layer=window|
+full, kind=needed|visited}`` (needed: the keys each real query saw,
+from the device; visited: the keys of the blocks and chunks the
+dispatched programs multiplied, padding and beyond-the-diagonal parts
+included, from the plan) and ``rtpu_seq_mtp_positions_total`` (positions
+that got a likelihood term for the arc after next). ``seq.step`` carries
+``mtp`` (1 where the module ran) beside ``mixers``.
 """
 
 from __future__ import annotations
@@ -110,11 +122,22 @@ def _seq_metrics():
                 "rtpu_seq_linear_chunks_total",
                 "Steps of the linear mixers' chunked scans that the "
                 "dispatched steps ran."),
+            "gqa_keys": reg.counter(
+                "rtpu_seq_gqa_keys_total",
+                "Keys of the plain grouped-query layers, window or full: "
+                "seen by real queries (needed), and multiplied by the "
+                "dispatched programs, masked or padded or not "
+                "(visited).", ("layer", "kind")),
+            "mtp_positions": reg.counter(
+                "rtpu_seq_mtp_positions_total",
+                "Positions whose arc after next a prediction module "
+                "scored."),
         }
     return _metrics
 
 
-_COUNTERS = ("tokens", "chunks", "sparse_keys", "linear_chunks")
+_COUNTERS = ("tokens", "chunks", "sparse_keys", "linear_chunks", "gqa_keys",
+             "mtp_positions")
 
 
 class Step(NamedTuple):

@@ -417,16 +417,19 @@ def _route_lm_types():
     """Model type in an artifact's header → class. ``RouteLM`` is what
     a header without the key holds."""
     from routest_tpu.models.route_lm import RouteLM
+    from routest_tpu.models.route_lm_kexaone import RouteLMKExaone
     from routest_tpu.models.route_lm_sala import RouteLMSala
 
-    return {"RouteLM": RouteLM, "RouteLMSala": RouteLMSala}
+    return {"RouteLM": RouteLM, "RouteLMSala": RouteLMSala,
+            "RouteLMKExaone": RouteLMKExaone}
 
 
 def save_route_lm(path: str, model, params) -> None:
     """Route-LM serving artifact: the header carries the model's type,
     the published sizes it was built from, the share of the deployment
     these parameters are (``RouteLM``: layers, experts and vocabulary
-    rows held, chips a layer; ``RouteLMSala``: a run of layers from
+    rows held, chips a layer; ``RouteLMKExaone``: the same and whether
+    the prediction module is held; ``RouteLMSala``: a run of layers from
     ``layers_first`` on) and the dtype policy; the blob is the params
     pytree (bfloat16 leaves travel as they are)."""
     _write_artifact(path, MAGIC, {

@@ -1,19 +1,22 @@
-"""One scorer for both route-sequence models: what ``RouteScorer`` asks
-of a model (``serve/seq_score.py``) is met by ``RouteLM`` and by
-``RouteLMSala``; ``RouteLM``'s plan and result tables are what they
-were."""
+"""One scorer for every route-sequence model: what ``RouteScorer`` asks
+of a model (``serve/seq_score.py``) is met by ``RouteLM``, by
+``RouteLMSala`` and by ``RouteLMKExaone``, whose prediction module's
+column comes through the same tap tables; ``RouteLM``'s and
+``RouteLMSala``'s plans and result tables are what they were."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import _route_lm_kexaone_toy as kexaone
 import _route_lm_sala_toy as sala
 import _route_lm_toy as dots3
 from routest_tpu.serve import seq_score
 from routest_tpu.serve.seq_score import RouteScorer, plan_pass
 
 LENGTHS = [96, 33, 70]
+TOYS = {"dots3": dots3, "sala": sala, "kexaone": kexaone}
 
 
 def _scorer(toy, **kw):
@@ -27,12 +30,12 @@ def scorers():
     """One scorer a model for the whole file: its step programs compile
     once."""
     return {name: _scorer(toy, max_step_tokens=128)
-            for name, toy in (("dots3", dots3), ("sala", sala))}
+            for name, toy in TOYS.items()}
 
 
-@pytest.fixture(scope="module", params=["dots3", "sala"])
+@pytest.fixture(scope="module", params=["dots3", "sala", "kexaone"])
 def scored(request, scorers):
-    toy = {"dots3": dots3, "sala": sala}[request.param]
+    toy = TOYS[request.param]
     m, params, scorer = scorers[request.param]
     ids, lengths, rows_at = (jnp.asarray(a) for a in toy.routes(4, LENGTHS))
     return request.param, m, params, scorer, (ids, lengths, rows_at), \
@@ -68,7 +71,38 @@ def test_the_tables_are_the_models_tap_tables(scored):
             assert shape[axis] == -(-ids.shape[1] // unit)
     assert set(want) == {
         "dots3": {"n_keys", "first_key", "chosen", "selected"},
-        "sala": {"n_keys", "n_visible", "blocks", "state"}}[name]
+        "sala": {"n_keys", "n_visible", "blocks", "state"},
+        "kexaone": {"n_keys", "first_key", "chosen", "mtp_next_logit",
+                    "mtp_lse", "mtp_loglik"}}[name]
+
+
+def test_the_modules_column_reaches_the_caller_over_the_table(scorers):
+    """Device arrays over the table's rows, as the first column's: a
+    per-position tap follows the padded length, the per-route one has
+    no such axis; both are the model's own answers for the route
+    alone."""
+    m, params, scorer = scorers["kexaone"]
+    ids, lengths, rows_at = (jnp.asarray(a)
+                             for a in kexaone.routes(4, LENGTHS))
+    taps = scorer.score(ids, lengths, rows_at).taps
+    assert isinstance(taps["mtp_loglik"], jax.Array)
+    assert taps["mtp_lse"].shape == (1, 3, 96)
+    assert taps["mtp_loglik"].shape == (1, 3)
+    tables = m.tap_tables(3, 96, 3)
+    assert tables["mtp_loglik"][2] is None and tables["mtp_lse"][2] == 2
+    for r, n in enumerate(int(v) for v in lengths):
+        padded = -(-n // 8) * 8
+        alone = jax.jit(m.apply)(
+            params, jnp.pad(ids[r:r + 1, :n], ((0, 0), (0, padded - n))),
+            lengths[r:r + 1], rows_at[r:r + 1])
+        for tap in ("mtp_next_logit", "mtp_lse"):
+            np.testing.assert_allclose(taps[tap][0, r, :n - 1],
+                                       alone[tap][0, 0, :n - 1], rtol=1e-5,
+                                       atol=1e-5)
+        np.testing.assert_allclose(taps["mtp_loglik"][0, r],
+                                   alone["mtp_loglik"][0, 0], rtol=1e-5)
+        # the last two positions have no arc after next
+        assert not np.asarray(taps["mtp_next_logit"][0, r, n - 2:]).any()
 
 
 def test_route_lms_plan_and_tables_are_what_they_were(scorers):
@@ -108,6 +142,17 @@ def test_the_real_models_quanta():
                                         8960]
     assert all(len(s.routes) == 1 for s in plan)
     assert sum(s.padded_tokens for s in plan) == 543        # 0.38%
+    from routest_tpu.models.route_lm_kexaone import RouteLMKExaone
+
+    _, cfg, mix = R.load_cell(manifest, "route-lm-kexaone-mixed")
+    m = RouteLMKExaone.from_config(cfg)
+    assert m.length_quantum == 256
+    plan = plan_pass(mix["lengths"], m.length_quantum,
+                     mix["max_step_tokens"], mix["max_classes"])
+    assert [(len(s.routes), s.length) for s in plan] == [
+        (1, 26624), (1, 15104), (2, 11008), (2, 7168), (3, 5120), (3, 3328),
+        (4, 2304), (4, 1280)]
+    assert sum(s.padded_tokens for s in plan) == 11880      # 10.1%
 
 
 @pytest.fixture
@@ -164,3 +209,53 @@ def test_route_lm_counters_are_what_they_were(registry, scorers):
     assert 0 < _family(registry, "rtpu_seq_held_assignment_share")[()] <= 1.0
     assert _family(registry, "rtpu_seq_selected_keys_per_query")[()] > 1.0
     assert _family(registry, "rtpu_seq_sparse_keys_total") == {}
+
+
+def test_kexaone_counters_and_span_attributes(registry, scorers):
+    from routest_tpu.obs import get_tracer
+    from routest_tpu.parallel import gqa
+
+    m, params, scorer = scorers["kexaone"]
+    ids, lengths, rows_at = (jnp.asarray(a)
+                             for a in kexaone.routes(4, LENGTHS))
+    scorer.score(ids, lengths, rows_at)
+    assert _family(registry, "rtpu_seq_tokens_total")[("real",)] == sum(
+        LENGTHS)
+    keys = _family(registry, "rtpu_seq_gqa_keys_total")
+    # four sliding layers see min(t + 1, 8) keys, the full layer t + 1
+    # and the module's block t + 1 over a route's n - 1 positions
+    seen = lambda n, cap: sum(min(t + 1, cap) for t in range(n))  # noqa: E731
+    assert keys[("window", "needed")] == 4 * sum(seen(n, 8) for n in LENGTHS)
+    assert keys[("full", "needed")] == sum(seen(n, n) + seen(n - 1, n)
+                                           for n in LENGTHS)
+    plan = scorer.plan(np.asarray(LENGTHS))
+    assert [s.length for s in plan] == [96, 72, 40]
+    assert keys[("window", "visited")] == 4 * sum(
+        gqa.window_visited(s.length, 8) for s in plan)
+    assert keys[("full", "visited")] == 2 * sum(
+        gqa.causal_visited(s.length, 8, 16) for s in plan)
+    assert keys[("window", "visited")] > keys[("window", "needed")]
+    assert keys[("full", "visited")] > keys[("full", "needed")]
+    assert _family(registry, "rtpu_seq_mtp_positions_total")[()] == sum(
+        n - 2 for n in LENGTHS)
+    assert _family(registry, "rtpu_seq_expert_load_max_over_mean")[()] >= 1.0
+    # 8 of 16 experts held: about half of the assignments land here
+    assert 0.3 < _family(registry,
+                         "rtpu_seq_held_assignment_share")[()] < 0.7
+    assert _family(registry, "rtpu_seq_attention_chunks_total") == {}
+    assert _family(registry, "rtpu_seq_sparse_keys_total") == {}
+    steps = [s for s in get_tracer().buffer.snapshot()
+             if s["name"] == "seq.step"][-3:]
+    assert all(s["attrs"]["mixers"] == "full=xla,window=xla"
+               and s["attrs"]["mtp"] == "1" for s in steps)
+
+
+def test_the_other_two_models_emit_no_gqa_or_module_counters(registry,
+                                                             scorers):
+    for name, toy in (("dots3", dots3), ("sala", sala)):
+        _, _, scorer = scorers[name]
+        ids, lengths, rows_at = (jnp.asarray(a)
+                                 for a in toy.routes(4, LENGTHS))
+        scorer.score(ids, lengths, rows_at)
+    assert _family(registry, "rtpu_seq_gqa_keys_total") == {}
+    assert _family(registry, "rtpu_seq_mtp_positions_total") == {}
