@@ -65,7 +65,8 @@ from routest_tpu.models.lm_common import (expert_pass_counts, next_arc_head,
 from routest_tpu.parallel.expert import ExpertShare, gated_mlp, moe_share
 from routest_tpu.parallel.select import (attention_path, block_and_chunk,
                                          chunk_steps, selected_attention,
-                                         selected_rows, windowed_attention)
+                                         selected_rows, window_path,
+                                         window_span, windowed_attention)
 
 Params = Dict
 
@@ -216,6 +217,18 @@ class RouteLM:
                               a.d_v, self.policy.compute_dtype)
         return path, chunk_steps(length, self.select_block, self.key_chunk)
 
+    def window_steps(self, length: int) -> Tuple[str, int]:
+        """For a route padded to ``length``, in one sliding layer: which
+        window step runs (``"fused"`` or ``"xla"``: what
+        ``select.window_path`` says of this model's shapes here) and how
+        many blocks of queries it takes."""
+        a = self.attention_sizes(SLIDING)
+        block = min(self.window_block, length)
+        path = window_path(a.heads, block,
+                           window_span(length, block, a.window), a.d_nope,
+                           a.d_rope, a.d_v, self.policy.compute_dtype)
+        return path, length // block
+
     # ── what the scorer asks of a model (serve/seq_score.py) ────────
 
     @property
@@ -240,8 +253,10 @@ class RouteLM:
         return out
 
     def step_attrs(self, length: int) -> Dict[str, str]:
-        path = self.selected_steps(length)[0]
-        return {"attention": path, "mixers": f"full={path},sliding=xla"}
+        path, window = self.selected_steps(length)[0], self.window_steps(
+            length)[0]
+        return {"attention": path, "window": window,
+                "mixers": f"full={path},sliding={window}"}
 
     def step_stats(self, out: Dict, lengths) -> Dict:
         """Device values of one step for the pass's counters."""
@@ -264,11 +279,15 @@ class RouteLM:
         import numpy as np
 
         n_full = sum(1 for a, _ in self.layer_kinds() if a == FULL)
+        n_sliding = len(self.layer_kinds()) - n_full
         out = []
         for step in steps:
             path, chunks = self.selected_steps(step.length)
             out.append(("chunks", {"path": path},
                         chunks * len(step.routes) * n_full))
+            path, blocks = self.window_steps(step.length)
+            out.append(("window_blocks", {"path": path},
+                        blocks * len(step.routes) * n_sliding))
         counts = [s["counts"] for s in stats if "counts" in s]
         if counts:
             k = int(self.sizes["num_experts_per_tok"])

@@ -12,10 +12,10 @@ as the keys). The score is ``(q.k + q_shared.k_shared) * scale``;
 products take the arrays' own dtype and accumulate in float32, the
 softmax is float32.
 
-- :func:`windowed_attention`: query t sees ``t - window + 1 <= s <= t``.
-  A block of queries is scored against the few blocks of keys that its
-  window touches and nothing else: the work is O(L * window), not
-  O(L^2).
+- :func:`windowed_attention`: query t sees ``t - window + 1 <= s <= t``
+  (:func:`window_keys`). A block of queries is scored against the few
+  blocks of keys that its window touches and nothing else: the work is
+  O(L * window), not O(L^2).
 - :func:`selected_attention`: query t sees the ``top_k`` keys ``s <= t``
   with the largest selector score ``I(t, s) = sum_j w_j(t) relu(qI_j(t)
   . kI(s))`` (every ``s <= t`` while there are no more than ``top_k``;
@@ -56,6 +56,43 @@ Both visit every key tile that holds a key ``s <= t`` of the block and
 none wholly in its future (such a tile adds exactly nothing: its scores
 are all masked, so the running max, the sum and the accumulator stay as
 they are); a masked key contributes exactly zero mass in both.
+
+**Which window step runs where.** The score-softmax-value step of
+:func:`windowed_attention` has the same two forms under the same
+contract — ``(q.k + q_shared.k_shared) * scale``, products of the
+arrays' dtype into float32, float32 statistics, probabilities cast to
+``v.dtype`` for the value product, a key outside the window exactly
+zero mass — and :func:`window_path` chooses between them from heads,
+block, span, the three widths, dtype and backend alone:
+
+- ``"fused"``: one Pallas TPU kernel a block of queries
+  (:func:`_window_fused`, ``windowed_attention_step`` in a trace), a
+  grid over groups of ``HEAD_TILE`` heads and tiles of ``WINDOW_Q_TILE``
+  queries. A step fetches ONE span of keys — the tile's own and the
+  ``window - 1`` before them, rounded up to whole lanes of 128: 768 keys
+  for 256 queries under a window of 513 — at an element offset computed
+  from the block's position (a prefetched scalar), and takes one softmax
+  over it in VMEM: no running max, sum or rescaling (with tiles of keys
+  and an online softmax the per-row statistics, one lane in 128 used,
+  cost as much as the scores: PERF.md §6, PR 36), and the mask comes
+  from position iotas: none is fetched. Both key parts are scored as ONE
+  product ``d + d_shared`` deep: a per-head part 192 wide would cost two
+  128-deep passes of the MXU and the shared 64 a third, side by side
+  they are two. The layer's keys come with the length last, (B, H, D, L)
+  and (B, Dr, L): how XLA lays out an expansion 192 wide anyway, so the
+  transposes this function asks for are free and the kernel puts the
+  shared part under every head's own in VMEM. It runs on a TPU,
+  bfloat16, heads a multiple of ``HEAD_TILE``, ``d + d_shared`` and
+  ``d_v`` multiples of 128, the block whole tiles of queries, the span
+  whole lanes and no more than ``WINDOW_MAX_SPAN``. Written in XLA the
+  step sends a float32 (heads, block, span) tile through HBM five times
+  (134 MB at 64 heads, 512 queries, 1,024 keys).
+- ``"xla"``: :func:`_window_xla`, one softmax over the span of whole
+  blocks the window touches (:func:`window_span`): everywhere else, and
+  the oracle of the kernel's parity test (``tests/test_window_fused.py``).
+
+What each query saw (``n_keys``, ``first_key``) is computed in both
+forms by the same lines from the same :func:`window_keys`.
 """
 
 from __future__ import annotations
@@ -86,32 +123,61 @@ def _key_taps(keys):
             jnp.argmax(keys, -1).astype(jnp.int32))
 
 
+def window_keys(t, s, window: int):
+    """Whether the query at position ``t`` sees the key at ``s``: the
+    one expression both forms of the window step mask by."""
+    return (s <= t) & (s > t - window)
+
+
+def window_span(length: int, block: int, window: int) -> int:
+    """Keys that the XLA form scores a block of queries against: the
+    whole blocks its window touches (the length where that is less)."""
+    return min(((window - 2) // block + 2) * block, length)
+
+
+def _window_xla(q, q_shared, k, k_shared, v, keys, scale: float):
+    """One block of queries against the span of keys its window touches,
+    one softmax over the span: q (Q, H, D), q_shared (Q, H, Dr), k (S,
+    H, D), k_shared (S, Dr), v (S, H, Dv), keys (Q, S) bool → (Q, H, Dv)
+    float32."""
+    s = jnp.where(keys[None], _scores(q, q_shared, k, k_shared, scale), _NEG)
+    p = jnp.exp(s - s.max(-1, keepdims=True))
+    p = p / p.sum(-1, keepdims=True)
+    return jnp.einsum("hqk,khd->qhd", p.astype(v.dtype), v,
+                      preferred_element_type=jnp.float32)
+
+
 def windowed_attention(q_fn: Callable, k, k_shared, v, *, window: int,
                        scale: float, block: int = 512):
     """→ (out (B, L, H, Dv), n_keys (B, L), first_key (B, L)). ``L``
     must be a multiple of ``block`` (or smaller than it)."""
-    b_sz, length, heads, _ = k.shape
+    b_sz, length, heads, d = k.shape
     block = min(block, length)
     if length % block:
         raise ValueError(f"length {length} is not a multiple of {block}")
     n_blk = length // block
-    span = min(((window - 2) // block + 2) * block, length)
+    span = window_span(length, block, window)
+    fused = window_path(heads, block, span, d, k_shared.shape[-1],
+                        v.shape[-1], k.dtype) == "fused"
+    if fused:       # by head, the keys with the length last, once a layer
+        k_heads, ks_t = k.transpose(0, 2, 3, 1), k_shared.transpose(0, 2, 1)
+        v_heads = v.transpose(0, 2, 1, 3)
 
     def one(n):
         b, i = n // n_blk, n % n_blk
         q, q_shared = q_fn(b, i * block)
         start = jnp.clip((i + 1) * block - span, 0, length - span)
-        kw = jax.lax.dynamic_slice_in_dim(k[b], start, span, 0)
-        ks = jax.lax.dynamic_slice_in_dim(k_shared[b], start, span, 0)
-        vw = jax.lax.dynamic_slice_in_dim(v[b], start, span, 0)
         t = i * block + jnp.arange(block)[:, None]
-        s_pos = start + jnp.arange(span)[None, :]
-        keys = (s_pos <= t) & (s_pos > t - window)
-        s = jnp.where(keys[None], _scores(q, q_shared, kw, ks, scale), _NEG)
-        p = jnp.exp(s - s.max(-1, keepdims=True))
-        p = p / p.sum(-1, keepdims=True)
-        out = jnp.einsum("hqk,khd->qhd", p.astype(v.dtype), vw,
-                         preferred_element_type=jnp.float32)
+        keys = window_keys(t, start + jnp.arange(span)[None, :], window)
+        if fused:
+            out = _window_fused(jnp.concatenate([q, q_shared], -1), k_heads,
+                                ks_t, v_heads, b, i * block, window=window,
+                                scale=scale).transpose(1, 0, 2)
+        else:
+            out = _window_xla(
+                q, q_shared, jax.lax.dynamic_slice_in_dim(k[b], start, span),
+                jax.lax.dynamic_slice_in_dim(k_shared[b], start, span),
+                jax.lax.dynamic_slice_in_dim(v[b], start, span), keys, scale)
         n_keys, first = _key_taps(keys)
         return out.astype(v.dtype), n_keys, first + start
 
@@ -350,6 +416,125 @@ def _attend_fused(q, q_shared, k, k_shared, v, keys, b, n_tiles, *,
         interpret=interpret,
     )(at, q.transpose(1, 0, 2), q_shared.transpose(1, 0, 2), k, k_shared, v,
       keys.astype(jnp.int8))
+
+
+# ── the window step of windowed_attention, as a kernel ───────────────
+
+WINDOW_Q_TILE = 256         # queries of one grid step of the window kernel
+WINDOW_MAX_SPAN = 2048      # (readings of the tilings: PERF.md §6, PR 36)
+_LANES = 128
+
+
+def window_path(heads: int, block: int, span: int, d: int, d_shared: int,
+                d_v: int, dtype, backend: str = "") -> str:
+    """The step :func:`windowed_attention` runs at these shapes:
+    ``"fused"`` (the Pallas kernel) on a TPU where bfloat16 arrays tile,
+    ``"xla"`` everywhere else. ``block`` is the block of queries it
+    really takes and ``span`` the keys :func:`window_span` gives it (both
+    after the length cut them; the kernel's own span of keys is no
+    longer, and a float32 tile of ``WINDOW_Q_TILE`` queries by more than
+    ``WINDOW_MAX_SPAN`` keys a head would not fit VMEM); the kernel
+    scores the two key parts as one, ``d + d_shared`` wide; ``backend``
+    defaults to JAX's own."""
+    tiles = (heads % HEAD_TILE == 0 and block % WINDOW_Q_TILE == 0
+             and span % _LANES == 0 and span <= WINDOW_MAX_SPAN
+             and (d + d_shared) % _LANES == 0 and d_v % _LANES == 0)
+    on_tpu = (backend or jax.default_backend()) == "tpu"
+    return ("fused" if on_tpu and tiles and jnp.dtype(dtype) == jnp.bfloat16
+            else "xla")
+
+
+def _window_first_key(t0, back: int, n_k: int, length: int):
+    """The first of the ``n_k`` keys fetched for the tile of queries
+    from position ``t0`` on, ``back`` keys before it where the route has
+    them: in whole lanes, so that the compiler sees an aligned offset."""
+    return jnp.clip((t0 - back) // _LANES, 0, (length - n_k) // _LANES) \
+        * _LANES
+
+
+def _window_kernel(at_ref, q_ref, k_ref, ks_ref, v_ref, o_ref, kk_ref, *,
+                   scale: float, window: int, back: int, length: int):
+    """One grid step: ``HEAD_TILE`` heads of one tile of queries against
+    the one span of keys their windows lie in, one softmax over it.
+    ``at_ref`` (2,): the route and the position of the block's first
+    query; which keys a query sees comes from positions alone."""
+    n_q, d, n_k = q_ref.shape[1], k_ref.shape[2], k_ref.shape[3]
+    t0 = at_ref[1] + pl.program_id(1) * n_q
+    # both key parts as one, the shared part under every head's own: one
+    # product of whole 128-deep passes
+    kk_ref[:, :d, :] = k_ref[0]
+    kk_ref[:, d:, :] = jnp.broadcast_to(
+        ks_ref[...], kk_ref.shape[:1] + ks_ref.shape[1:])
+    t = t0 + jax.lax.broadcasted_iota(jnp.int32, (n_q, n_k), 0)
+    s_pos = _window_first_key(t0, back, n_k, length) \
+        + jax.lax.broadcasted_iota(jnp.int32, (n_q, n_k), 1)
+    s = jnp.einsum("hqd,hdk->hqk", q_ref[...], kk_ref[...],
+                   preferred_element_type=jnp.float32) * scale
+    s = jnp.where(window_keys(t, s_pos, window)[None], s, _NEG)
+    # a query sees its own key, so the max is a real score and a masked
+    # key's exp(NEG - max) is exactly 0
+    p = jnp.exp(s - s.max(-1, keepdims=True))
+    out = jnp.einsum("hqk,hkd->hqd", p.astype(v_ref.dtype), v_ref[0],
+                     preferred_element_type=jnp.float32)
+    o_ref[...] = (out / p.sum(-1, keepdims=True)).astype(o_ref.dtype)
+
+
+def _window_fused(q, k, k_shared, v, b, t0, *, window: int, scale: float,
+                  q_tile: int = WINDOW_Q_TILE, head_tile: int = HEAD_TILE,
+                  interpret: bool = False):
+    """The block of queries from position ``t0`` of route ``b`` on
+    against the keys its window sees, as one kernel: q (Q, H, D + Dr),
+    both parts of a query side by side; k (B, H, D, L) and k_shared (B,
+    Dr, L), the length last (how XLA lays out a key part of width 192
+    anyway, so the expansion writes it at no cost); v (B, H, L, Dv) by
+    head → (H, Q, Dv) in ``v.dtype``. A grid over groups of heads and
+    tiles of ``q_tile`` queries; a step fetches ONE span of keys, the
+    tile's own and the ``window - 1`` before them rounded up to whole
+    lanes (the whole route where it is shorter), at an element offset
+    straight from the whole arrays (``b`` and ``t0`` are prefetched
+    scalars), and takes one softmax over it: no running statistics, no
+    mask fetched."""
+    n_q, heads, d_q = q.shape
+    d, d_r, d_v, length = k.shape[2], k_shared.shape[1], v.shape[-1], v.shape[2]
+    back = -(-(window - 1) // _LANES) * _LANES
+    n_k = min(q_tile + back, length)
+    if n_q % q_tile or q_tile % _LANES or length % _LANES or heads % head_tile:
+        raise ValueError(f"{n_q} queries in tiles of {q_tile}, {length} keys, "
+                         f"{heads} heads in tiles of {head_tile}: not whole "
+                         f"tiles, or not whole lanes of {_LANES}")
+    at = jnp.stack([b, t0]).astype(jnp.int32)
+
+    def first_key(i, at):
+        return _window_first_key(at[1] + i * q_tile, back, n_k, length)
+
+    el = pl.Element         # offsets in elements: a span starts anywhere
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(heads // head_tile, n_q // q_tile),
+        in_specs=[
+            pl.BlockSpec((head_tile, q_tile, d_q), lambda g, i, at: (g, i, 0)),
+            pl.BlockSpec((el(1), el(head_tile), el(d), el(n_k)),
+                         lambda g, i, at: (at[0], g * head_tile, 0,
+                                           first_key(i, at))),
+            pl.BlockSpec((el(1), el(d_r), el(n_k)),
+                         lambda g, i, at: (at[0], 0, first_key(i, at))),
+            pl.BlockSpec((el(1), el(head_tile), el(n_k), el(d_v)),
+                         lambda g, i, at: (at[0], g * head_tile,
+                                           first_key(i, at), 0))],
+        out_specs=pl.BlockSpec((head_tile, q_tile, d_v),
+                               lambda g, i, at: (g, i, 0)),
+        scratch_shapes=[pltpu.VMEM((head_tile, d_q, n_k), k.dtype)])
+    return pl.pallas_call(
+        functools.partial(_window_kernel, scale=scale, window=window,
+                          back=back, length=length),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((heads, n_q, d_v), v.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=_VMEM_BYTES),
+        name="windowed_attention_step",
+        interpret=interpret,
+    )(at, q.transpose(1, 0, 2), k, k_shared, v)
 
 
 def selected_attention(q_fn: Callable, k, k_shared, v, idx_fn: Callable,

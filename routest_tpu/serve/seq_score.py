@@ -43,12 +43,15 @@ ends in one sync.
 Spans: ``seq.score_pass`` (root) with one ``seq.step`` child per step
 (the host's dispatch of it; attrs ``length_class``, ``routes``,
 ``real_tokens``, ``padded_tokens``, ``mixers`` and, for ``RouteLM``,
-``attention``: the online-softmax step its full layers run, ``fused`` or
+``attention`` and ``window``: the online-softmax step its full layers
+run and the window step its sliding layers run, each ``fused`` or
 ``xla``) and ``seq.wait`` (the sync). As every recorded span they are
 ``TraceAnnotation``s too. Counters: ``rtpu_seq_tokens_total{kind=real|
 padded}`` from the plan, for every model. ``RouteLM``:
 ``rtpu_seq_attention_chunks_total{path=fused|xla}`` (the full layers'
-steps over chunks of keys) from the plan; and, read from the device
+steps over chunks of keys) and ``rtpu_seq_window_blocks_total{path=
+fused|xla}`` (the sliding layers' blocks of queries) from the plan; and,
+read from the device
 once a pass after its sync, ``rtpu_seq_expert_tokens{stat=max|mean}``
 (tokens per held expert per step and layer), ``rtpu_seq_expert_load_
 max_over_mean``, ``rtpu_seq_held_assignment_share`` (the share of a
@@ -94,6 +97,11 @@ def _seq_metrics():
                 "Online-softmax steps over chunks of keys that the full "
                 "layers of the dispatched steps ran, by the form of the "
                 "step (fused: the Pallas kernel; xla).", ("path",)),
+            "window_blocks": reg.counter(
+                "rtpu_seq_window_blocks_total",
+                "Blocks of queries that the sliding layers of the "
+                "dispatched steps ran, by the form of the window step "
+                "(fused: the Pallas kernel; xla).", ("path",)),
             "expert_tokens": reg.gauge(
                 "rtpu_seq_expert_tokens",
                 "Tokens a held expert got in one step of one expert "
@@ -136,8 +144,8 @@ def _seq_metrics():
     return _metrics
 
 
-_COUNTERS = ("tokens", "chunks", "sparse_keys", "linear_chunks", "gqa_keys",
-             "mtp_positions")
+_COUNTERS = ("tokens", "chunks", "window_blocks", "sparse_keys",
+             "linear_chunks", "gqa_keys", "mtp_positions")
 
 
 class Step(NamedTuple):

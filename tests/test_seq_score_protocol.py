@@ -122,7 +122,7 @@ def test_route_lms_plan_and_tables_are_what_they_were(scorers):
         "rows": (4, 3, 128), "n_keys": (5, 4, 96), "first_key": (5, 4, 96),
         "chosen": (4, 4, 96, 4), "selected": (2, 4, 3, 96)}
     assert tables["selected"].dtype == jnp.bool_
-    assert m.step_attrs(96) == {"attention": "xla",
+    assert m.step_attrs(96) == {"attention": "xla", "window": "xla",
                                 "mixers": "full=xla,sliding=xla"}
 
 
@@ -205,6 +205,17 @@ def test_route_lm_counters_are_what_they_were(registry, scorers):
     want = sum(m.selected_steps(s.length)[1] * len(s.routes) * 2
                for s in scorer.plan(np.asarray(LENGTHS)))
     assert chunks == {("xla",): want}
+    # three sliding layers, blocks of 8 queries, one route a step
+    plan = scorer.plan(np.asarray(LENGTHS))
+    assert _family(registry, "rtpu_seq_window_blocks_total") == {
+        ("xla",): 3 * sum(s.length // 8 * len(s.routes) for s in plan)}
+    assert m.pass_counts(plan, [], sum(LENGTHS))[1] == (
+        "window_blocks", {"path": "xla"}, 3 * 96 // 8)
+    from routest_tpu.obs import get_tracer
+    steps = [s for s in get_tracer().buffer.snapshot()
+             if s["name"] == "seq.step"][-len(plan):]
+    assert [(s["attrs"]["attention"], s["attrs"]["window"]) for s in steps] \
+        == [("xla", "xla")] * len(plan)
     assert _family(registry, "rtpu_seq_expert_load_max_over_mean")[()] >= 1.0
     assert 0 < _family(registry, "rtpu_seq_held_assignment_share")[()] <= 1.0
     assert _family(registry, "rtpu_seq_selected_keys_per_query")[()] > 1.0
@@ -243,6 +254,7 @@ def test_kexaone_counters_and_span_attributes(registry, scorers):
     assert 0.3 < _family(registry,
                          "rtpu_seq_held_assignment_share")[()] < 0.7
     assert _family(registry, "rtpu_seq_attention_chunks_total") == {}
+    assert _family(registry, "rtpu_seq_window_blocks_total") == {}
     assert _family(registry, "rtpu_seq_sparse_keys_total") == {}
     steps = [s for s in get_tracer().buffer.snapshot()
              if s["name"] == "seq.step"][-3:]

@@ -174,3 +174,33 @@ def test_selected_attention_step_compiles_for_v5e(v5e, routes, length):
         on((routes, heads, length, d_v)), on((block, length), jnp.bool_),
         on((), jnp.int32), on((), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()   # Mosaic, not interpret
+
+
+# Its sliding layers' window step at the published widths (64 heads, key
+# parts of 192 + 64, values of 128, a window of 513): the same two
+# classes. The keys come with the length last, as the layer's expansion
+# writes them.
+@pytest.mark.parametrize("routes,length", [(1, 26624), (3, 1536)])
+def test_windowed_attention_step_compiles_for_v5e(v5e, routes, length):
+    from routest_tpu.parallel import select
+
+    heads, block, d, d_shared, d_v, window = 64, 512, 192, 64, 128, 513
+    assert select.window_path(
+        heads, block, select.window_span(length, block, window), d, d_shared,
+        d_v, jnp.bfloat16, backend="tpu") == "fused"
+
+    def on(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    compiled = jax.jit(functools.partial(
+        select._window_fused, window=window,
+        scale=(d + d_shared) ** -0.5)).lower(
+        on((block, heads, d + d_shared)), on((routes, heads, d, length)),
+        on((routes, d_shared, length)), on((routes, heads, length, d_v)),
+        on((), jnp.int32), on((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text                 # Mosaic, not interpret
+    # its own name in the trace: the full layers' roofline sums every
+    # operation named ``selected_attention_step…``
+    assert "windowed_attention_step" in text
+    assert "selected_attention_step" not in text
