@@ -238,11 +238,18 @@ class ContinuousTrainer:
         children (aggregate / upload / steps / apply / save). A child
         ends where the host already is — nothing synchronises for the
         tracer's sake — so an upload still in flight when ``upload``
-        closes is absorbed by ``steps``."""
-        from routest_tpu.obs import trace_span
+        closes is absorbed by ``steps``. Where it is recorded the root
+        also carries the host's account of the cycle (``obs/host.py``:
+        ``psi_cpu_ms``, ``steal_ms``, ``nivcsw``, ``gc_ms`` …) and
+        ``compile_ms`` where a program compiled or came from the
+        persistent cache meanwhile (the first cycle's step)."""
+        from routest_tpu.core.cache import compile_seconds
+        from routest_tpu.obs import host, trace_span
         from routest_tpu.utils.logging import get_logger
 
         with trace_span("live.retrain") as root:
+            before = host.begin(root)
+            compiled = compile_seconds()
             try:
                 result, self.last_result = self._cycle(root)
             except Exception as e:
@@ -255,6 +262,10 @@ class ContinuousTrainer:
                     "trained": False, "reason": f"{type(e).__name__}: {e}"}
             _trainer_metrics()["runs"].labels(result=result).inc()
             root.set_attr("result", result)
+            compiled = compile_seconds() - compiled
+            if compiled > 0.0:
+                root.set_attr("compile_ms", 1e3 * compiled)
+            host.end(root, before)
             return self.last_result
 
     def _cycle(self, root) -> Tuple[str, Dict]:

@@ -12,8 +12,11 @@ Two pieces:
   call site reports into: the ETA scoring batcher
   (``serve/ml_service.py``), the fastlane cache in front of it
   (``serve/fastlane.py``, rows served *without* device compute), the
-  road-solve batcher (``optimize/road_router.py``), and the dispatch
-  batcher/reopt passes (``routest_tpu/dispatch``). One ``record()`` per
+  road-solve batcher (``optimize/road_router.py``), the dispatch
+  batcher/reopt passes (``routest_tpu/dispatch``), and the sequence
+  scorer (``serve/seq_score.py``: rows are tokens, a bucket is a length
+  class, compute seconds are the step's device time read from ordered
+  waits, no queue). One ``record()`` per
   device call carries real rows, padded rows, the bucket chosen, and
   the queue-vs-compute wall split; the ledger rolls them into the
   ``rtpu_efficiency_*`` families on the process registry (so they flow
@@ -66,7 +69,8 @@ _log = get_logger("routest_tpu.obs.efficiency")
 # adds its program name here and is covered by the padding objective
 # from its first recorded row.
 PROGRAMS: Tuple[str, ...] = (
-    "eta_score", "route_solve", "dispatch_solve", "dispatch_reopt")
+    "eta_score", "route_solve", "dispatch_solve", "dispatch_reopt",
+    "seq_score")
 
 # Fill-fraction histogram bounds: real/padded per device call (1.0 =
 # zero padding waste).

@@ -39,13 +39,38 @@ ends in one sync.
   ``pass_counts(steps, stats, real_tokens)`` → [(family, labels,
   value)] for the families of :func:`_seq_metrics`, from the plan and
   from the steps' stats, which are fetched once a pass after its sync.
+  The stats are also what a step's device time is read from: a step's
+  program hands them back when it ends, the device runs the queued
+  steps in order, so the host waits on them one step after another
+  once all are dispatched. A step whose ``step_stats`` is empty has
+  nothing to wait on and is timed with the next step that has (a tail
+  of such steps with the pass's result tables): one span for the
+  group, its tokens and routes summed, under the class of its first
+  step.
 
 Spans: ``seq.score_pass`` (root) with one ``seq.step`` child per step
 (the host's dispatch of it; attrs ``length_class``, ``routes``,
 ``real_tokens``, ``padded_tokens``, ``mixers`` and, for ``RouteLM``,
 ``attention`` and ``window``: the online-softmax step its full layers
 run and the window step its sliding layers run, each ``fused`` or
-``xla``) and ``seq.wait`` (the sync). As every recorded span they are
+``xla``; ``compile_ms`` where the dispatch compiled or fetched its
+program: ``core/cache.compile_seconds`` grew while the span was open)
+and ``seq.wait`` (the sync). Where the pass is recorded, ``seq.wait``
+holds one ``seq.wait.step`` a timed step, in dispatch order, with the
+step's ``length_class``, ``routes``, ``real_tokens``, ``padded_tokens``
+and ``device_ms``: the step's completion less the later of the step
+before's completion and its own dispatch, on the host's
+``perf_counter``; each is reported once to the goodput ledger
+(``obs/efficiency.py``) as program ``seq_score``, bucket = the class,
+rows = tokens (real against launched), so ``/api/efficiency`` holds
+tokens per device-second by length class; a pass that compiled reports
+none (the host reached its waits long after the first steps ended, so
+its ``device_ms`` are upper bounds). The root then carries
+``device_ms``, their sum (what of a pass no step accounts for is the
+rest), and the host's account of the pass (``obs/host.py``:
+``psi_cpu_ms``, ``steal_ms``, ``nivcsw``, ``gc_ms`` …). A pass that is
+not recorded (tracer off, trace unsampled) waits once and reports
+none of this. As every recorded span they are
 ``TraceAnnotation``s too. Counters: ``rtpu_seq_tokens_total{kind=real|
 padded}`` from the plan, for every model. ``RouteLM``:
 ``rtpu_seq_attention_chunks_total{path=fused|xla}`` (the full layers'
@@ -74,6 +99,7 @@ that got a likelihood term for the arc after next). ``seq.step`` carries
 
 from __future__ import annotations
 
+import time
 from typing import Dict, List, NamedTuple, Optional, Sequence
 
 import numpy as np
@@ -218,6 +244,42 @@ def plan_pass(lengths: Sequence[int], quantum: int, max_step_tokens: int,
     return steps
 
 
+def _wait_steps(steps, stats, tables, report: bool) -> float:
+    """Wait on the dispatched steps one after another, in the order the
+    device runs them; one ``seq.wait.step`` span and, with ``report``,
+    one goodput-ledger record a timed step (the module's text says
+    which steps are timed together). ``steps``: (span attributes, host
+    time of dispatch) a step. Returns the summed device milliseconds."""
+    import jax
+
+    from routest_tpu.obs import trace_span
+    from routest_tpu.obs.efficiency import get_ledger
+
+    total, done, group = 0.0, None, []
+    for i, (step, st) in enumerate(zip(steps, stats)):
+        group.append(step)
+        if not jax.tree_util.tree_leaves(st):
+            if i < len(stats) - 1:
+                continue
+            st = tables
+        attrs = dict(group[0][0])
+        for name in ("routes", "real_tokens", "padded_tokens"):
+            attrs[name] = sum(a[name] for a, _ in group)
+        with trace_span("seq.wait.step", **attrs) as span:
+            jax.block_until_ready(st)
+            now = time.perf_counter()
+            began = group[0][1] if done is None else max(done, group[0][1])
+            device_ms = 1e3 * (now - began)
+            span.set_attr("device_ms", device_ms)
+        if report:
+            get_ledger().record(
+                "seq_score", real_rows=attrs["real_tokens"],
+                padded_rows=attrs["real_tokens"] + attrs["padded_tokens"],
+                bucket=attrs["length_class"], compute_s=device_ms / 1e3)
+        total, done, group = total + device_ms, now, []
+    return total
+
+
 class SeqScores(NamedTuple):
     """Device arrays over the table's rows: ``next_logit`` and ``lse``
     (R, L_max) float32, ``loglik`` (R,), ``rows`` (R, P, vocab_held) the
@@ -313,31 +375,45 @@ class RouteScorer:
         import jax
         import jax.numpy as jnp
 
-        from routest_tpu.obs import trace_span
+        from routest_tpu.core.cache import compile_seconds
+        from routest_tpu.obs import host, trace_span
 
         plan = plan if plan is not None else self.plan(lengths)
         real = sum(s.real_tokens for s in plan)
         padded = sum(s.padded_tokens for s in plan)
         with trace_span("seq.score_pass", routes=int(ids.shape[0]),
                         steps=len(plan), real_tokens=real,
-                        padded_tokens=padded):
+                        padded_tokens=padded) as root:
+            before = host.begin(root)
             tables = self._empty_tables(ids.shape[0], ids.shape[1],
                                         rows_at.shape[1])
-            stats = []
+            stats, steps = [], []       # steps: (attrs, dispatched at)
+            fresh = False               # a step of this pass compiled
             for step in plan:
-                with trace_span("seq.step", length_class=step.length,
-                                routes=int((step.routes >= 0).sum()),
-                                real_tokens=step.real_tokens,
-                                padded_tokens=step.padded_tokens,
-                                **self.model.step_attrs(step.length)):
+                attrs = {"length_class": step.length,
+                         "routes": int((step.routes >= 0).sum()),
+                         "real_tokens": step.real_tokens,
+                         "padded_tokens": step.padded_tokens}
+                with trace_span("seq.step", **attrs,
+                                **self.model.step_attrs(step.length)) as span:
+                    compiled = compile_seconds()
                     tables, st = self._step(
                         self.params, ids, lengths, rows_at,
                         jnp.asarray(step.routes, jnp.int32), tables,
                         step.length)
                     stats.append(st)
+                    steps.append((attrs, time.perf_counter()))
+                    compiled = compile_seconds() - compiled
+                    if compiled > 0.0:
+                        span.set_attr("compile_ms", 1e3 * compiled)
+                        fresh = True
             with trace_span("seq.wait"):
+                if root.sampled:
+                    root.set_attr("device_ms", _wait_steps(
+                        steps, stats, tables, report=not fresh))
                 jax.block_until_ready(tables)
             self._count(plan, jax.device_get(stats), real, padded)
+            host.end(root, before)
         taps = {k: v for k, v in tables.items()
                 if k not in ("next_logit", "lse", "loglik", "rows")}
         return SeqScores(tables["next_logit"], tables["lse"],

@@ -25,12 +25,35 @@ def _scorer(toy, **kw):
     return m, params, RouteScorer(m, params, **kw)
 
 
+FIRST_PASS = {}      # model → the seq.step spans of its compiling pass
+
+
+def _last_pass(names=("seq.step",)):
+    """The spans of those names that the last recorded pass left, in
+    the order they finished."""
+    from routest_tpu.obs import get_tracer
+
+    spans = get_tracer().buffer.snapshot()
+    (root,) = [s for s in spans if s["name"] == "seq.score_pass"][-1:]
+    mine = {root["span_id"]} | {s["span_id"] for s in spans
+                                if s["parent_id"] == root["span_id"]}
+    return root, [s for s in spans
+                  if s["parent_id"] in mine and s["name"] in names]
+
+
 @pytest.fixture(scope="module")
 def scorers():
     """One scorer a model for the whole file: its step programs compile
-    once."""
-    return {name: _scorer(toy, max_step_tokens=128)
-            for name, toy in TOYS.items()}
+    once, in a first pass here, with the compiles counted."""
+    from routest_tpu.core.cache import count_compiles
+
+    count_compiles()
+    out = {}
+    for name, toy in TOYS.items():
+        out[name] = m, params, scorer = _scorer(toy, max_step_tokens=128)
+        scorer.score(*(jnp.asarray(a) for a in toy.routes(4, LENGTHS)))
+        FIRST_PASS[name] = _last_pass()[1]
+    return out
 
 
 @pytest.fixture(scope="module", params=["dots3", "sala", "kexaone"])
@@ -271,3 +294,135 @@ def test_the_other_two_models_emit_no_gqa_or_module_counters(registry,
         scorer.score(ids, lengths, rows_at)
     assert _family(registry, "rtpu_seq_gqa_keys_total") == {}
     assert _family(registry, "rtpu_seq_mtp_positions_total") == {}
+
+
+# ── a pass accounts for its own time (ISSUE 37) ─────────────────────
+
+
+@pytest.fixture
+def ledger(monkeypatch):
+    """A goodput ledger of the test's own as the process's."""
+    from routest_tpu.obs import MetricsRegistry, efficiency
+
+    mine = efficiency.GoodputLedger(registry=MetricsRegistry())
+    monkeypatch.setattr(efficiency, "_ledger", mine)
+    return mine
+
+
+@pytest.mark.parametrize("name", sorted(TOYS))
+def test_a_pass_leaves_one_timed_wait_a_step_in_dispatch_order(
+        name, scorers, ledger):
+    _, _, scorer = scorers[name]
+    ids, lengths, rows_at = (jnp.asarray(a)
+                             for a in TOYS[name].routes(4, LENGTHS))
+    scorer.score(ids, lengths, rows_at)
+    plan = scorer.plan(np.asarray(LENGTHS))
+    root, waits = _last_pass(("seq.wait.step",))
+    want = [{"length_class": s.length, "routes": int((s.routes >= 0).sum()),
+             "real_tokens": s.real_tokens, "padded_tokens": s.padded_tokens}
+            for s in plan]
+    assert [{k: w["attrs"][k] for k in want[0]} for w in waits] == want
+    assert all(w["attrs"]["device_ms"] > 0.0 for w in waits)
+    total = sum(w["attrs"]["device_ms"] for w in waits)
+    assert root["attrs"]["device_ms"] == pytest.approx(total)
+    assert 0.0 < total <= root["duration_ms"]
+    # every wait lies inside the pass's one seq.wait
+    (wait,) = _last_pass(("seq.wait",))[1]
+    assert {w["parent_id"] for w in waits} == {wait["span_id"]}
+    assert sum(w["duration_ms"] for w in waits) <= wait["duration_ms"]
+    # the host's account of the pass, as far as this machine gives one
+    assert root["attrs"]["cpu_ms"] > 0.0 and root["attrs"]["gc_ms"] >= 0.0
+    # one ledger record a step: tokens, launched tokens, the class
+    seq = ledger.snapshot()["programs"]["seq_score"]
+    assert seq["calls"] == len(plan)
+    assert seq["rows"] == sum(LENGTHS)
+    assert seq["padded_rows"] == sum(s.length * len(s.routes) for s in plan)
+    assert seq["device_s"] == pytest.approx(total / 1e3, rel=1e-3)
+    assert {b: (w["rows"], w["padded"]) for b, w in seq["buckets"].items()} \
+        == {s.length: (s.real_tokens, s.length * len(s.routes))
+            for s in plan}
+
+
+@pytest.mark.parametrize("name", sorted(TOYS))
+def test_with_the_tracer_off_a_pass_waits_once_and_reports_nothing(
+        name, scorers, ledger, monkeypatch):
+    from routest_tpu.obs import Tracer, configure_tracer, get_tracer
+
+    _, _, scorer = scorers[name]
+    args = [jnp.asarray(a) for a in TOYS[name].routes(4, LENGTHS)]
+    waited, block = [], jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: waited.append(1) or block(x))
+    old = get_tracer()
+    off = configure_tracer(Tracer(enabled=False))
+    try:
+        scores = scorer.score(*args)
+    finally:
+        configure_tracer(old)
+    assert len(waited) == 1 and off.buffer.snapshot() == []
+    assert ledger.snapshot()["programs"]["seq_score"]["calls"] == 0
+    on = scorer.score(*args)
+    np.testing.assert_array_equal(scores.loglik, on.loglik)
+    assert len(waited) == 1 + len(scorer.plan(np.asarray(LENGTHS))) + 1
+
+
+@pytest.mark.parametrize("name", sorted(TOYS))
+def test_the_steps_that_compiled_say_so_and_no_later_one_does(
+        name, scorers, ledger):
+    first = FIRST_PASS[name]
+    assert len(first) == 3
+    assert all(s["attrs"]["compile_ms"] > 0.0 for s in first)
+    assert all(s["attrs"]["compile_ms"] <= s["duration_ms"] for s in first)
+    _, _, scorer = scorers[name]
+    scorer.score(*(jnp.asarray(a) for a in TOYS[name].routes(4, LENGTHS)))
+    assert not any("compile_ms" in s["attrs"] for s in _last_pass()[1])
+
+
+def test_a_pass_that_compiled_reports_no_device_seconds(ledger):
+    """Its waits began long after its first steps ended."""
+    _, _, scorer = _scorer(dots3, max_step_tokens=128)
+    args = [jnp.asarray(a) for a in dots3.routes(4, [16])]
+    scorer.score(*args)
+    assert ledger.snapshot()["programs"]["seq_score"]["calls"] == 0
+    (wait,) = _last_pass(("seq.wait.step",))[1]
+    assert wait["attrs"]["device_ms"] > 0.0
+    scorer.score(*args)
+    assert ledger.snapshot()["programs"]["seq_score"]["calls"] == 1
+
+
+class _NoStats:
+    """A model whose steps of the middle class hand back no stats."""
+
+    def __init__(self, model, silent):
+        self._model, self._silent = model, silent
+
+    def __getattr__(self, name):
+        return getattr(self._model, name)
+
+    def step_stats(self, out, lengths):
+        if out["lse"].shape[1] in self._silent:
+            return {}
+        return self._model.step_stats(out, lengths)
+
+    def pass_counts(self, steps, stats, real):
+        return []
+
+
+@pytest.mark.parametrize("silent,want", [
+    ((72,), [(96, 96, 1), (72, 103, 2)]),       # timed with the next one
+    ((40,), [(96, 96, 1), (72, 70, 1), (40, 33, 1)]),   # a tail: the tables
+    ((96, 72, 40), [(96, 199, 3)])])
+def test_a_step_with_nothing_to_wait_on_is_timed_with_the_next(
+        silent, want, ledger):
+    m = dots3.model()
+    params = jax.jit(m.init)(jax.random.PRNGKey(0))
+    scorer = RouteScorer(_NoStats(m, silent), params, max_step_tokens=128)
+    args = [jnp.asarray(a) for a in dots3.routes(4, LENGTHS)]
+    scorer.score(*args)
+    scorer.score(*args)
+    root, waits = _last_pass(("seq.wait.step",))
+    assert [(w["attrs"]["length_class"], w["attrs"]["real_tokens"],
+             w["attrs"]["routes"]) for w in waits] == want
+    assert sum(w["attrs"]["real_tokens"] for w in waits) == sum(LENGTHS)
+    assert root["attrs"]["device_ms"] == pytest.approx(
+        sum(w["attrs"]["device_ms"] for w in waits))
