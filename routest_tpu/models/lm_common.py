@@ -96,16 +96,23 @@ def map_rows(fn, x, block: int):
     return out.reshape(n * block, -1)[:t]
 
 
-def expert_pass_counts(counts: Sequence, assignments: float) -> List[Tuple]:
+def expert_pass_counts(counts: Sequence, assignments: float,
+                       row_tile: int = 1) -> List[Tuple]:
     """(family, labels, value) of one pass's expert layers for the
-    scorer's gauges. ``counts``: for each step the tokens every held
-    expert got in each expert layer, (layers, experts_held);
+    scorer's gauges and counters. ``counts``: for each step the tokens
+    every held expert got in each expert layer, (layers, experts_held);
     ``assignments``: the (token, slot) choices the pass's real tokens
-    made in those layers, on held experts or not."""
+    made in those layers, on held experts or not; ``row_tile``: the rows
+    an expert's part of the grouped product's layout is rounded up to
+    (``parallel/expert.row_tile_of``)."""
     import numpy as np
 
-    per_layer = np.concatenate([np.asarray(c, np.float64) for c in counts],
+    from routest_tpu.parallel.expert import rows_visited
+
+    per_layer = np.concatenate([np.asarray(c, np.int64) for c in counts],
                                0)                    # (steps·layers, E)
+    visited = float(rows_visited(per_layer, row_tile).sum())
+    per_layer = per_layer.astype(np.float64)
     means = per_layer.mean(1)
     busy = means > 0
     out = [("expert_tokens", {"stat": "max"}, per_layer.max()),
@@ -114,4 +121,6 @@ def expert_pass_counts(counts: Sequence, assignments: float) -> List[Tuple]:
         out.append(("load", {}, float(np.mean(
             per_layer[busy].max(1) / means[busy]))))
     out.append(("held_share", {}, per_layer.sum() / max(1, assignments)))
+    out += [("expert_rows", {"kind": "visited"}, visited),
+            ("expert_rows", {"kind": "held"}, float(per_layer.sum()))]
     return out

@@ -62,7 +62,8 @@ from routest_tpu.core.dtypes import BF16_POLICY, Policy
 from routest_tpu.models.lm_common import dot32 as _dot
 from routest_tpu.models.lm_common import (expert_pass_counts, next_arc_head,
                                           rms_norm, rope)
-from routest_tpu.parallel.expert import ExpertShare, gated_mlp, moe_share
+from routest_tpu.parallel.expert import (ExpertShare, expert_path, gated_mlp,
+                                         moe_share, row_tile_of)
 from routest_tpu.parallel.select import (attention_path, block_and_chunk,
                                          chunk_steps, selected_attention,
                                          selected_rows, window_path,
@@ -229,6 +230,14 @@ class RouteLM:
                            a.d_rope, a.d_v, self.policy.compute_dtype)
         return path, length // block
 
+    def expert_blocks(self) -> Tuple[str, int]:
+        """(the form of the held experts' grouped product at this
+        model's widths, the expert layers held)."""
+        n_moe = sum(1 for _, f in self.layer_kinds() if f == "moe")
+        return expert_path(int(self.sizes["hidden_size"]),
+                           int(self.sizes["moe_intermediate_size"]),
+                           self.policy.compute_dtype), n_moe
+
     # ── what the scorer asks of a model (serve/seq_score.py) ────────
 
     @property
@@ -255,8 +264,12 @@ class RouteLM:
     def step_attrs(self, length: int) -> Dict[str, str]:
         path, window = self.selected_steps(length)[0], self.window_steps(
             length)[0]
-        return {"attention": path, "window": window,
-                "mixers": f"full={path},sliding={window}"}
+        attrs = {"attention": path, "window": window,
+                 "mixers": f"full={path},sliding={window}"}
+        experts, n_moe = self.expert_blocks()
+        if n_moe:
+            attrs["experts"] = experts
+        return attrs
 
     def step_stats(self, out: Dict, lengths) -> Dict:
         """Device values of one step for the pass's counters."""
@@ -280,6 +293,7 @@ class RouteLM:
 
         n_full = sum(1 for a, _ in self.layer_kinds() if a == FULL)
         n_sliding = len(self.layer_kinds()) - n_full
+        experts, n_moe = self.expert_blocks()
         out = []
         for step in steps:
             path, chunks = self.selected_steps(step.length)
@@ -288,11 +302,14 @@ class RouteLM:
             path, blocks = self.window_steps(step.length)
             out.append(("window_blocks", {"path": path},
                         blocks * len(step.routes) * n_sliding))
+            if n_moe:
+                out.append(("expert_blocks", {"path": experts}, n_moe))
         counts = [s["counts"] for s in stats if "counts" in s]
         if counts:
             k = int(self.sizes["num_experts_per_tok"])
             out += expert_pass_counts(counts,
-                                      k * real * np.shape(counts[0])[0])
+                                      k * real * np.shape(counts[0])[0],
+                                      row_tile_of(experts))
         picked = [float(s["selected_keys"]) for s in stats
                   if "selected_keys" in s]
         if picked:

@@ -62,7 +62,8 @@ from routest_tpu.models.lm_common import (dot32, expert_pass_counts,
                                           map_rows, next_arc_head, rms_norm,
                                           rope, settled)
 from routest_tpu.parallel import gqa
-from routest_tpu.parallel.expert import ExpertShare, gated_mlp, moe_share
+from routest_tpu.parallel.expert import (ExpertShare, expert_path, gated_mlp,
+                                         moe_share, row_tile_of)
 
 Params = Dict
 
@@ -185,6 +186,15 @@ class RouteLMKExaone:
         the ``n_keys`` / ``first_key`` taps."""
         return self.layer_kinds() + [(FULL, SPARSE)] * self.mtp_held
 
+    def expert_blocks(self) -> Tuple[str, int]:
+        """(the form of the held experts' grouped product at this
+        model's widths, the expert blocks held, the module's among
+        them)."""
+        n_moe = sum(1 for _, f in self.block_kinds() if f == SPARSE)
+        return expert_path(int(self.sizes["hidden_size"]),
+                           int(self.sizes["moe_intermediate_size"]),
+                           self.policy.compute_dtype), n_moe
+
     def uses_rope(self, kind: str) -> bool:
         return kind == SLIDING
 
@@ -217,8 +227,12 @@ class RouteLMKExaone:
         return out
 
     def step_attrs(self, length: int) -> Dict[str, str]:
-        return {"mixers": "full=xla,window=xla",
-                "mtp": str(int(self.mtp_held))}
+        attrs = {"mixers": "full=xla,window=xla",
+                 "mtp": str(int(self.mtp_held))}
+        experts, n_moe = self.expert_blocks()
+        if n_moe:
+            attrs["experts"] = experts
+        return attrs
 
     def step_stats(self, out: Dict, lengths) -> Dict:
         """Device values of one step for the pass's counters: the keys
@@ -247,6 +261,7 @@ class RouteLMKExaone:
         is_window = np.asarray([a == SLIDING for a, _ in kinds])
         seen = sum(np.asarray(s["keys_seen"], np.float64) for s in stats)
         visited = {"window": 0.0, "full": 0.0}
+        experts, n_moe = self.expert_blocks()
         for step in steps:
             n = len(step.routes)
             visited["window"] += n * is_window.sum() * gqa.window_visited(
@@ -259,6 +274,9 @@ class RouteLMKExaone:
                      float(seen[rows].sum())),
                     ("gqa_keys", {"layer": layer, "kind": "visited"},
                      visited[layer])]
+        if n_moe:
+            out.append(("expert_blocks", {"path": experts},
+                        float(n_moe * len(steps))))
         mtp_tokens = sum(int(s["mtp_tokens"]) for s in stats)
         if self.mtp_held:
             out.append(("mtp_positions", {}, float(
@@ -268,7 +286,8 @@ class RouteLMKExaone:
             n_trunk = sum(1 for _, f in self.layer_kinds() if f == SPARSE)
             out += expert_pass_counts(
                 counts, int(self.sizes["num_experts_per_tok"])
-                * (real * n_trunk + mtp_tokens * self.mtp_held))
+                * (real * n_trunk + mtp_tokens * self.mtp_held),
+                row_tile_of(experts))
         return out
 
     # ── parameters ──────────────────────────────────────────────────

@@ -14,15 +14,28 @@ chip the layer runs without its exchange, and the parts that all shares
 give, with the shared expert counted once, add up to the whole layer
 (``tests/test_route_lm_share.py``).
 
-The held experts' product is a grouped one with uneven groups: the
-(token, slot) assignments that land here are sorted by expert, each
-expert's group is cut into tiles of ``tile`` rows, and one loop runs
-over the tiles that exist (a dynamic trip count: no capacity, so no
-bound on a group but the number of tokens): gather the tile's tokens,
-the gated MLP with that expert's weights, scatter-add the weighted rows
-into a float32 sum. A tile holds one expert's tokens only, so a short
-group costs one mostly empty tile; ``tile`` follows the tokens an
-expert can expect.
+The held experts' product is a grouped one with uneven groups, one
+dataflow in three steps (:func:`grouped_experts`). **Sort**: the (token,
+slot) assignments that land here are sorted by expert once a layer, and
+each expert's rows are laid out from a multiple of the row tile on, so
+that a tile of rows belongs to one expert (an expert wastes at most one
+part-tile: :func:`rows_visited`). **Grouped product**: the layout is
+walked in chunks of a fixed number of rows (a dynamic trip count: no
+capacity, so no bound on the held rows but the number of assignments,
+and memory of one chunk): a chunk's rows are gathered once, multiplied
+by ``w_gate`` and ``w_up`` of the experts that own them with
+``silu(gate) * up`` cast to the rows' dtype, then by ``w_down`` with the
+assignment's float32 weight on the finished float32 row. Two forms of
+the product, chosen by :func:`expert_path` from widths, dtype and
+backend alone: ``fused``, two Pallas kernels over row tiles x column
+tiles that read each expert's matrices from the ``(count, D, M)`` stacks
+by block index (``grouped_expert_product_up`` / ``_down`` in a trace),
+and ``xla``, ``jax.lax.ragged_dot`` over the same layout (the CPU,
+float32, toy widths; the kernels' oracle). **Combine**: the chunk's rows
+are scatter-added into the float32 sum a few hundred at a time, the
+sum and the rows held lane by lane (:func:`lane_rows`) until the layer
+hands the sum on; a token's held terms are added in expert order
+whatever else the step holds.
 
 **The exchange** (:func:`make_moe_apply`, with :func:`moe_apply_dense`
 as its oracle): Switch-style top-1 routing with a capacity over an
@@ -38,10 +51,13 @@ M3).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from routest_tpu.core.smap import shard_map
@@ -197,17 +213,189 @@ def gated_mlp(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
     return jnp.matmul(hidden, w_down, preferred_element_type=jnp.float32)
 
 
-def expert_tile(tokens: int, top_k: int, n_experts: int) -> int:
-    """Rows of a tile: the tokens an expert can expect of ``tokens``,
-    as a power of two between 128 (below it the MXU idles) and 512."""
-    expect = max(1, tokens * top_k // n_experts)
-    return min(512, max(128, 1 << (expect - 1).bit_length()))
+# ── the held experts' grouped product ─────────────────────────────────
+
+ROW_TILE = 128              # rows of a tile of the kernels: one expert's
+CHUNK_ROWS = 8192           # rows of the layout multiplied at a time
+COMBINE_ROWS = 512          # most rows of one scatter-add of the combine
+_LANES = 128                # (readings of the three: PERF.md §6, PR 38)
+_SUBLANES = 8
+_BLOCK_BYTES = 16 * 2 ** 20  # an expert's whole-depth blocks of one step
+_VMEM_BYTES = 96 * 2 ** 20
+
+
+def _col_tile(n: int, depth: int, matrices: int, itemsize: int = 2,
+              by_lanes: bool = False) -> int:
+    """Output columns of one grid step: the most whole lanes that divide
+    ``n`` whose ``matrices`` blocks of the whole ``depth`` fit
+    ``_BLOCK_BYTES`` (twice that is in VMEM: the next expert's blocks
+    arrive while this one's are multiplied); 0 where none does.
+    ``by_lanes``: the output is written as rows of (n / 128, 128), so a
+    tile is whole groups of 8 lanes' worth, or all of ``n``."""
+    step = _LANES * _SUBLANES if by_lanes else _LANES
+    fits = [c for c in list(range(step, n, step)) + [n] if n % c == 0
+            and matrices * depth * c * itemsize <= _BLOCK_BYTES]
+    return max(fits, default=0)
+
+
+def lane_rows(d: int):
+    """(groups, lanes) of a row of ``d`` numbers laid out lane by lane:
+    as (T, groups, lanes) a token's float32 row lies in one piece of
+    the device's memory (as (T, d) eight tokens' rows are interleaved
+    128 numbers at a time), which is what makes a row of the combine's
+    scatter-add cost a quarter (PERF.md §6, PR 38)."""
+    lanes = math.gcd(d, _LANES)
+    return d // lanes, lanes
+
+
+def expert_path(d: int, m: int, dtype, backend: str = "") -> str:
+    """The grouped product :func:`grouped_experts` runs for experts of
+    ``d`` x ``m``: ``"fused"`` (the Pallas kernels) on a TPU where
+    bfloat16 rows tile and a whole-depth block of an expert fits,
+    ``"xla"`` (``ragged_dot``) everywhere else. ``backend`` defaults to
+    JAX's own."""
+    tiles = (d % _LANES == 0 and m % _LANES == 0
+             and _col_tile(m, d, 2) > 0
+             and _col_tile(d, m, 1, by_lanes=True) > 0)
+    on_tpu = (backend or jax.default_backend()) == "tpu"
+    return ("fused" if on_tpu and tiles and jnp.dtype(dtype) == jnp.bfloat16
+            else "xla")
+
+
+def row_tile_of(path: str) -> int:
+    """Rows an expert's part of the layout is rounded up to: the
+    kernels' tile, or single rows for ``ragged_dot``."""
+    return ROW_TILE if path == "fused" else 1
+
+
+def rows_visited(counts, row_tile: int):
+    """Rows of the layout each expert owns, the padding of its last tile
+    included: what the grouped product multiplies for ``counts`` held
+    rows (numpy or JAX integers)."""
+    return -(-counts // row_tile) * row_tile
+
+
+def _n_live(tiles):
+    """The chunk's live tiles: the last entry of ``tiles``."""
+    return tiles[tiles.shape[0] - 1]
+
+
+def _live_tile(j, tiles):
+    """No tile beyond the chunk's last live one: a grid step past it
+    fetches nothing new and computes nothing."""
+    return jnp.minimum(j, _n_live(tiles) - 1)
+
+
+def _up_kernel(tiles_ref, x_ref, wg_ref, wu_ref, h_ref):
+    @pl.when(pl.program_id(1) < _n_live(tiles_ref))
+    def _():
+        x = x_ref[...]
+        gate = jnp.dot(x, wg_ref[...], preferred_element_type=jnp.float32)
+        up = jnp.dot(x, wu_ref[...], preferred_element_type=jnp.float32)
+        h_ref[...] = (jax.nn.silu(gate) * up).astype(h_ref.dtype)
+
+
+def _down_kernel(tiles_ref, h_ref, wd_ref, w_ref, o_ref):
+    @pl.when(pl.program_id(1) < _n_live(tiles_ref))
+    def _():
+        o_ref[...] = (jnp.dot(
+            h_ref[...], wd_ref[...], preferred_element_type=jnp.float32)
+            * w_ref[...]).reshape(o_ref.shape)        # rows by lanes
+
+
+def _grouped_call(kernel, name: str, rows, stacks, extra, tiles, out_dtype,
+                  *, row_tile: int, col_tile: int, by_lanes: bool,
+                  interpret: bool):
+    """``rows`` (C, depth) against the ``stacks`` (count, depth, n), a
+    tile of ``row_tile`` rows by the matrices of the expert ``tiles``
+    names for it: a grid over column tiles (outer) and row tiles, so
+    that an expert's whole-depth blocks, read from the stacks by block
+    index, stay in VMEM while its tiles of rows pass. ``extra``: (C, 1)
+    arrays that go with the rows. → (C, n), or ``by_lanes`` (C, n / 128,
+    128): :func:`lane_rows`."""
+    c, depth = rows.shape
+    n = stacks[0].shape[-1]
+    if c % row_tile or n % col_tile:
+        raise ValueError(f"{c} rows in tiles of {row_tile}, {n} columns in "
+                         f"tiles of {col_tile}: not whole tiles")
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(n // col_tile, c // row_tile),
+        in_specs=(
+            [pl.BlockSpec((row_tile, depth),
+                          lambda i, j, t: (_live_tile(j, t), 0))]
+            + [pl.BlockSpec((None, depth, col_tile),
+                            lambda i, j, t: (t[_live_tile(j, t)], 0, i))
+               for _ in stacks]
+            + [pl.BlockSpec((row_tile, 1),
+                            lambda i, j, t: (_live_tile(j, t), 0))
+               for _ in extra]),
+        out_specs=(pl.BlockSpec((row_tile, col_tile // _LANES, _LANES),
+                                lambda i, j, t: (_live_tile(j, t), i, 0))
+                   if by_lanes else
+                   pl.BlockSpec((row_tile, col_tile),
+                                lambda i, j, t: (_live_tile(j, t), i))))
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(
+            (c, n // _LANES, _LANES) if by_lanes else (c, n), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_BYTES),
+        name=name, interpret=interpret,
+    )(tiles, rows, *stacks, *extra)
+
+
+@functools.partial(jax.jit, static_argnames=("row_tile", "interpret"))
+def _product_fused(xs, w, tiles, experts: Params, *, row_tile: int,
+                   interpret: bool = False):
+    """A chunk's rows through their experts as two kernels: ``xs`` (C,
+    D), ``w`` (C,) float32, ``tiles`` (C / row_tile + 1,) int32: the
+    expert of each tile of rows, then the number of live tiles → (C, D /
+    128, 128) float32 (:func:`lane_rows`), the rows of dead tiles
+    unwritten. Jitted, so that a step program traces and lowers the two
+    kernels once and not once an expert block."""
+    d, m = experts["w_gate"].shape[1:]
+    size = jnp.dtype(xs.dtype).itemsize
+    hidden = _grouped_call(
+        _up_kernel, "grouped_expert_product_up", xs,
+        (experts["w_gate"], experts["w_up"]), (), tiles, xs.dtype,
+        row_tile=row_tile, col_tile=_col_tile(m, d, 2, size), by_lanes=False,
+        interpret=interpret)
+    return _grouped_call(
+        _down_kernel, "grouped_expert_product_down", hidden,
+        (experts["w_down"],), (w[:, None],), tiles, jnp.float32,
+        row_tile=row_tile, col_tile=_col_tile(d, m, 1, size, by_lanes=True),
+        by_lanes=True, interpret=interpret)
+
+
+def _product_xla(xs, w, sizes, experts: Params):
+    """The same chunk through ``ragged_dot``: ``sizes`` (count,) the
+    rows of the chunk each expert owns, in the layout's order; the rows
+    by lanes as the kernels give them."""
+    def dot(rows, stack):
+        return jax.lax.ragged_dot(rows, stack, sizes,
+                                  preferred_element_type=jnp.float32)
+
+    hidden = (jax.nn.silu(dot(xs, experts["w_gate"]))
+              * dot(xs, experts["w_up"])).astype(xs.dtype)
+    out = dot(hidden, experts["w_down"]) * w[:, None]
+    return out.reshape((out.shape[0],) + lane_rows(out.shape[1]))
+
+
+def combine_rows(chunk: int, t: int) -> int:
+    """Rows of one scatter-add of the combine into a sum of ``t`` rows:
+    the most that divide the chunk, up to ``COMBINE_ROWS`` and up to an
+    eighth of the sum's rows: of more than an eighth of a (T, D) sum's
+    rows XLA sorts the indices and permutes the rows first (PERF.md §6,
+    PR 38; no sum laid out lane by lane was seen to go that way)."""
+    most = max(1, min(COMBINE_ROWS, t // 8))
+    return max(p for p in range(1, most + 1) if chunk % p == 0)
 
 
 def grouped_experts(x: jax.Array, chosen: jax.Array, weights: jax.Array,
                     experts: Params, share: ExpertShare,
-                    valid: Optional[jax.Array] = None,
-                    tile: Optional[int] = None):
+                    valid: Optional[jax.Array] = None):
     """The held experts' terms: ``sum over the chosen e held here of
     weights_e * E_e(x)`` for every token, as float32 (T, D), and the
     number of tokens each held expert got, (count,) int32. ``experts``
@@ -216,33 +404,62 @@ def grouped_experts(x: jax.Array, chosen: jax.Array, weights: jax.Array,
     t, d = x.shape
     k = chosen.shape[1]
     n_held = share.count
-    tile = tile or expert_tile(t, k, share.n_experts)
+    path = expert_path(d, experts["w_gate"].shape[-1], x.dtype)
+    tile = row_tile_of(path)
     local = chosen - share.first
     held = (local >= 0) & (local < n_held)
     if valid is not None:
         held = held & valid[:, None]
     local = jnp.where(held, local, n_held).reshape(-1)     # (T·k,)
     order = jnp.argsort(local, stable=True).astype(jnp.int32)
-    counts = jnp.zeros((n_held + 1,), jnp.int32).at[local].add(1)[:n_held]
-    tiles = (counts + tile - 1) // tile
-    tile_end = jnp.cumsum(tiles)
+    counts = jnp.sum(local[:, None] == jnp.arange(n_held)[None, :], 0,
+                     dtype=jnp.int32)       # (a scatter-add takes 1 ms)
+    # the layout: expert e owns the rows end[e] - owned[e] .. end[e] - 1,
+    # the first counts[e] of them assignments, from row0[e] of ``order``
+    owned = rows_visited(counts, tile)
+    end = jnp.cumsum(owned)
     row0 = jnp.cumsum(counts) - counts
-    offs = jnp.arange(tile, dtype=jnp.int32)
+    most = -(-(t * k + n_held * (tile - 1)) // tile) * tile
+    chunk = min(-(-CHUNK_ROWS // tile) * tile, most)
+    piece = combine_rows(chunk, t)
+    at = jnp.arange(chunk, dtype=jnp.int32)
+    flat_w = weights.reshape(-1)
+    stacks = {name: experts[name] for name in ("w_gate", "w_up", "w_down")}
 
-    def one_tile(j, y):
-        e = jnp.searchsorted(tile_end, j, side="right").astype(jnp.int32)
-        within = (j - (tile_end[e] - tiles[e])) * tile + offs
-        live = within < counts[e]
-        flat = order[jnp.clip(row0[e] + within, 0, t * k - 1)]
-        token, slot = flat // k, flat % k
-        w = jnp.where(live, weights[token, slot], 0.0)
-        out = gated_mlp(x[token], experts["w_gate"][e], experts["w_up"][e],
-                        experts["w_down"][e])
-        return y.at[token].add(out * w[:, None])
+    def one_chunk(i, y):
+        lo = i * chunk
+        n_rows = jnp.clip(end[-1] - lo, 0, chunk)       # whole tiles
+        e = jnp.searchsorted(end, lo + at, side="right").astype(jnp.int32)
+        own = jnp.minimum(e, n_held - 1)
+        within = lo + at - (end[own] - owned[own])
+        live = (e < n_held) & (within < counts[own])
+        flat = order[jnp.clip(row0[own] + within, 0, t * k - 1)]
+        token = flat // k
+        w = jnp.where(live, flat_w[flat], 0.0)
+        if path == "fused":
+            tiles = jnp.concatenate([own[::tile], n_rows[None] // tile])
+            out = _product_fused(x[token], w, tiles, stacks, row_tile=tile)
+        else:
+            sizes = (jnp.clip(end - lo, 0, chunk)
+                     - jnp.clip(end - owned - lo, 0, chunk))
+            out = _product_xla(x[token], w, sizes, stacks)
+        # a token's held terms arrive in expert order; rows that are no
+        # assignment (a tile's padding, a dead tile) are dropped. Rows
+        # by lanes, and a few hundred a scatter-add: XLA sorts the
+        # indices of a larger one and permutes its rows first
+        to = jnp.where(live, token, t)
 
-    y = jax.lax.fori_loop(0, tile_end[-1], one_tile,
-                          jnp.zeros((t, d), jnp.float32))
-    return y, counts
+        def add_piece(j, y):
+            return y.at[jax.lax.dynamic_slice_in_dim(to, j * piece, piece)
+                        ].add(jax.lax.dynamic_slice_in_dim(
+                            out, j * piece, piece), mode="drop")
+
+        return jax.lax.fori_loop(0, (n_rows + piece - 1) // piece,
+                                 add_piece, y)
+
+    y = jax.lax.fori_loop(0, (end[-1] + chunk - 1) // chunk, one_chunk,
+                          jnp.zeros((t,) + lane_rows(d), jnp.float32))
+    return y.reshape(t, d), counts
 
 
 def moe_share(params: Params, x: jax.Array, top_k: int, share: ExpertShare,

@@ -53,7 +53,8 @@ Spans: ``seq.score_pass`` (root) with one ``seq.step`` child per step
 ``real_tokens``, ``padded_tokens``, ``mixers`` and, for ``RouteLM``,
 ``attention`` and ``window``: the online-softmax step its full layers
 run and the window step its sliding layers run, each ``fused`` or
-``xla``; ``compile_ms`` where the dispatch compiled or fetched its
+``xla``; for a model with expert layers ``experts``: the form of the
+held experts' grouped product, ``fused`` or ``xla``; ``compile_ms`` where the dispatch compiled or fetched its
 program: ``core/cache.compile_seconds`` grew while the span was open)
 and ``seq.wait`` (the sync). Where the pass is recorded, ``seq.wait``
 holds one ``seq.wait.step`` a timed step, in dispatch order, with the
@@ -75,20 +76,25 @@ none of this. As every recorded span they are
 padded}`` from the plan, for every model. ``RouteLM``:
 ``rtpu_seq_attention_chunks_total{path=fused|xla}`` (the full layers'
 steps over chunks of keys) and ``rtpu_seq_window_blocks_total{path=
-fused|xla}`` (the sliding layers' blocks of queries) from the plan; and,
+fused|xla}`` (the sliding layers' blocks of queries) from the plan, as
+``rtpu_seq_expert_blocks_total{path=fused|xla}`` (expert blocks x
+steps, by ``parallel/expert.expert_path`` at the model's widths); and,
 read from the device
 once a pass after its sync, ``rtpu_seq_expert_tokens{stat=max|mean}``
 (tokens per held expert per step and layer), ``rtpu_seq_expert_load_
 max_over_mean``, ``rtpu_seq_held_assignment_share`` (the share of a
-step's k·T assignments that land on held experts) and
+step's k·T assignments that land on held experts),
+``rtpu_seq_expert_rows_total{kind=held|visited}`` (assignments on held
+experts, and the rows of the tiles their layout gives them: the rule
+``expert.rows_visited`` over those counts, on the host: no kernel's) and
 ``rtpu_seq_selected_keys_per_query``. ``RouteLMSala``:
 ``rtpu_seq_sparse_keys_total{kind=chosen|visited}`` (keys in chosen
 blocks at or before the query, from the device; keys the second stage
 multiplied, from the plan), ``rtpu_seq_sparse_blocks_per_query`` (the
 first over the real (query, group) pairs and the block's keys) and
 ``rtpu_seq_linear_chunks_total`` (steps of the linear mixers' scans).
-``RouteLMKExaone``: the expert gauges as ``RouteLM`` (the module's
-block among the expert layers), ``rtpu_seq_gqa_keys_total{layer=window|
+``RouteLMKExaone``: the expert gauges and counters as ``RouteLM`` (the
+module's block among the expert layers), ``rtpu_seq_gqa_keys_total{layer=window|
 full, kind=needed|visited}`` (needed: the keys each real query saw,
 from the device; visited: the keys of the blocks and chunks the
 dispatched programs multiplied, padding and beyond-the-diagonal parts
@@ -128,6 +134,17 @@ def _seq_metrics():
                 "Blocks of queries that the sliding layers of the "
                 "dispatched steps ran, by the form of the window step "
                 "(fused: the Pallas kernel; xla).", ("path",)),
+            "expert_blocks": reg.counter(
+                "rtpu_seq_expert_blocks_total",
+                "Expert blocks that the dispatched steps ran, by the "
+                "form of the held experts' grouped product (fused: the "
+                "Pallas kernels; xla: ragged_dot).", ("path",)),
+            "expert_rows": reg.counter(
+                "rtpu_seq_expert_rows_total",
+                "Rows of the held experts' grouped product: assignments "
+                "on held experts (held), and rows of the tiles its layout "
+                "gives them by rule, an expert's last tile's padding "
+                "included (visited).", ("kind",)),
             "expert_tokens": reg.gauge(
                 "rtpu_seq_expert_tokens",
                 "Tokens a held expert got in one step of one expert "
@@ -170,8 +187,9 @@ def _seq_metrics():
     return _metrics
 
 
-_COUNTERS = ("tokens", "chunks", "window_blocks", "sparse_keys",
-             "linear_chunks", "gqa_keys", "mtp_positions")
+_COUNTERS = ("tokens", "chunks", "window_blocks", "expert_blocks",
+             "expert_rows", "sparse_keys", "linear_chunks", "gqa_keys",
+             "mtp_positions")
 
 
 class Step(NamedTuple):

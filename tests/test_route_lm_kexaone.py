@@ -164,7 +164,8 @@ def test_the_held_layers_are_the_published_pattern():
         ("sliding_attention", "sparse")]
     assert m.block_kinds()[-1] == ("full_attention", "sparse")
     assert m.length_quantum == 8 and m.share == (16, 0, 8)
-    assert m.step_attrs(96) == {"mixers": "full=xla,window=xla", "mtp": "1"}
+    assert m.step_attrs(96) == {"mixers": "full=xla,window=xla", "mtp": "1",
+                                "experts": "xla"}
     bare = model(share={"chips_per_layer": 2, "experts_first": 0,
                         "mtp_held": False})
     assert len(bare.block_kinds()) == 5

@@ -1,7 +1,7 @@
 """The pieces under the route-sequence language model, each against the
 plain reference or a dense oracle: the share of the expert layer (the
 parts all shares give, the shared expert counted once, add up to the
-uncut layer), the grouped product's tiles, the selector's exact top-k
+uncut layer), the grouped product's layout, the selector's exact top-k
 with ties, the window, and the scorer's length ladder."""
 
 import jax
@@ -79,10 +79,15 @@ def test_routing_is_over_all_experts_and_the_bias_moves_only_the_choice():
                                atol=1e-6)
 
 
-@pytest.mark.parametrize("tile,tokens", [(4, 50), (16, 50), (64, 7)])
-def test_grouped_product_with_uneven_and_empty_groups(tile, tokens):
-    """Tiles smaller and larger than the groups, an expert nobody
-    chose, padded tokens left out."""
+@pytest.mark.parametrize("tile,chunk,tokens", [
+    (4, 32, 50), (16, 64, 50), (64, 64, 7), (1, 16, 50)])
+def test_grouped_product_with_uneven_and_empty_groups(tile, chunk, tokens,
+                                                      monkeypatch):
+    """Tiles smaller and larger than the groups, chunks that cut an
+    expert's rows in two, an expert nobody chose, padded tokens left
+    out."""
+    monkeypatch.setattr(expert, "row_tile_of", lambda path: tile)
+    monkeypatch.setattr(expert, "CHUNK_ROWS", chunk)
     p = _layer(3)
     x = jax.random.normal(jax.random.PRNGKey(4), (tokens, D))
     chosen, w = expert.route_top_k(x, p["router"], p["bias"], K)
@@ -90,7 +95,7 @@ def test_grouped_product_with_uneven_and_empty_groups(tile, tokens):
     valid = jnp.arange(tokens) < tokens - 3
     share = expert.ExpertShare(E, 2, 9)              # experts 2..10
     y, counts = highest(jax.jit(lambda *a: expert.grouped_experts(
-        *a, share, valid, tile)))(x, chosen, w, _cut(p, 2, 9))
+        *a, share, valid)))(x, chosen, w, _cut(p, 2, 9))
     want = np.zeros((tokens, D), np.float32)
     n = np.zeros(9, np.int64)
     for t in range(tokens - 3):
@@ -104,12 +109,6 @@ def test_grouped_product_with_uneven_and_empty_groups(tile, tokens):
     np.testing.assert_allclose(y, want, atol=2e-5)
     np.testing.assert_array_equal(counts, n)
     assert counts[3] == 0
-
-
-def test_expert_tile_follows_the_tokens_an_expert_can_expect():
-    assert expert.expert_tile(32768, 8, 256) == 512
-    assert expert.expert_tile(8192, 8, 256) == 256
-    assert expert.expert_tile(1024, 8, 256) == 128
 
 
 # ── the selector ─────────────────────────────────────────────────────

@@ -146,7 +146,8 @@ def test_route_lms_plan_and_tables_are_what_they_were(scorers):
         "chosen": (4, 4, 96, 4), "selected": (2, 4, 3, 96)}
     assert tables["selected"].dtype == jnp.bool_
     assert m.step_attrs(96) == {"attention": "xla", "window": "xla",
-                                "mixers": "full=xla,sliding=xla"}
+                                "mixers": "full=xla,sliding=xla",
+                                "experts": "xla"}
 
 
 def test_the_real_models_quanta():
@@ -241,6 +242,12 @@ def test_route_lm_counters_are_what_they_were(registry, scorers):
         == [("xla", "xla")] * len(plan)
     assert _family(registry, "rtpu_seq_expert_load_max_over_mean")[()] >= 1.0
     assert 0 < _family(registry, "rtpu_seq_held_assignment_share")[()] <= 1.0
+    # four expert layers a step; ragged_dot multiplies the held rows alone
+    assert _family(registry, "rtpu_seq_expert_blocks_total") == {
+        ("xla",): 4 * len(plan)}
+    rows = _family(registry, "rtpu_seq_expert_rows_total")
+    assert rows[("visited",)] == rows[("held",)] > 0
+    assert all(s["attrs"]["experts"] == "xla" for s in steps)
     assert _family(registry, "rtpu_seq_selected_keys_per_query")[()] > 1.0
     assert _family(registry, "rtpu_seq_sparse_keys_total") == {}
 
@@ -276,13 +283,19 @@ def test_kexaone_counters_and_span_attributes(registry, scorers):
     # 8 of 16 experts held: about half of the assignments land here
     assert 0.3 < _family(registry,
                          "rtpu_seq_held_assignment_share")[()] < 0.7
+    # four trunk expert blocks and the module's, three steps
+    assert _family(registry, "rtpu_seq_expert_blocks_total") == {
+        ("xla",): 5 * 3}
+    rows = _family(registry, "rtpu_seq_expert_rows_total")
+    assert rows[("visited",)] == rows[("held",)] > 0
     assert _family(registry, "rtpu_seq_attention_chunks_total") == {}
     assert _family(registry, "rtpu_seq_window_blocks_total") == {}
     assert _family(registry, "rtpu_seq_sparse_keys_total") == {}
     steps = [s for s in get_tracer().buffer.snapshot()
              if s["name"] == "seq.step"][-3:]
     assert all(s["attrs"]["mixers"] == "full=xla,window=xla"
-               and s["attrs"]["mtp"] == "1" for s in steps)
+               and s["attrs"]["mtp"] == "1"
+               and s["attrs"]["experts"] == "xla" for s in steps)
 
 
 def test_the_other_two_models_emit_no_gqa_or_module_counters(registry,

@@ -204,3 +204,70 @@ def test_windowed_attention_step_compiles_for_v5e(v5e, routes, length):
     # operation named ``selected_attention_step…``
     assert "windowed_attention_step" in text
     assert "selected_attention_step" not in text
+
+
+# The longest and the shortest step of the two sparse-expert
+# route-sequence models at the cells' own configurations (one route of
+# 26,624 tokens; three of 1,536 and four of 1,280), and a step shorter
+# than any the cells hold (one route of 1,280: under 4,096 tokens a
+# piece of the combine is less than COMBINE_ROWS), every path function
+# answering as it does on the chip: the held experts' grouped
+# product is the two kernels, an expert's matrices are read by block
+# index (the loop this replaced sliced w_gate[e], w_up[e], w_down[e] out
+# of the stacks a tile at a time), and the combine's scatter-adds stay
+# the plain ones (a larger one sorts its indices and permutes its rows
+# first: PERF.md §6, PR 38).
+@pytest.mark.parametrize("config,module,cls,blocks,routes,length", [
+    ("dots3-note-prev-ep8", "route_lm", "RouteLM", 4, 1, 26624),
+    ("k-exaone-236b-ep8", "route_lm_kexaone", "RouteLMKExaone", 5, 1, 26624),
+    ("dots3-note-prev-ep8", "route_lm", "RouteLM", 4, 3, 1536),
+    ("k-exaone-236b-ep8", "route_lm_kexaone", "RouteLMKExaone", 5, 4, 1280),
+    ("k-exaone-236b-ep8", "route_lm_kexaone", "RouteLMKExaone", 5, 1, 1280)])
+def test_a_step_holds_the_grouped_expert_kernels(v5e, monkeypatch, config,
+                                                 module, cls, blocks, routes,
+                                                 length):
+    import importlib
+    import json
+    import re
+
+    from routest_tpu.parallel import expert, select
+
+    def on_the_chip(fn):
+        return lambda *a, **kw: fn(*a, **{**kw, "backend": "tpu"})
+
+    monkeypatch.setattr(expert, "expert_path",
+                        on_the_chip(expert.expert_path))
+    monkeypatch.setattr(select, "attention_path",
+                        on_the_chip(select.attention_path))
+    monkeypatch.setattr(select, "window_path",
+                        on_the_chip(select.window_path))
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmark", "configs",
+                           config + ".json")) as f:
+        cfg = json.load(f)
+    model = getattr(importlib.import_module("routest_tpu.models." + module),
+                    cls).from_config(cfg)
+    d, m = cfg["hidden_size"], cfg["moe_intermediate_size"]
+    assert expert.expert_path(d, m, jnp.bfloat16) == "fused"
+
+    def on(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    text = jax.jit(model.apply).lower(
+        _on(v5e, jax.eval_shape(model.init, jax.random.PRNGKey(0))),
+        on((routes, length)), on((routes,)),
+        on((routes, 3))).compile().as_text()
+    for kernel in ("grouped_expert_product_up", "grouped_expert_product_down"):
+        calls = re.findall(rf"%{kernel}[.\d]* = \S+ custom-call\(.*"
+                           r"tpu_custom_call", text)
+        assert len(calls) == blocks, (kernel, len(calls))
+    inside = [line for line in text.split("\n") if ".moe.experts/" in line]
+    whole = re.compile(rf"\[(1,)?({d},{m}|{m},{d})\]")
+    sliced = [line for line in inside if "dynamic-slice(" in line
+              and whole.search(line.split(" dynamic-slice(")[0])]
+    assert not sliced, sliced[0][:300]
+    # one sort an expert block, the assignments' argsort: no scatter-add
+    # of the combine went the sorted way
+    assert sum(" sort(" in line for line in inside) == blocks
+    assert not [line for line in inside if " scatter(" in line
+                and "indices_are_sorted=true" in line]
