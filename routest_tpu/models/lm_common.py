@@ -1,16 +1,18 @@
 """What the route-sequence language models share (``route_lm.RouteLM``,
-``route_lm_sala.RouteLMSala`` and ``route_lm_kexaone.RouteLMKExaone``):
-the norm, the rotary embedding, the float32-accumulating product, the
-chunked next-arc head, the settled stream, the row-wise map and the
-expert layers' pass counts."""
+``route_lm_sala.RouteLMSala``, ``route_lm_kexaone.RouteLMKExaone`` and
+``route_lm_gigachat.RouteLMGigaChat``): the norm, the rotary embedding
+and YaRN's frequency table for it, the float32-accumulating product,
+the chunked next-arc head, the prediction module's column, the settled
+stream, the row-wise map and the expert layers' pass counts."""
 
 from __future__ import annotations
 
 import math
-from typing import List, Sequence, Tuple
+from typing import Callable, Dict, List, Mapping, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def rms_norm(x, w, eps: float):
@@ -19,18 +21,50 @@ def rms_norm(x, w, eps: float):
     return (y * w.astype(jnp.float32)).astype(x.dtype)
 
 
-def rope(x, pos, theta: float):
+def rope(x, pos, theta: float, inv_freq=None):
     """Rotate-half RoPE over the last axis; ``pos`` has the shape of
-    ``x`` less its last axis, or broadcasts to it from the left."""
+    ``x`` less its last axis, or broadcasts to it from the left. The
+    pair i turns by ``pos * theta ** (-i / half)``, or by ``pos *
+    inv_freq[i]`` where a table is given (:func:`yarn_inv_freq`)."""
     half = x.shape[-1] // 2
-    freq = jnp.float32(theta) ** (-jnp.arange(half, dtype=jnp.float32)
-                                  / half)
+    if inv_freq is None:
+        freq = jnp.float32(theta) ** (-jnp.arange(half, dtype=jnp.float32)
+                                      / half)
+    else:
+        freq = jnp.asarray(inv_freq, jnp.float32)
     ang = pos.astype(jnp.float32)[..., None] * freq
     ang = ang.reshape(pos.shape + (1,) * (x.ndim - 1 - pos.ndim) + (half,))
     xf = x.astype(jnp.float32)
     a, b = xf[..., :half], xf[..., half:]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     return jnp.concatenate([a * cos - b * sin, a * sin + b * cos], -1)
+
+
+def yarn_inv_freq(dim: int, theta: float, scaling: Mapping) -> np.ndarray:
+    """YaRN's frequencies of the ``dim / 2`` rotary pairs, float64:
+    pair i keeps ``theta ** (-2 i / dim)`` where it turns more than
+    ``beta_fast`` times over the ``original_max_position_embeddings``,
+    takes ``1 / factor`` of it where it turns fewer than ``beta_slow``
+    times, and a linear blend between the two pairs that those counts
+    name (the lower rounded down, the higher up)."""
+    base, original = float(theta), scaling["original_max_position_embeddings"]
+
+    def pair_turning(turns):
+        return (dim * math.log(original / (turns * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low = max(math.floor(pair_turning(scaling["beta_fast"])), 0)
+    high = min(math.ceil(pair_turning(scaling["beta_slow"])), dim - 1)
+    plain = base ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    ramp = np.clip((np.arange(dim // 2) - low)
+                   / ((high - low) or 0.001), 0.0, 1.0)
+    return plain * (1.0 - ramp) + plain / scaling["factor"] * ramp
+
+
+def yarn_mscale(factor: float, coefficient: float) -> float:
+    """``0.1 * coefficient * ln(factor) + 1``; 1 where nothing is
+    stretched."""
+    return 1.0 if factor <= 1 else 0.1 * coefficient * math.log(factor) + 1.0
 
 
 def dot32(x, w):
@@ -72,6 +106,34 @@ def next_arc_head(params, h, ids, lengths, rows_at, eps: float,
     has_next = (jnp.arange(length)[None, :] + shift) < lengths[:, None]
     next_logit = jnp.where(has_next, next_logit.reshape(b_sz, length), 0.0)
     return next_logit, lse.reshape(b_sz, length), full_rows
+
+
+def prediction_column(params, h, ids, next_ids, lengths, rows_at,
+                      eps: float, block: Callable) -> Dict:
+    """A prediction module's likelihood column, the arc AFTER next:
+    ``u_t = W_p [RMSNorm(h_t) ; RMSNorm(E[next_ids_t])]`` from the
+    trunk's last hidden state h (B, L, d) and the trunk's embedding,
+    ``block(layer params, u, valid)`` (one residual block of the
+    model's own kind over a route's n - 1 positions), then the trunk's
+    head behind the module's own norm. ``params``: the model's, with
+    ``mtp`` = ``h_norm``, ``e_norm``, ``w_proj`` (2d, d), ``layer``,
+    ``final_norm``. → ``mtp_next_logit`` (the logit of ids[t + 2]) and
+    ``mtp_lse`` (1, B, L), ``mtp_loglik`` (1, B): a leading axis of one
+    entry a module."""
+    m, d, dt = params["mtp"], h.shape[-1], h.dtype
+    at = jnp.arange(h.shape[1])[None, :]
+    with jax.named_scope("lm.mtp.proj"):
+        e = params["embed"][next_ids].astype(dt)
+        u = (dot32(rms_norm(h, m["h_norm"], eps), m["w_proj"][:d])
+             + dot32(rms_norm(e, m["e_norm"], eps),
+                     m["w_proj"][d:])).astype(dt)
+    h2 = block(m["layer"], u, at + 1 < lengths[:, None])
+    logit2, lse2, _ = next_arc_head(
+        {"final_norm": m["final_norm"], "head": params["head"]}, h2,
+        ids, lengths, rows_at, eps, shift=2, scope="lm.mtp.head")
+    return {"mtp_next_logit": logit2[None], "mtp_lse": lse2[None],
+            "mtp_loglik": jnp.sum(jnp.where(
+                at + 2 < lengths[:, None], logit2 - lse2, 0.0), -1)[None]}
 
 
 def settled(h):

@@ -59,8 +59,9 @@ import jax.numpy as jnp
 
 from routest_tpu.core.dtypes import BF16_POLICY, Policy
 from routest_tpu.models.lm_common import (dot32, expert_pass_counts,
-                                          map_rows, next_arc_head, rms_norm,
-                                          rope, settled)
+                                          map_rows, next_arc_head,
+                                          prediction_column, rms_norm, rope,
+                                          settled)
 from routest_tpu.parallel import gqa
 from routest_tpu.parallel.expert import (ExpertShare, expert_path, gated_mlp,
                                          moe_share, row_tile_of)
@@ -450,7 +451,7 @@ class RouteLMKExaone:
         blocks, experts_held)."""
         b_sz, length = ids.shape
         s, dt = self.sizes, self.policy.compute_dtype
-        eps, d = s["rms_norm_eps"], s["hidden_size"]
+        eps = s["rms_norm_eps"]
         at = jnp.arange(length)[None, :]
         h = params["embed"][ids].astype(dt)
         taps = {"n_keys": [], "first_key": [], "chosen": [], "counts": []}
@@ -464,20 +465,9 @@ class RouteLMKExaone:
         out = {"next_logit": next_logit, "lse": lse, "loglik": loglik,
                "rows": rows}
         if self.mtp_held:
-            m = params["mtp"]
-            with jax.named_scope("lm.mtp.proj"):
-                e = params["embed"][self.mtp_input_ids(ids)].astype(dt)
-                u = (dot32(rms_norm(h, m["h_norm"], eps), m["w_proj"][:d])
-                     + dot32(rms_norm(e, m["e_norm"], eps),
-                             m["w_proj"][d:])).astype(dt)
-            h2 = self.block("lm.mtp", (FULL, SPARSE), m["layer"], u,
-                            at + 1 < lengths[:, None], taps)
-            logit2, lse2, _ = next_arc_head(
-                {"final_norm": m["final_norm"], "head": params["head"]}, h2,
-                ids, lengths, rows_at, eps, shift=2, scope="lm.mtp.head")
-            out.update({
-                "mtp_next_logit": logit2[None], "mtp_lse": lse2[None],
-                "mtp_loglik": jnp.sum(jnp.where(
-                    at + 2 < lengths[:, None], logit2 - lse2, 0.0), -1)[None]})
+            out.update(prediction_column(
+                params, h, ids, self.mtp_input_ids(ids), lengths, rows_at,
+                eps, lambda p, u, valid: self.block(
+                    "lm.mtp", (FULL, SPARSE), p, u, valid, taps)))
         out.update({k: jnp.stack(v) for k, v in taps.items() if v})
         return out

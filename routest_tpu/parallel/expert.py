@@ -6,13 +6,14 @@ mesh axis with one expert a device, the all-to-all exchange.
 :func:`moe_share`). A layer of ``n_experts`` routed experts is divided
 over chips; this chip holds the experts ``first .. first + count - 1``
 (:class:`ExpertShare`). It routes every token over ALL experts
-(sigmoid scores in float32, top-k of score + correction bias, weights
-renormalised over the chosen, no capacity and no drops), and adds, for
-each token, the terms of the chosen experts it holds and the shared
-expert. What the experts held elsewhere would add is left out: on one
-chip the layer runs without its exchange, and the parts that all shares
-give, with the shared expert counted once, add up to the whole layer
-(``tests/test_route_lm_share.py``).
+(sigmoid scores in float32, top-k of score + correction bias — where
+the router has groups, within the groups :func:`keep_groups` keeps —,
+weights renormalised over the chosen, no capacity and no drops), and
+adds, for each token, the terms of the chosen experts it holds and the
+shared expert. What the experts held elsewhere would add is left out:
+on one chip the layer runs without its exchange, and the parts that all
+shares give, with the shared expert counted once, add up to the whole
+layer (``tests/test_route_lm_share.py``).
 
 The held experts' product is a grouped one with uneven groups, one
 dataflow in three steps (:func:`grouped_experts`). **Sort**: the (token,
@@ -52,7 +53,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Dict, NamedTuple, Optional
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -189,15 +190,33 @@ class ExpertShare(NamedTuple):
     count: int
 
 
+def keep_groups(score: jax.Array, n_group: int, topk_group: int):
+    """Group-limited routing's cut: the experts in ``n_group`` groups of
+    consecutive ones, a group scored by the sum of its two largest
+    ``score``s, the ``topk_group`` best groups kept (ties to the lower
+    group) and the scores of the rest set to 0. ``score`` (T, E)."""
+    t, n = score.shape
+    grouped = score.reshape(t, n_group, n // n_group)
+    best_two = jax.lax.top_k(grouped, 2)[0].sum(-1)
+    _, kept = jax.lax.top_k(best_two, topk_group)
+    keep = jnp.any(kept[:, :, None] == jnp.arange(n_group)[None, None, :], 1)
+    return jnp.where(keep[:, :, None], grouped, 0.0).reshape(t, n)
+
+
 def route_top_k(x: jax.Array, router: jax.Array, bias: jax.Array,
-                top_k: int, scaling: float = 1.0):
+                top_k: int, scaling: float = 1.0, n_group: int = 1,
+                topk_group: int = 1):
     """Every token over all experts: ``p = sigmoid(x @ router)`` in
-    float32, chosen = top-k of ``p + bias`` (ties to the lower expert),
-    weights ``p / sum over the chosen`` times ``scaling``.
+    float32, chosen = top-k of ``p + bias`` (ties to the lower expert)
+    — with ``n_group`` above 1 of what :func:`keep_groups` leaves of it
+    —, weights ``p / sum over the chosen`` times ``scaling``.
     → (chosen (T, k) int32, weights (T, k) float32)."""
     prob = jax.nn.sigmoid(jnp.matmul(
         x, router, preferred_element_type=jnp.float32))
-    _, chosen = jax.lax.top_k(prob + bias.astype(jnp.float32), top_k)
+    score = prob + bias.astype(jnp.float32)
+    if n_group > 1:
+        score = keep_groups(score, n_group, topk_group)
+    _, chosen = jax.lax.top_k(score, top_k)
     picked = jnp.take_along_axis(prob, chosen, axis=-1)
     return chosen.astype(jnp.int32), (
         picked / picked.sum(-1, keepdims=True) * scaling)
@@ -464,15 +483,19 @@ def grouped_experts(x: jax.Array, chosen: jax.Array, weights: jax.Array,
 
 def moe_share(params: Params, x: jax.Array, top_k: int, share: ExpertShare,
               scaling: float = 1.0, valid: Optional[jax.Array] = None,
-              scope: str = "moe"):
+              scope: str = "moe", groups: Tuple[int, int] = (1, 1)):
     """This chip's part of the layer for tokens ``x`` (T, D): the held
     experts' terms plus the shared expert, float32. ``params``:
     ``router`` (D, n_experts), ``bias`` (n_experts,), the held experts'
     ``w_gate`` / ``w_up`` / ``w_down``, and ``shared`` (the shared
-    expert's three matrices). → (y, {"chosen", "counts"})."""
+    expert's three matrices); ``groups``: the router's (``n_group``,
+    ``topk_group``), named to it only where there is more than one
+    group. → (y, {"chosen", "counts"})."""
+    limited = ({} if groups[0] == 1 else
+               {"n_group": groups[0], "topk_group": groups[1]})
     with jax.named_scope(scope + ".route"):
         chosen, weights = route_top_k(x, params["router"], params["bias"],
-                                      top_k, scaling)
+                                      top_k, scaling, **limited)
     with jax.named_scope(scope + ".experts"):
         y, counts = grouped_experts(x, chosen, weights, params, share, valid)
     with jax.named_scope(scope + ".shared"):

@@ -371,14 +371,17 @@ def _attend_kernel(at_ref, q_ref, qs_ref, k_ref, ks_ref, v_ref, seen_ref,
 
 def _attend_fused(q, q_shared, k, k_shared, v, keys, b, n_tiles, *,
                   scale: float, key_tile: int, head_tile: int = HEAD_TILE,
-                  interpret: bool = False):
+                  interpret: bool = False,
+                  name: str = "selected_attention_step"):
     """The same block of queries as :func:`_attend_xla` over the first
     ``n_tiles`` tiles of ``key_tile`` keys of route ``b``, as one kernel:
     q (Q, H, D), q_shared (Q, H, Dr) as ``q_fn`` makes them; k (B, H, L,
     D), v (B, H, L, Dv) laid out by head; k_shared (B, L, Dr); keys (Q,
     L) bool → (H, Q, Dv) in ``v.dtype``. Keys and values are fetched
     tile by tile straight from the whole arrays (``b`` is a prefetched
-    scalar: no route is sliced out in HBM)."""
+    scalar: no route is sliced out in HBM). ``name``: what a device
+    trace calls the kernel (``parallel/latent.py`` runs it under a name
+    of its own)."""
     n_q, heads, d = q.shape
     d_r, d_v, length = q_shared.shape[-1], v.shape[-1], v.shape[2]
     at = jnp.stack([b, n_tiles]).astype(jnp.int32)
@@ -412,7 +415,7 @@ def _attend_fused(q, q_shared, k, k_shared, v, keys, b, n_tiles, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_BYTES),
-        name="selected_attention_step",
+        name=name,
         interpret=interpret,
     )(at, q.transpose(1, 0, 2), q_shared.transpose(1, 0, 2), k, k_shared, v,
       keys.astype(jnp.int8))

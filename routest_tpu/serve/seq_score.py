@@ -2,9 +2,11 @@
 language model: the table-scoring entry that takes and returns device
 arrays. One scorer for every such model (``models/route_lm.RouteLM``,
 the ``dots3-note-prev`` architecture, ``models/route_lm_sala
-.RouteLMSala``, the ``MiniCPM-SALA`` one, and ``models/route_lm_kexaone
-.RouteLMKExaone``, the ``K-EXAONE-236B-A23B`` one, whose prediction
-module gives a second likelihood column — the arc after next — as taps
+.RouteLMSala``, the ``MiniCPM-SALA`` one, ``models/route_lm_kexaone
+.RouteLMKExaone``, the ``K-EXAONE-236B-A23B`` one, and
+``models/route_lm_gigachat.RouteLMGigaChat``, the
+``GigaChat3.1-702B-A36B`` one; the last two have a prediction module
+that gives a second likelihood column — the arc after next — as taps
 ``mtp_next_logit``, ``mtp_lse`` and ``mtp_loglik``).
 
 A caller holds ``ids`` (R, L_max) and ``lengths`` (R,) on the device
@@ -101,6 +103,18 @@ dispatched programs multiplied, padding and beyond-the-diagonal parts
 included, from the plan) and ``rtpu_seq_mtp_positions_total`` (positions
 that got a likelihood term for the arc after next). ``seq.step`` carries
 ``mtp`` (1 where the module ran) beside ``mixers``.
+``RouteLMGigaChat``: the expert gauges and counters and
+``rtpu_seq_mtp_positions_total`` as ``RouteLMKExaone``,
+``rtpu_seq_latent_keys_total{kind=needed|visited}`` (its dense causal
+latent-attention blocks, the module's among them: ``t + 1`` keys a real
+query, from the device; the keys of the chunks the dispatched programs
+multiplied, from the plan) and ``rtpu_seq_expert_group_tokens_total{
+kind=held_group|all}`` (real tokens of the expert blocks one of whose
+chosen experts lies in the held experts' routing group, from the
+device's ``chosen`` taps; all of them). ``seq.step`` carries ``mixers``
+(``latent=fused`` or ``latent=xla``: the form of the dense softmax,
+``parallel/latent.latent_path``), ``mtp``, ``experts`` and ``groups``
+(the router's ``n_group/topk_group``, ``8/4``).
 """
 
 from __future__ import annotations
@@ -183,13 +197,25 @@ def _seq_metrics():
                 "rtpu_seq_mtp_positions_total",
                 "Positions whose arc after next a prediction module "
                 "scored."),
+            "latent_keys": reg.counter(
+                "rtpu_seq_latent_keys_total",
+                "Keys of the dense causal latent-attention blocks: seen "
+                "by real queries (needed), and multiplied by the "
+                "dispatched programs, masked or padded or not "
+                "(visited).", ("kind",)),
+            "expert_group_tokens": reg.counter(
+                "rtpu_seq_expert_group_tokens_total",
+                "Real tokens of the expert blocks under group-limited "
+                "routing: those one of whose chosen experts lies in the "
+                "held experts' routing group (held_group), and all of "
+                "them (all).", ("kind",)),
         }
     return _metrics
 
 
 _COUNTERS = ("tokens", "chunks", "window_blocks", "expert_blocks",
              "expert_rows", "sparse_keys", "linear_chunks", "gqa_keys",
-             "mtp_positions")
+             "mtp_positions", "latent_keys", "expert_group_tokens")
 
 
 class Step(NamedTuple):

@@ -417,21 +417,23 @@ def _route_lm_types():
     """Model type in an artifact's header → class. ``RouteLM`` is what
     a header without the key holds."""
     from routest_tpu.models.route_lm import RouteLM
+    from routest_tpu.models.route_lm_gigachat import RouteLMGigaChat
     from routest_tpu.models.route_lm_kexaone import RouteLMKExaone
     from routest_tpu.models.route_lm_sala import RouteLMSala
 
     return {"RouteLM": RouteLM, "RouteLMSala": RouteLMSala,
-            "RouteLMKExaone": RouteLMKExaone}
+            "RouteLMKExaone": RouteLMKExaone,
+            "RouteLMGigaChat": RouteLMGigaChat}
 
 
 def save_route_lm(path: str, model, params) -> None:
     """Route-LM serving artifact: the header carries the model's type,
     the published sizes it was built from, the share of the deployment
     these parameters are (``RouteLM``: layers, experts and vocabulary
-    rows held, chips a layer; ``RouteLMKExaone``: the same and whether
-    the prediction module is held; ``RouteLMSala``: a run of layers from
-    ``layers_first`` on) and the dtype policy; the blob is the params
-    pytree (bfloat16 leaves travel as they are)."""
+    rows held, chips a layer; ``RouteLMKExaone`` and ``RouteLMGigaChat``:
+    the same and whether the prediction module is held; ``RouteLMSala``:
+    a run of layers from ``layers_first`` on) and the dtype policy; the
+    blob is the params pytree (bfloat16 leaves travel as they are)."""
     _write_artifact(path, MAGIC, {
         "format": "routest_tpu.route_lm",
         "version": ROUTE_LM_ARTIFACT_VERSION,

@@ -1,7 +1,7 @@
 """One scorer for every route-sequence model: what ``RouteScorer`` asks
 of a model (``serve/seq_score.py``) is met by ``RouteLM``, by
-``RouteLMSala`` and by ``RouteLMKExaone``, whose prediction module's
-column comes through the same tap tables; ``RouteLM``'s and
+``RouteLMSala``, by ``RouteLMKExaone`` and by ``RouteLMGigaChat``, whose
+prediction modules' columns come through the same tap tables; ``RouteLM``'s and
 ``RouteLMSala``'s plans and result tables are what they were."""
 
 import jax
@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import _route_lm_gigachat_toy as gigachat
 import _route_lm_kexaone_toy as kexaone
 import _route_lm_sala_toy as sala
 import _route_lm_toy as dots3
@@ -16,7 +17,8 @@ from routest_tpu.serve import seq_score
 from routest_tpu.serve.seq_score import RouteScorer, plan_pass
 
 LENGTHS = [96, 33, 70]
-TOYS = {"dots3": dots3, "sala": sala, "kexaone": kexaone}
+TOYS = {"dots3": dots3, "sala": sala, "kexaone": kexaone,
+        "gigachat": gigachat}
 
 
 def _scorer(toy, **kw):
@@ -56,7 +58,8 @@ def scorers():
     return out
 
 
-@pytest.fixture(scope="module", params=["dots3", "sala", "kexaone"])
+@pytest.fixture(scope="module", params=["dots3", "sala", "kexaone",
+                                        "gigachat"])
 def scored(request, scorers):
     toy = TOYS[request.param]
     m, params, scorer = scorers[request.param]
@@ -96,7 +99,9 @@ def test_the_tables_are_the_models_tap_tables(scored):
         "dots3": {"n_keys", "first_key", "chosen", "selected"},
         "sala": {"n_keys", "n_visible", "blocks", "state"},
         "kexaone": {"n_keys", "first_key", "chosen", "mtp_next_logit",
-                    "mtp_lse", "mtp_loglik"}}[name]
+                    "mtp_lse", "mtp_loglik"},
+        "gigachat": {"n_keys", "first_key", "chosen", "mtp_next_logit",
+                     "mtp_lse", "mtp_loglik"}}[name]
 
 
 def test_the_modules_column_reaches_the_caller_over_the_table(scorers):
@@ -177,6 +182,17 @@ def test_the_real_models_quanta():
         (1, 26624), (1, 15104), (2, 11008), (2, 7168), (3, 5120), (3, 3328),
         (4, 2304), (4, 1280)]
     assert sum(s.padded_tokens for s in plan) == 11880      # 10.1%
+    from routest_tpu.models.route_lm_gigachat import RouteLMGigaChat
+
+    _, cfg, mix = R.load_cell(manifest, "route-lm-gigachat-dense")
+    m = RouteLMGigaChat.from_config(cfg)
+    assert m.length_quantum == 256
+    plan = plan_pass(mix["lengths"], m.length_quantum,
+                     mix["max_step_tokens"], mix["max_classes"])
+    assert [(len(s.routes), s.length) for s in plan] == [
+        (1, 26112), (1, 17152), (1, 13312), (1, 10752), (2, 8960), (1, 6400),
+        (2, 5120), (1, 2816)]
+    assert sum(s.padded_tokens for s in plan) == 3643       # 3.48%
 
 
 @pytest.fixture
@@ -298,6 +314,56 @@ def test_kexaone_counters_and_span_attributes(registry, scorers):
                and s["attrs"]["experts"] == "xla" for s in steps)
 
 
+def test_gigachat_counters_and_span_attributes(registry, scorers):
+    from routest_tpu.obs import get_tracer
+    from routest_tpu.parallel import gqa
+
+    m, params, scorer = scorers["gigachat"]
+    ids, lengths, rows_at = (jnp.asarray(a)
+                             for a in gigachat.routes(4, LENGTHS))
+    scores = scorer.score(ids, lengths, rows_at)
+    assert _family(registry, "rtpu_seq_tokens_total")[("real",)] == sum(
+        LENGTHS)
+    keys = _family(registry, "rtpu_seq_latent_keys_total")
+    # five trunk blocks see t + 1 keys, the module's block t + 1 over a
+    # route's n - 1 positions
+    tri = lambda n: n * (n + 1) // 2                        # noqa: E731
+    assert keys[("needed",)] == sum(5 * tri(n) + tri(n - 1) for n in LENGTHS)
+    plan = scorer.plan(np.asarray(LENGTHS))
+    assert [s.length for s in plan] == [96, 72, 40]
+    assert keys[("visited",)] == 6 * sum(
+        gqa.causal_visited(s.length, 8, 16) for s in plan)
+    assert keys[("visited",)] > keys[("needed",)]
+    assert _family(registry, "rtpu_seq_mtp_positions_total")[()] == sum(
+        n - 2 for n in LENGTHS)
+    # the tokens one of whose chosen experts lies in group 0 (experts
+    # 0-3; 0-1 are held), from the table's taps; all: four trunk expert
+    # blocks' n and the module's n - 1
+    chosen = np.asarray(scores.taps["chosen"])              # (5, R, W, k)
+    real = (np.arange(96)[None, None] < np.asarray(lengths)[None, :, None]
+            - (np.arange(5) == 4)[:, None, None])
+    hits = _family(registry, "rtpu_seq_expert_group_tokens_total")
+    assert hits[("held_group",)] == ((chosen // 4 == 0).any(-1) & real).sum()
+    assert hits[("all",)] == real.sum() == 5 * sum(LENGTHS) - len(LENGTHS)
+    assert 0.2 < hits[("held_group",)] / hits[("all",)] < 0.6
+    assert _family(registry, "rtpu_seq_expert_load_max_over_mean")[()] >= 1.0
+    # 2 of 32 experts held
+    assert 0.02 < _family(registry,
+                          "rtpu_seq_held_assignment_share")[()] < 0.12
+    assert _family(registry, "rtpu_seq_expert_blocks_total") == {
+        ("xla",): 5 * 3}
+    rows = _family(registry, "rtpu_seq_expert_rows_total")
+    assert rows[("visited",)] == rows[("held",)] > 0
+    assert _family(registry, "rtpu_seq_gqa_keys_total") == {}
+    assert _family(registry, "rtpu_seq_attention_chunks_total") == {}
+    assert _family(registry, "rtpu_seq_sparse_keys_total") == {}
+    steps = [s for s in get_tracer().buffer.snapshot()
+             if s["name"] == "seq.step"][-3:]
+    assert all(s["attrs"]["mixers"] == "latent=xla"
+               and s["attrs"]["mtp"] == "1" and s["attrs"]["groups"] == "8/4"
+               and s["attrs"]["experts"] == "xla" for s in steps)
+
+
 def test_the_other_two_models_emit_no_gqa_or_module_counters(registry,
                                                              scorers):
     for name, toy in (("dots3", dots3), ("sala", sala)):
@@ -307,6 +373,8 @@ def test_the_other_two_models_emit_no_gqa_or_module_counters(registry,
         scorer.score(ids, lengths, rows_at)
     assert _family(registry, "rtpu_seq_gqa_keys_total") == {}
     assert _family(registry, "rtpu_seq_mtp_positions_total") == {}
+    assert _family(registry, "rtpu_seq_latent_keys_total") == {}
+    assert _family(registry, "rtpu_seq_expert_group_tokens_total") == {}
 
 
 # ── a pass accounts for its own time (ISSUE 37) ─────────────────────

@@ -208,7 +208,9 @@ def test_windowed_attention_step_compiles_for_v5e(v5e, routes, length):
 
 # The longest and the shortest step of the two sparse-expert
 # route-sequence models at the cells' own configurations (one route of
-# 26,624 tokens; three of 1,536 and four of 1,280), and a step shorter
+# 26,624 tokens; three of 1,536 and four of 1,280), the longest step and
+# a shared one of the group-limited one (one route of 26,112 tokens
+# beside 9.83 GiB of parameters; two of 8,960), and a step shorter
 # than any the cells hold (one route of 1,280: under 4,096 tokens a
 # piece of the combine is less than COMBINE_ROWS), every path function
 # answering as it does on the chip: the held experts' grouped
@@ -222,7 +224,11 @@ def test_windowed_attention_step_compiles_for_v5e(v5e, routes, length):
     ("k-exaone-236b-ep8", "route_lm_kexaone", "RouteLMKExaone", 5, 1, 26624),
     ("dots3-note-prev-ep8", "route_lm", "RouteLM", 4, 3, 1536),
     ("k-exaone-236b-ep8", "route_lm_kexaone", "RouteLMKExaone", 5, 4, 1280),
-    ("k-exaone-236b-ep8", "route_lm_kexaone", "RouteLMKExaone", 5, 1, 1280)])
+    ("k-exaone-236b-ep8", "route_lm_kexaone", "RouteLMKExaone", 5, 1, 1280),
+    ("gigachat3.1-702b-ep16", "route_lm_gigachat", "RouteLMGigaChat", 5, 1,
+     26112),
+    ("gigachat3.1-702b-ep16", "route_lm_gigachat", "RouteLMGigaChat", 5, 2,
+     8960)])
 def test_a_step_holds_the_grouped_expert_kernels(v5e, monkeypatch, config,
                                                  module, cls, blocks, routes,
                                                  length):
@@ -230,7 +236,7 @@ def test_a_step_holds_the_grouped_expert_kernels(v5e, monkeypatch, config,
     import json
     import re
 
-    from routest_tpu.parallel import expert, select
+    from routest_tpu.parallel import expert, latent, select
 
     def on_the_chip(fn):
         return lambda *a, **kw: fn(*a, **{**kw, "backend": "tpu"})
@@ -241,6 +247,8 @@ def test_a_step_holds_the_grouped_expert_kernels(v5e, monkeypatch, config,
                         on_the_chip(select.attention_path))
     monkeypatch.setattr(select, "window_path",
                         on_the_chip(select.window_path))
+    monkeypatch.setattr(latent, "latent_path",
+                        on_the_chip(latent.latent_path))
     here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(here, "benchmark", "configs",
                            config + ".json")) as f:
@@ -253,14 +261,24 @@ def test_a_step_holds_the_grouped_expert_kernels(v5e, monkeypatch, config,
     def on(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
 
-    text = jax.jit(model.apply).lower(
+    compiled = jax.jit(model.apply).lower(
         _on(v5e, jax.eval_shape(model.init, jax.random.PRNGKey(0))),
-        on((routes, length)), on((routes,)),
-        on((routes, 3))).compile().as_text()
+        on((routes, length)), on((routes,)), on((routes, 3))).compile()
+    text = compiled.as_text()
+    # the step fits beside its parameters: the chip gives a program
+    # 15.75 GiB
+    memory = compiled.memory_analysis()
+    assert (memory.temp_size_in_bytes + memory.argument_size_in_bytes
+            + memory.output_size_in_bytes) < 15.75 * 2 ** 30
     for kernel in ("grouped_expert_product_up", "grouped_expert_product_down"):
         calls = re.findall(rf"%{kernel}[.\d]* = \S+ custom-call\(.*"
                            r"tpu_custom_call", text)
         assert len(calls) == blocks, (kernel, len(calls))
+    # the dense causal blocks of the model that has them run the fused
+    # step under its own name, the module's block among them
+    dense = re.findall(r"%latent_attention_step[.\d]* = \S+ custom-call\(.*"
+                       r"tpu_custom_call", text)
+    assert len(dense) == (blocks + 1 if cls == "RouteLMGigaChat" else 0)
     inside = [line for line in text.split("\n") if ".moe.experts/" in line]
     whole = re.compile(rf"\[(1,)?({d},{m}|{m},{d})\]")
     sliced = [line for line in inside if "dynamic-slice(" in line
