@@ -73,7 +73,6 @@ from routest_tpu.models.lm_common import (dot32, expert_pass_counts,
 from routest_tpu.parallel import latent
 from routest_tpu.parallel.expert import (ExpertShare, expert_path, gated_mlp,
                                          moe_share, row_tile_of)
-from routest_tpu.parallel.gqa import causal_visited
 
 Params = Dict
 
@@ -288,8 +287,9 @@ class RouteLMGigaChat:
 
     def pass_counts(self, steps, stats, real: int) -> List[Tuple]:
         """(family, labels, value) of one pass for the scorer's
-        counters and gauges: the visited keys from the plan, the rest
-        from ``stats``, fetched once after the pass's sync."""
+        counters and gauges: the visited keys and the dense softmax's
+        grid steps from the plan (``latent.causal_grid``'s tables), the
+        rest from ``stats``, fetched once after the pass's sync."""
         import numpy as np
 
         experts, n_moe = self.expert_blocks()
@@ -298,9 +298,15 @@ class RouteLMGigaChat:
                    np.asarray(s["keys_seen"], np.float64).sum()
                    for s in stats))),
                ("latent_keys", {"kind": "visited"}, float(sum(
-                   len(step.routes) * n_blocks * causal_visited(
+                   len(step.routes) * n_blocks * latent.visited(
                        step.length, self.full_block, self.key_chunk)
                    for step in steps)))]
+        heads = int(self.sizes["num_attention_heads"])
+        for kind in ("interior", "diagonal"):
+            out.append(("latent_tiles", {"kind": kind}, float(sum(
+                n_blocks * latent.grid_steps(
+                    len(step.routes), step.length, self.full_block,
+                    self.key_chunk, heads)[kind] for step in steps))))
         mtp_tokens = sum(int(s["mtp_tokens"]) for s in stats)
         if self.mtp_held:
             out.append(("mtp_positions", {}, float(
@@ -414,24 +420,24 @@ class RouteLMGigaChat:
             widen = ((0, 0), (0, latent.padded_keys(
                 length, block, self.key_chunk) - length), (0, 0))
             c_kv, k_shared = jnp.pad(c_kv, widen), jnp.pad(k_shared, widen)
+            # by head, as the kernel tiles them: the keys' length before
+            # their width, the values' after it, the queries whole
             w_ukv = p["w_ukv"].reshape(r_kv, heads, dn + dv)
-            k = jnp.einsum("blr,rhd->blhd", c_kv, w_ukv[..., :dn],
+            k = jnp.einsum("blr,rhd->bhld", c_kv, w_ukv[..., :dn],
                            preferred_element_type=jnp.float32).astype(dt)
-            v = jnp.einsum("blr,rhd->blhd", c_kv, w_ukv[..., dn:],
+            v = jnp.einsum("blr,rhd->bhdl", c_kv, w_ukv[..., dn:],
                            preferred_element_type=jnp.float32).astype(dt)
             w_uq = p["w_uq"].reshape(r_q, heads, dn + dr)
-
-        def q_fn(b, t0):
-            with jax.named_scope(scope + ".mla"):
-                cq = jax.lax.dynamic_slice_in_dim(c_q[b], t0, block, 0)
-                q = jnp.einsum("qr,rhd->qhd", cq, w_uq,
-                               preferred_element_type=jnp.float32)
-                t = t0 + jnp.arange(block, dtype=jnp.int32)
-                return q[..., :dn].astype(dt), turned(q[..., dn:], t)
+            q = jnp.einsum("blr,rhd->bhld", c_q, w_uq[..., :dn],
+                           preferred_element_type=jnp.float32).astype(dt)
+            q_shared = turned(jnp.einsum(
+                "blr,rhd->bhld", c_q, w_uq[..., dn:],
+                preferred_element_type=jnp.float32),
+                jnp.arange(length, dtype=jnp.int32)[None, None])
 
         out, n_keys, first = latent.causal_attention(
-            q_fn, k, k_shared, v, length=length, scale=scale, block=block,
-            chunk=self.key_chunk, scope=scope + ".mla.full")
+            q, q_shared, k, k_shared, v, length=length, scale=scale,
+            block=block, chunk=self.key_chunk, scope=scope + ".mla.full")
         with jax.named_scope(scope + ".mla"):
             return dot32(out.reshape(b_sz, length, heads * dv),
                          p["w_o"]), n_keys, first

@@ -380,8 +380,8 @@ def _attend_fused(q, q_shared, k, k_shared, v, keys, b, n_tiles, *,
     L) bool → (H, Q, Dv) in ``v.dtype``. Keys and values are fetched
     tile by tile straight from the whole arrays (``b`` is a prefetched
     scalar: no route is sliced out in HBM). ``name``: what a device
-    trace calls the kernel (``parallel/latent.py`` runs it under a name
-    of its own)."""
+    trace calls the kernel; ``parallel/latent.py``'s dense causal step
+    is a kernel of its own since PR 40 and no longer calls this one."""
     n_q, heads, d = q.shape
     d_r, d_v, length = q_shared.shape[-1], v.shape[-1], v.shape[2]
     at = jnp.stack([b, n_tiles]).astype(jnp.int32)

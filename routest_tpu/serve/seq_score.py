@@ -107,8 +107,9 @@ that got a likelihood term for the arc after next). ``seq.step`` carries
 ``rtpu_seq_mtp_positions_total`` as ``RouteLMKExaone``,
 ``rtpu_seq_latent_keys_total{kind=needed|visited}`` (its dense causal
 latent-attention blocks, the module's among them: ``t + 1`` keys a real
-query, from the device; the keys of the chunks the dispatched programs
-multiplied, from the plan) and ``rtpu_seq_expert_group_tokens_total{
+query, from the device; the pairs the kernel's grid multiplies, from
+the plan), ``rtpu_seq_latent_tiles_total{kind=interior|diagonal}`` (its
+grid steps, from the same tables) and ``rtpu_seq_expert_group_tokens_total{
 kind=held_group|all}`` (real tokens of the expert blocks one of whose
 chosen experts lies in the held experts' routing group, from the
 device's ``chosen`` taps; all of them). ``seq.step`` carries ``mixers``
@@ -203,6 +204,12 @@ def _seq_metrics():
                 "by real queries (needed), and multiplied by the "
                 "dispatched programs, masked or padded or not "
                 "(visited).", ("kind",)),
+            "latent_tiles": reg.counter(
+                "rtpu_seq_latent_tiles_total",
+                "Grid steps of the dense causal latent-attention kernel "
+                "at the dispatched steps' shapes, a group of heads each: "
+                "whole tiles of keys, unmasked (interior), and a block "
+                "of queries' last tile, masked (diagonal).", ("kind",)),
             "expert_group_tokens": reg.counter(
                 "rtpu_seq_expert_group_tokens_total",
                 "Real tokens of the expert blocks under group-limited "
@@ -215,7 +222,8 @@ def _seq_metrics():
 
 _COUNTERS = ("tokens", "chunks", "window_blocks", "expert_blocks",
              "expert_rows", "sparse_keys", "linear_chunks", "gqa_keys",
-             "mtp_positions", "latent_keys", "expert_group_tokens")
+             "mtp_positions", "latent_keys", "latent_tiles",
+             "expert_group_tokens")
 
 
 class Step(NamedTuple):

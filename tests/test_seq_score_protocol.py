@@ -316,7 +316,7 @@ def test_kexaone_counters_and_span_attributes(registry, scorers):
 
 def test_gigachat_counters_and_span_attributes(registry, scorers):
     from routest_tpu.obs import get_tracer
-    from routest_tpu.parallel import gqa
+    from routest_tpu.parallel import latent
 
     m, params, scorer = scorers["gigachat"]
     ids, lengths, rows_at = (jnp.asarray(a)
@@ -331,9 +331,24 @@ def test_gigachat_counters_and_span_attributes(registry, scorers):
     assert keys[("needed",)] == sum(5 * tri(n) + tri(n - 1) for n in LENGTHS)
     plan = scorer.plan(np.asarray(LENGTHS))
     assert [s.length for s in plan] == [96, 72, 40]
+    # the pairs the kernel's grid multiplies: every block of 8 queries
+    # times the keys up to its own, 8 x 8 x (1 + 2 + ... + L / 8)
     assert keys[("visited",)] == 6 * sum(
-        gqa.causal_visited(s.length, 8, 16) for s in plan)
+        latent.visited(s.length, 8, 16) for s in plan) == 6 * sum(
+        64 * tri(s.length // 8) for s in plan)
     assert keys[("visited",)] > keys[("needed",)]
+    # its grid steps from the same tables: one diagonal a block of
+    # queries, the whole tiles of 16 keys before it interior; one group
+    # of heads at the toy's 4
+    tiles = _family(registry, "rtpu_seq_latent_tiles_total")
+    assert tiles[("diagonal",)] == 6 * sum(
+        len(s.routes) * s.length // 8 for s in plan)
+    assert tiles[("interior",)] + tiles[("diagonal",)] == 6 * sum(
+        len(latent.causal_grid(len(s.routes), s.length, 8, 16))
+        for s in plan)
+    assert tiles[("interior",)] == 6 * sum(
+        len(s.routes) * sum(i // 2 for i in range(s.length // 8))
+        for s in plan)
     assert _family(registry, "rtpu_seq_mtp_positions_total")[()] == sum(
         n - 2 for n in LENGTHS)
     # the tokens one of whose chosen experts lies in group 0 (experts
@@ -374,6 +389,7 @@ def test_the_other_two_models_emit_no_gqa_or_module_counters(registry,
     assert _family(registry, "rtpu_seq_gqa_keys_total") == {}
     assert _family(registry, "rtpu_seq_mtp_positions_total") == {}
     assert _family(registry, "rtpu_seq_latent_keys_total") == {}
+    assert _family(registry, "rtpu_seq_latent_tiles_total") == {}
     assert _family(registry, "rtpu_seq_expert_group_tokens_total") == {}
 
 
