@@ -223,13 +223,18 @@ def route_top_k(x: jax.Array, router: jax.Array, bias: jax.Array,
 
 
 def gated_mlp(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
-              w_down: jax.Array) -> jax.Array:
-    """``(silu(x w_gate) * x w_up) w_down``: products in the arrays'
-    dtype, accumulated and returned in float32."""
+              w_down: jax.Array, gate_mult: float = 1.0,
+              down_mult: float = 1.0) -> jax.Array:
+    """``(silu(gate_mult x w_gate) * x w_up) w_down * down_mult``:
+    products in the arrays' dtype, accumulated and returned in float32;
+    a multiplier of 1 is no operation of the program."""
     gate = jnp.matmul(x, w_gate, preferred_element_type=jnp.float32)
     up = jnp.matmul(x, w_up, preferred_element_type=jnp.float32)
+    if gate_mult != 1.0:
+        gate = gate * gate_mult
     hidden = (jax.nn.silu(gate) * up).astype(x.dtype)
-    return jnp.matmul(hidden, w_down, preferred_element_type=jnp.float32)
+    out = jnp.matmul(hidden, w_down, preferred_element_type=jnp.float32)
+    return out if down_mult == 1.0 else out * down_mult
 
 
 # ── the held experts' grouped product ─────────────────────────────────

@@ -5,9 +5,11 @@ the ``dots3-note-prev`` architecture, ``models/route_lm_sala
 .RouteLMSala``, the ``MiniCPM-SALA`` one, ``models/route_lm_kexaone
 .RouteLMKExaone``, the ``K-EXAONE-236B-A23B`` one, and
 ``models/route_lm_gigachat.RouteLMGigaChat``, the
-``GigaChat3.1-702B-A36B`` one; the last two have a prediction module
-that gives a second likelihood column — the arc after next — as taps
-``mtp_next_logit``, ``mtp_lse`` and ``mtp_loglik``).
+``GigaChat3.1-702B-A36B`` one — these two have a prediction module
+that gives a second likelihood column, the arc after next, as taps
+``mtp_next_logit``, ``mtp_lse`` and ``mtp_loglik`` — and
+``models/route_lm_falcon_h1.RouteLMFalconH1``, the
+``Falcon-H1-34B-Instruct`` one).
 
 A caller holds ``ids`` (R, L_max) and ``lengths`` (R,) on the device
 and asks for every route's next-arc logits, log-sum-exps and
@@ -116,6 +118,14 @@ device's ``chosen`` taps; all of them). ``seq.step`` carries ``mixers``
 (``latent=fused`` or ``latent=xla``: the form of the dense softmax,
 ``parallel/latent.latent_path``), ``mtp``, ``experts`` and ``groups``
 (the router's ``n_group/topk_group``, ``8/4``).
+``RouteLMFalconH1``: ``rtpu_seq_gqa_keys_total{layer=full, kind=needed|
+visited}`` as ``RouteLMKExaone``'s full layers (its attention, one a
+block) and ``rtpu_seq_ssm_chunks_total{path=fused|xla}`` (routes x
+chunks x state-space blocks of the dispatched steps, by the form
+``parallel/ssd.ssd_path`` names at the step's shapes, from the plan).
+``seq.step`` carries ``mixers`` (``ssm=fused,attn=xla`` or
+``ssm=xla,attn=xla``); the taps ``state`` are each state-space block's
+state at a route's last real token.
 """
 
 from __future__ import annotations
@@ -216,6 +226,12 @@ def _seq_metrics():
                 "routing: those one of whose chosen experts lies in the "
                 "held experts' routing group (held_group), and all of "
                 "them (all).", ("kind",)),
+            "ssm_chunks": reg.counter(
+                "rtpu_seq_ssm_chunks_total",
+                "Chunk steps of the state-space scans that the dispatched "
+                "steps ran (routes x chunks x state-space blocks), by the "
+                "form of the scan (fused: the Pallas kernel; xla).",
+                ("path",)),
         }
     return _metrics
 
@@ -223,7 +239,7 @@ def _seq_metrics():
 _COUNTERS = ("tokens", "chunks", "window_blocks", "expert_blocks",
              "expert_rows", "sparse_keys", "linear_chunks", "gqa_keys",
              "mtp_positions", "latent_keys", "latent_tiles",
-             "expert_group_tokens")
+             "expert_group_tokens", "ssm_chunks")
 
 
 class Step(NamedTuple):

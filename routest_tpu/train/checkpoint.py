@@ -417,13 +417,15 @@ def _route_lm_types():
     """Model type in an artifact's header → class. ``RouteLM`` is what
     a header without the key holds."""
     from routest_tpu.models.route_lm import RouteLM
+    from routest_tpu.models.route_lm_falcon_h1 import RouteLMFalconH1
     from routest_tpu.models.route_lm_gigachat import RouteLMGigaChat
     from routest_tpu.models.route_lm_kexaone import RouteLMKExaone
     from routest_tpu.models.route_lm_sala import RouteLMSala
 
     return {"RouteLM": RouteLM, "RouteLMSala": RouteLMSala,
             "RouteLMKExaone": RouteLMKExaone,
-            "RouteLMGigaChat": RouteLMGigaChat}
+            "RouteLMGigaChat": RouteLMGigaChat,
+            "RouteLMFalconH1": RouteLMFalconH1}
 
 
 def save_route_lm(path: str, model, params) -> None:
@@ -432,7 +434,9 @@ def save_route_lm(path: str, model, params) -> None:
     these parameters are (``RouteLM``: layers, experts and vocabulary
     rows held, chips a layer; ``RouteLMKExaone`` and ``RouteLMGigaChat``:
     the same and whether the prediction module is held; ``RouteLMSala``:
-    a run of layers from ``layers_first`` on) and the dtype policy; the
+    a run of layers from ``layers_first`` on; ``RouteLMFalconH1``: a run
+    of blocks and the vocabulary's rows over ``vocab_chips``) and the
+    dtype policy; the
     blob is the params pytree (bfloat16 leaves travel as they are)."""
     _write_artifact(path, MAGIC, {
         "format": "routest_tpu.route_lm",

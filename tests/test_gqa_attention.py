@@ -67,6 +67,23 @@ def test_attention_matches_a_brute_force_softmax(kind, length, sizes):
         keys.argmax(-1), first.shape))
 
 
+
+@pytest.mark.parametrize("groups,per", [(4, 5), (2, 5)])
+def test_causal_attention_at_five_query_heads_a_group(groups, per):
+    """The fifth model's grouping, 20 query heads over 4 key-value heads
+    (and 10 over 2 at its toy size): no code of the function changed for
+    it; the numbers are the brute force's."""
+    ks = jax.random.split(jax.random.PRNGKey(7), 3)
+    q = jax.random.normal(ks[0], (2, 40, groups, per, D))
+    k = jax.random.normal(ks[1], (2, 40, groups, D))
+    v = jax.random.normal(ks[2], (2, 40, groups, D))
+    out, n_keys, _ = _run("causal", q, k, v, block=8, chunk=16)
+    want, keys = _brute(q, k, v)
+    assert out.shape == (2, 40, groups, per, D)
+    np.testing.assert_allclose(out, want, rtol=2e-5, atol=2e-6)
+    np.testing.assert_array_equal(n_keys, np.broadcast_to(
+        keys.sum(-1), n_keys.shape))
+
 def test_key_taps_are_exact_at_the_windows_edge():
     q, k, v = _qkv(1, 1, 32)
     _, n_keys, first = _run("window", q, k, v, window=8, block=8, rows=16)

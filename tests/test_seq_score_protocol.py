@@ -1,7 +1,8 @@
 """One scorer for every route-sequence model: what ``RouteScorer`` asks
 of a model (``serve/seq_score.py``) is met by ``RouteLM``, by
-``RouteLMSala``, by ``RouteLMKExaone`` and by ``RouteLMGigaChat``, whose
-prediction modules' columns come through the same tap tables; ``RouteLM``'s and
+``RouteLMSala``, by ``RouteLMKExaone``, by ``RouteLMGigaChat``, whose
+prediction modules' columns come through the same tap tables, and by
+``RouteLMFalconH1``, whose state-space layers' states do; ``RouteLM``'s and
 ``RouteLMSala``'s plans and result tables are what they were."""
 
 import jax
@@ -9,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import _route_lm_falcon_h1_toy as falcon
 import _route_lm_gigachat_toy as gigachat
 import _route_lm_kexaone_toy as kexaone
 import _route_lm_sala_toy as sala
@@ -18,7 +20,7 @@ from routest_tpu.serve.seq_score import RouteScorer, plan_pass
 
 LENGTHS = [96, 33, 70]
 TOYS = {"dots3": dots3, "sala": sala, "kexaone": kexaone,
-        "gigachat": gigachat}
+        "gigachat": gigachat, "falcon": falcon}
 
 
 def _scorer(toy, **kw):
@@ -59,7 +61,7 @@ def scorers():
 
 
 @pytest.fixture(scope="module", params=["dots3", "sala", "kexaone",
-                                        "gigachat"])
+                                        "gigachat", "falcon"])
 def scored(request, scorers):
     toy = TOYS[request.param]
     m, params, scorer = scorers[request.param]
@@ -101,7 +103,8 @@ def test_the_tables_are_the_models_tap_tables(scored):
         "kexaone": {"n_keys", "first_key", "chosen", "mtp_next_logit",
                     "mtp_lse", "mtp_loglik"},
         "gigachat": {"n_keys", "first_key", "chosen", "mtp_next_logit",
-                     "mtp_lse", "mtp_loglik"}}[name]
+                     "mtp_lse", "mtp_loglik"},
+        "falcon": {"n_keys", "first_key", "state"}}[name]
 
 
 def test_the_modules_column_reaches_the_caller_over_the_table(scorers):
@@ -193,6 +196,17 @@ def test_the_real_models_quanta():
         (1, 26112), (1, 17152), (1, 13312), (1, 10752), (2, 8960), (1, 6400),
         (2, 5120), (1, 2816)]
     assert sum(s.padded_tokens for s in plan) == 3643       # 3.48%
+    from routest_tpu.models.route_lm_falcon_h1 import RouteLMFalconH1
+
+    _, cfg, mix = R.load_cell(manifest, "route-lm-falcon-hybrid")
+    m = RouteLMFalconH1.from_config(cfg)
+    assert m.length_quantum == 256
+    plan = plan_pass(mix["lengths"], m.length_quantum,
+                     mix["max_step_tokens"], mix["max_classes"])
+    assert [(len(s.routes), s.length) for s in plan] == [
+        (1, 14848), (1, 9728), (2, 7936), (2, 5632), (2, 4608), (4, 3584),
+        (4, 2560), (4, 1536)]
+    assert sum(s.padded_tokens for s in plan) == 8843       # 9.65%
 
 
 @pytest.fixture
@@ -379,6 +393,45 @@ def test_gigachat_counters_and_span_attributes(registry, scorers):
                and s["attrs"]["experts"] == "xla" for s in steps)
 
 
+def test_falcon_counters_span_attributes_and_states(registry, scorers):
+    """The attention's keys as K-EXAONE's full layers count them, the
+    scan's chunk steps by form, and each block's state at a route's last
+    real token: the state of the route alone, whatever its step."""
+    from routest_tpu.obs import get_tracer
+    from routest_tpu.parallel import gqa
+
+    m, params, scorer = scorers["falcon"]
+    ids, lengths, rows_at = (jnp.asarray(a)
+                             for a in falcon.routes(4, LENGTHS))
+    scores = scorer.score(ids, lengths, rows_at)
+    assert _family(registry, "rtpu_seq_tokens_total")[("real",)] == sum(
+        LENGTHS)
+    keys = _family(registry, "rtpu_seq_gqa_keys_total")
+    tri = lambda n: n * (n + 1) // 2                        # noqa: E731
+    plan = scorer.plan(np.asarray(LENGTHS))
+    assert [s.length for s in plan] == [96, 72, 40]
+    assert keys[("full", "needed")] == 3 * sum(tri(n) for n in LENGTHS)
+    assert keys[("full", "visited")] == 3 * sum(
+        gqa.causal_visited(s.length, 8, 16) for s in plan)
+    assert set(keys) == {("full", "needed"), ("full", "visited")}
+    # three blocks x (12 + 9 + 5) chunks of 8, the XLA form here
+    assert _family(registry, "rtpu_seq_ssm_chunks_total") == {
+        ("xla",): 3 * 26}
+    assert _family(registry, "rtpu_seq_linear_chunks_total") == {}
+    assert _family(registry, "rtpu_seq_expert_blocks_total") == {}
+    steps = [s for s in get_tracer().buffer.snapshot()
+             if s["name"] == "seq.step"][-3:]
+    assert all(s["attrs"]["mixers"] == "ssm=xla,attn=xla" for s in steps)
+    for r, n in enumerate(int(v) for v in lengths):
+        padded = -(-n // 8) * 8
+        alone = jax.jit(m.apply)(
+            params, jnp.pad(ids[r:r + 1, :n], ((0, 0), (0, padded - n))),
+            lengths[r:r + 1], rows_at[r:r + 1])
+        np.testing.assert_allclose(scores.taps["state"][:, r],
+                                   alone["state"][:, 0], rtol=1e-4,
+                                   atol=1e-6)
+
+
 def test_the_other_two_models_emit_no_gqa_or_module_counters(registry,
                                                              scorers):
     for name, toy in (("dots3", dots3), ("sala", sala)):
@@ -391,6 +444,7 @@ def test_the_other_two_models_emit_no_gqa_or_module_counters(registry,
     assert _family(registry, "rtpu_seq_latent_keys_total") == {}
     assert _family(registry, "rtpu_seq_latent_tiles_total") == {}
     assert _family(registry, "rtpu_seq_expert_group_tokens_total") == {}
+    assert _family(registry, "rtpu_seq_ssm_chunks_total") == {}
 
 
 # ── a pass accounts for its own time (ISSUE 37) ─────────────────────

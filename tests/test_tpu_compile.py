@@ -326,3 +326,70 @@ def test_a_step_holds_the_grouped_expert_kernels(v5e, monkeypatch, config,
     assert sum(" sort(" in line for line in inside) == blocks
     assert not [line for line in inside if " scatter(" in line
                 and "indices_are_sorted=true" in line]
+
+
+# The fifth model's state-space scan at the cell's widths (32 heads of
+# 128, two groups of a state of 256, chunks of 128), one kernel a block
+# over the conv's lanes: the longest class (one route of 14,848 tokens)
+# and the widest (four of 1,536).
+@pytest.mark.parametrize("routes,length", [(1, 14848), (4, 1536)])
+def test_ssd_scan_step_compiles_for_v5e(v5e, routes, length):
+    from routest_tpu.parallel import ssd
+
+    heads, p, groups, n = 32, 128, 2, 256
+    assert ssd.ssd_path(heads, p, n, jnp.bfloat16, "tpu", groups=groups,
+                        chunk=128) == "fused"
+
+    def on(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    compiled = jax.jit(functools.partial(
+        ssd._scan_fused, heads=heads, groups=groups, state=n,
+        chunk=128)).lower(
+        on((routes, length, heads * p + 2 * groups * n), jnp.bfloat16),
+        on((routes, heads, length)), on((routes, heads, length)),
+        on((heads,)), on((heads // ssd.HEAD_TILE,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%ssd_scan_step[.\d]* = \(.*\) custom-call\(",
+                          text)) == 1
+    assert "tpu_custom_call" in text
+
+
+# The fifth model's longest step (one route of 14,848 tokens) and its
+# largest batched one (two of 7,936) whole, the scan picked as on the
+# chip: one scan kernel a block, reading the convolution's output where
+# it lies, and the step beside its 7.03 GiB of parameters under 15.0 GiB
+# (8.0 at PR 41: PERF.md §4).
+@pytest.mark.parametrize("routes,length", [(1, 14848), (2, 7936)])
+def test_the_hybrid_step_fits_and_holds_one_scan_kernel_a_block(
+        v5e, monkeypatch, routes, length):
+    import json
+
+    from routest_tpu.models.route_lm_falcon_h1 import RouteLMFalconH1
+    from routest_tpu.parallel import ssd
+
+    real = ssd.ssd_path
+    monkeypatch.setattr(ssd, "ssd_path", lambda *a, **kw: real(
+        *a, **{**kw, "backend": "tpu"}))
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmark", "configs",
+                           "falcon-h1-34b-l0-7.json")) as f:
+        model = RouteLMFalconH1.from_config(json.load(f))
+    assert model.step_attrs(length) == {"mixers": "ssm=fused,attn=xla"}
+
+    def on(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=v5e)
+
+    compiled = jax.jit(model.apply).lower(
+        _on(v5e, jax.eval_shape(model.init, jax.random.PRNGKey(0))),
+        on((routes, length)), on((routes,)), on((routes, 4))).compile()
+    memory = compiled.memory_analysis()
+    assert (memory.temp_size_in_bytes
+            + memory.argument_size_in_bytes) < 15.0 * 2 ** 30
+    calls = re.findall(r"%ssd_scan_step[.\d]* = \(.*\) custom-call\((.*)",
+                       compiled.as_text())
+    assert len(calls) == 8
+    # x, B and C are three views of one operand, the conv's output
+    for args in calls:
+        operands = [a.strip() for a in args.split(")")[0].split(",")]
+        assert operands[2] == operands[3] == operands[4], operands
