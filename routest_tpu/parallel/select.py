@@ -38,16 +38,27 @@ product — has two forms with one set of semantics, and
 backend alone:
 
 - ``"fused"``: one Pallas TPU kernel a block of queries
-  (:func:`_attend_fused`), a grid over groups of ``HEAD_TILE`` heads and
-  tiles of 1,024 or 512 keys (:func:`key_tile_for`), the float32 score
-  and probability tiles and the running max, sum and accumulator in
-  VMEM. It runs where the backend is a TPU, the arrays are bfloat16 and
-  the shapes tile: head widths multiples of 128, the shared width of
-  64, the heads a multiple of ``HEAD_TILE``, the chunk of a key tile,
-  the block of 32. Written in XLA the same step sends a float32 (heads,
-  block, chunk) tile through HBM five times (268 MB at 128 heads, 256
-  queries, 2,048 keys) and is bound by that; the kernel reads the
-  chunk's keys and values and writes the block's output, nothing else.
+  (:func:`_attend_fused`, ``selected_attention_step`` in a trace), a
+  grid over groups of ``HEAD_TILE`` heads and tiles of 1,024 or 512
+  keys (:func:`key_tile_for`), the float32 (queries, keys) score and
+  probability tiles and the running max, sum and accumulator in VMEM.
+  The heads of a group are unrolled, one head's softmax beside the next
+  one's products. A head's running max and sum are (queries, 128), a
+  row's value in every lane, so that they meet the score tile 128 keys
+  at a time and the (queries, Dv) accumulator lane for lane, with no
+  column broadcast. The mask becomes an additive ``0 / -inf`` bias once
+  a step for the group's heads: one select a step, and none after the
+  ``exp``, because the running max starts at the finite ``_NEG`` and a
+  masked key's ``exp(-inf - max)`` is exactly 0, also for a query that
+  has seen no key yet. Jitted, so that the full layers of a step
+  program share one trace and lowering of it. It runs where the backend
+  is a TPU, the arrays are bfloat16 and the shapes tile: head widths
+  multiples of 128, the shared width of 64, the heads a multiple of
+  ``HEAD_TILE``, the chunk of a key tile, the block of 32. Written in
+  XLA the same step sends a float32 (heads, block, chunk) tile through
+  HBM five times (268 MB at 128 heads, 256 queries, 2,048 keys) and is
+  bound by that; the kernel reads the chunk's keys and values and
+  writes the block's output, nothing else.
 - ``"xla"``: :func:`_attend_xla`, a ``fori_loop`` over chunks of keys.
   It runs everywhere else — the CPU of the tests, toy widths, float32
   arrays — and is the oracle of the kernel's parity test.
@@ -257,6 +268,7 @@ def selector_scores(q_idx, w_idx, k_idx):
 HEAD_TILE = 8               # heads of one grid step of the kernel
 KEY_TILES = (1024, 512)     # keys of one: the first that divides the chunk
 _VMEM_BYTES = 64 * 2 ** 20  # (readings of both: PERF.md §6, PR 28)
+_LANES = 128
 
 
 def key_tile_for(chunk: int) -> int:
@@ -330,11 +342,17 @@ def _attend_xla(q, q_shared, k, k_shared, v, keys, b, n_chunks, *,
 
 
 def _attend_kernel(at_ref, q_ref, qs_ref, k_ref, ks_ref, v_ref, seen_ref,
-                   o_ref, acc_ref, m_ref, den_ref, *, scale: float):
+                   o_ref, acc_ref, m_ref, den_ref, bias_ref, *, scale: float):
     """One grid step: ``HEAD_TILE`` heads of the block against one tile
-    of keys. ``at_ref`` (2,): the route and the number of key tiles the
-    block visits; beyond it a step does nothing and fetches nothing."""
+    of keys. A head's running max and sum are (Q, 128), a row's value in
+    every lane, so that they meet the (Q, keys) score tile one 128-key
+    chunk at a time and the (Q, Dv) accumulator lane for lane, with no
+    column broadcast. ``at_ref`` (2,): the route and the number of key
+    tiles the block visits; beyond it a step does nothing and fetches
+    nothing."""
     j, n_tiles = pl.program_id(1), at_ref[1]
+    n_k, d_v = k_ref.shape[1], acc_ref.shape[-1]
+    nt = (((1,), (1,)), ((), ()))
 
     @pl.when(j == 0)
     def _():
@@ -344,31 +362,37 @@ def _attend_kernel(at_ref, q_ref, qs_ref, k_ref, ks_ref, v_ref, seen_ref,
 
     @pl.when(j < n_tiles)
     def _():
-        h, n_q, d_r = qs_ref.shape
-        seen = (seen_ref[...].astype(jnp.int32) != 0)[None]
-        s = jnp.einsum("hqd,hkd->hqk", q_ref[...], k_ref[...],
-                       preferred_element_type=jnp.float32)
-        shared = jax.lax.dot_general(        # all the tile's heads at once
-            qs_ref[...].reshape(h * n_q, d_r), ks_ref[...],
-            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-        s = jnp.where(seen, (s + shared.reshape(s.shape)) * scale, _NEG)
-        m = m_ref[...]
-        m_new = jnp.maximum(m, s.max(-1, keepdims=True))
-        # a masked key adds exactly nothing, also where a query sees no
-        # key of the tile and exp(NEG - NEG) = 1
-        p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
-        fix = jnp.exp(m - m_new)
-        acc_ref[...] = acc_ref[...] * fix + jnp.einsum(
-            "hqk,hkd->hqd", p.astype(v_ref.dtype), v_ref[...],
-            preferred_element_type=jnp.float32)
-        den_ref[...] = den_ref[...] * fix + p.sum(-1, keepdims=True)
-        m_ref[...] = m_new
+        # the mask once a step for the group's heads, as a bias: a masked
+        # score is -inf and the running max starts at the finite NEG, so
+        # exp(s - m_new) is exactly 0 at a masked key, also where a query
+        # has seen no key yet
+        bias_ref[...] = jnp.where(seen_ref[...].astype(jnp.int32) != 0, 0.0,
+                                  -jnp.inf)
+        # unrolled: one head's softmax beside the next one's products
+        for h in range(q_ref.shape[0]):
+            s = jax.lax.dot_general(q_ref[h], k_ref[h], nt,
+                                    preferred_element_type=jnp.float32)
+            s = s + jax.lax.dot_general(qs_ref[h], ks_ref[...], nt,
+                                        preferred_element_type=jnp.float32)
+            s = s * scale + bias_ref[...]
+            m = m_ref[h]
+            m_new = jnp.maximum(m, s.max(-1, keepdims=True))
+            p = jnp.exp(s - jnp.tile(m_new, (1, n_k // _LANES)))
+            fix = jnp.exp(m - m_new)
+            acc_ref[h] = acc_ref[h] * jnp.tile(fix, (1, d_v // _LANES)) \
+                + jnp.dot(p.astype(v_ref.dtype), v_ref[h],
+                          preferred_element_type=jnp.float32)
+            den_ref[h] = den_ref[h] * fix + p.sum(-1, keepdims=True)
+            m_ref[h] = m_new
 
     @pl.when(j == n_tiles - 1)
     def _():
-        o_ref[...] = (acc_ref[...] / den_ref[...]).astype(o_ref.dtype)
+        den = jnp.tile(den_ref[...], (1, 1, d_v // _LANES))
+        o_ref[...] = (acc_ref[...] / den).astype(o_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=("scale", "key_tile", "head_tile",
+                                             "interpret", "name"))
 def _attend_fused(q, q_shared, k, k_shared, v, keys, b, n_tiles, *,
                   scale: float, key_tile: int, head_tile: int = HEAD_TILE,
                   interpret: bool = False,
@@ -379,9 +403,9 @@ def _attend_fused(q, q_shared, k, k_shared, v, keys, b, n_tiles, *,
     D), v (B, H, L, Dv) laid out by head; k_shared (B, L, Dr); keys (Q,
     L) bool → (H, Q, Dv) in ``v.dtype``. Keys and values are fetched
     tile by tile straight from the whole arrays (``b`` is a prefetched
-    scalar: no route is sliced out in HBM). ``name``: what a device
-    trace calls the kernel; ``parallel/latent.py``'s dense causal step
-    is a kernel of its own since PR 40 and no longer calls this one."""
+    scalar: no route is sliced out in HBM). Jitted, so that the full
+    layers of a step program share one trace and lowering of it.
+    ``name``: what a device trace calls the kernel."""
     n_q, heads, d = q.shape
     d_r, d_v, length = q_shared.shape[-1], v.shape[-1], v.shape[2]
     at = jnp.stack([b, n_tiles]).astype(jnp.int32)
@@ -406,8 +430,9 @@ def _attend_fused(q, q_shared, k, k_shared, v, keys, b, n_tiles, *,
         out_specs=pl.BlockSpec((head_tile, n_q, d_v),
                                lambda g, j, at: (g, 0, 0)),
         scratch_shapes=[pltpu.VMEM((head_tile, n_q, d_v), jnp.float32),
-                        pltpu.VMEM((head_tile, n_q, 1), jnp.float32),
-                        pltpu.VMEM((head_tile, n_q, 1), jnp.float32)])
+                        pltpu.VMEM((head_tile, n_q, _LANES), jnp.float32),
+                        pltpu.VMEM((head_tile, n_q, _LANES), jnp.float32),
+                        pltpu.VMEM((n_q, key_tile), jnp.float32)])
     return pl.pallas_call(
         functools.partial(_attend_kernel, scale=scale),
         grid_spec=grid_spec,
@@ -425,7 +450,6 @@ def _attend_fused(q, q_shared, k, k_shared, v, keys, b, n_tiles, *,
 
 WINDOW_Q_TILE = 256         # queries of one grid step of the window kernel
 WINDOW_MAX_SPAN = 2048      # (readings of the tilings: PERF.md §6, PR 36)
-_LANES = 128
 
 
 def window_path(heads: int, block: int, span: int, d: int, d_shared: int,

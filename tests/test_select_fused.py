@@ -3,6 +3,8 @@ kernel, here under ``interpret=True``) against the XLA step it stands
 in for, at small shapes that tile; the pure function that chooses
 between the two; and what the scorer says of the choice."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -128,6 +130,55 @@ def test_the_larger_key_tile_is_the_xla_step_at_its_chunk(dtype):
                       key_tile=1024)
     tol = 2e-6 if dtype == "float32" else 1e-6
     np.testing.assert_allclose(got, want, atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_a_query_blind_to_whole_tiles_adds_nothing_there(dtype):
+    """The last block of queries over four tiles: every second query
+    sees no key in the first two, no query any in the third, all their
+    own keys in the fourth. A masked score is -inf and the running max
+    starts at the finite NEG, so a query that has seen nothing yet adds
+    exp(-inf) = 0 and keeps its max, sum and accumulator, and the fourth
+    tile's real max scales them by exp(NEG - max) = 0; the XLA step
+    multiplies by the mask instead."""
+    block, chunk = 32, 512
+    i = LENGTH // block - 1
+    q, q_shared, k, k_shared, v = _arrays(jnp.dtype(dtype), 4, block, 4)
+    tile = jnp.arange(LENGTH) // chunk
+    odd = (jnp.arange(block) % 2 == 1)[:, None]
+    keys = (_mask("causal", block, i, chunk) & (tile != 2)[None, :]
+            & ~(odd & (tile < 2)[None, :]))
+    assert not keys[1::2, :2 * chunk].any() and keys[::2, :2 * chunk].all()
+    assert not keys[:, 2 * chunk:3 * chunk].any()
+    got, want = _both(q, q_shared, k, k_shared, v, keys, i, chunk)
+    assert np.isfinite(got).all()
+    tol = 2e-6 if dtype == "float32" else 1e-6
+    np.testing.assert_allclose(got, want, atol=tol, rtol=tol)
+
+
+def test_two_calls_in_one_program_lower_one_kernel():
+    """Jitted: the two full layers of a step program call the kernel at
+    the same shapes and share one trace and lowering of it, one
+    ``tpu_custom_call`` in the program lowered for a TPU (the function
+    unwrapped lowers two)."""
+    heads, block, length = 8, 32, 1024
+    q, q_shared, k, k_shared, v = _arrays(jnp.bfloat16, heads, block)
+    args = (q, q_shared, k[:, :length].transpose(0, 2, 1, 3),
+            k_shared[:, :length], v[:, :length].transpose(0, 2, 1, 3),
+            _mask("causal", block, 30, 512)[:, :length])
+
+    def calls(attend):
+        def two(*a):
+            step = functools.partial(attend, scale=SCALE, key_tile=512)
+            return (step(*a, jnp.int32(0), jnp.int32(2))
+                    + step(*a, jnp.int32(1), jnp.int32(1)))
+
+        text = jax.jit(two).trace(*args).lower(
+            lowering_platforms=("tpu",)).as_text()
+        return text.count("tpu_custom_call")
+
+    assert calls(select._attend_fused) == 1
+    assert calls(select._attend_fused.__wrapped__) == 2
 
 
 # ── the choice ───────────────────────────────────────────────────────
