@@ -66,8 +66,9 @@ from routest_tpu.parallel.expert import (ExpertShare, expert_path, gated_mlp,
                                          moe_share, row_tile_of)
 from routest_tpu.parallel.select import (attention_path, block_and_chunk,
                                          chunk_steps, selected_attention,
-                                         selected_rows, window_path,
-                                         window_span, windowed_attention)
+                                         selected_rows, topk_blocks,
+                                         window_path, window_span,
+                                         windowed_attention)
 
 Params = Dict
 
@@ -218,6 +219,13 @@ class RouteLM:
                               a.d_v, self.policy.compute_dtype)
         return path, chunk_steps(length, self.select_block, self.key_chunk)
 
+    def topk_blocks(self, length: int) -> Tuple[str, int]:
+        """For a route padded to ``length``, in one full layer: which
+        form of the radix top-k runs (``"fused"`` or ``"xla"``) and how
+        many blocks of queries run it (``select.topk_blocks``)."""
+        return topk_blocks(length, self.select_block,
+                           self.attention_sizes(FULL).top_k)
+
     def window_steps(self, length: int) -> Tuple[str, int]:
         """For a route padded to ``length``, in one sliding layer: which
         window step runs (``"fused"`` or ``"xla"``: what
@@ -304,6 +312,10 @@ class RouteLM:
                         blocks * len(step.routes) * n_sliding))
             if n_moe:
                 out.append(("expert_blocks", {"path": experts}, n_moe))
+            path, blocks = self.topk_blocks(step.length)
+            if blocks:
+                out.append(("topk_blocks", {"path": path},
+                            blocks * len(step.routes) * n_full))
         counts = [s["counts"] for s in stats if "counts" in s]
         if counts:
             k = int(self.sizes["num_experts_per_tok"])

@@ -104,13 +104,44 @@ block, span, the three widths, dtype and backend alone:
 
 What each query saw (``n_keys``, ``first_key``) is computed in both
 forms by the same lines from the same :func:`window_keys`.
+
+**Which top-k runs where.** :func:`top_k_mask` has two forms of one
+result — the same set of keys, bit for bit — and :func:`topk_path`
+chooses between them from the scores' shape, dtype and backend alone:
+
+- ``"fused"``: one Pallas TPU kernel a block of queries
+  (:func:`_top_k_fused`, ``radix_top_k_step`` in a trace) finds each
+  row's k-th value and last tie; the mask is the shared last line
+  (:func:`_cut_at`) over them. A grid over tiles of ``TOPK_ROWS`` rows,
+  the tile's whole row of scores in VMEM; its ordered keys are made
+  once, and the value search reads them ``RADIX_BITS`` bits of the
+  threshold a pass, counting lane by lane in ``(rows, TOPK_PIECE)``
+  int32 with one sum across lanes a pass, over the columns up to the
+  tile's last causal one and none beyond (a dynamic trip count from the
+  rows' positions, prefetched). The search by position runs only in a
+  tile where a row has more ties at its k-th value than it still needs;
+  a tile whose rows have no more than ``top_k`` causal keys searches for
+  nothing. It runs on a TPU for float32 scores of whole tiles of rows
+  and whole lanes of columns: ``selected_attention``'s ``(256, L)``
+  buffers. In XLA each of the eight value passes compares every element
+  of the whole buffer, the columns after the causal ones too, against 15
+  candidates and reads it from HBM; in VMEM a pass's fixed cost (the
+  load of a piece, the loop, the sums across lanes) weighs about four
+  compares, so 16 passes of 2-bit digits beat 32 of one bit and 8 of
+  four (PERF.md §6, the forms of the top-k).
+- ``"xla"``: :func:`_top_k_xla`, the radix search over whole rows
+  (:func:`_radix_search`, four bits a pass), then the search by
+  position on every row. It runs everywhere else — the CPU, the few
+  named rows of ``selected_rows``, ``choose_blocks``' block scores
+  (whose columns are not whole lanes) — and is the oracle of the
+  kernel's parity test (``tests/test_topk_fused.py``).
 """
 
 from __future__ import annotations
 
 import functools
 import math
-from typing import Callable
+from typing import Callable, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -213,6 +244,52 @@ def _radix_search(holds: Callable, n_bits: int, rows: int):
     return r
 
 
+TOPK_ROWS = 16             # rows of one grid step of the top-k kernel
+TOPK_PIECE = 1024          # columns of one trip of its counting loops
+TOPK_MAX_COLS = 131072     # a step's scores and keys fit VMEM twice over
+RADIX_BITS = 2             # of the threshold a value pass decides
+# (readings of the forms: PERF.md §6, the top-k kernel)
+_INT_MIN = -2 ** 31        # the key of a column past the query: u = 0
+SELECTOR_DTYPE = jnp.float32   # a block's scores that top_k_mask cuts
+
+
+def topk_path(rows: int, cols: int, dtype, backend: str = "") -> str:
+    """The form :func:`top_k_mask` runs for ``(rows, cols)`` scores:
+    ``"fused"`` (the Pallas kernel) on a TPU for float32 scores in whole
+    tiles of ``TOPK_ROWS`` rows and whole lanes of columns, ``"xla"``
+    everywhere else. A route no longer than ``top_k`` takes neither: its
+    mask is every causal key. ``backend`` defaults to JAX's own."""
+    tiles = (rows % TOPK_ROWS == 0 and cols % _LANES == 0
+             and cols <= TOPK_MAX_COLS)
+    on_tpu = (backend or jax.default_backend()) == "tpu"
+    return ("fused" if on_tpu and tiles and jnp.dtype(dtype) == jnp.float32
+            else "xla")
+
+
+def _ordered(scores):
+    """int32 keys whose signed order is the float32 scores' order."""
+    bits = jax.lax.bitcast_convert_type(
+        jnp.where(scores == 0, 0.0, scores), jnp.int32)   # -0.0 is 0.0
+    return bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF))
+
+
+def _sortable(scores, causal):
+    """(Q, K) uint32 in the order of the float32 scores, 0 off the
+    causal columns: no finite score maps to 0."""
+    u = jax.lax.bitcast_convert_type(
+        _ordered(scores.astype(jnp.float32)), jnp.uint32) ^ jnp.uint32(
+        0x80000000)
+    return jnp.where(causal, u, jnp.uint32(0))
+
+
+def _cut_at(u, causal, s_pos, kth, last_tie):
+    """The keys above the k-th value and those equal to it up to
+    position ``last_tie``: both forms' last line."""
+    above = u > kth
+    tie = (u == kth) & causal
+    return (above & causal) | (tie & (s_pos.astype(jnp.uint32) <= last_tie))
+
+
 def top_k_mask(scores, t_pos, top_k: int):
     """(Q, K) bool: the ``top_k`` largest of ``scores[q, s]`` over ``s <=
     t_pos[q]``, ties to the lower s; every such s where there are no
@@ -220,19 +297,28 @@ def top_k_mask(scores, t_pos, top_k: int):
     are mapped to unsigned integers of the same order, the value of the
     k-th largest is found by a radix search on counts, then the cut
     among the keys equal to it by a second search on their positions.
-    """
+    Two forms of it, chosen by :func:`topk_path` from the shapes, dtype
+    and backend: :func:`_top_k_fused` finds each row's k-th value and
+    last tie in one kernel, :func:`_top_k_xla` everything in XLA."""
+    n_q, n_k = scores.shape
+    if n_k <= top_k or topk_path(n_q, n_k, scores.dtype) == "xla":
+        return _top_k_xla(scores, t_pos, top_k)
+    s_pos = jnp.arange(n_k, dtype=jnp.int32)[None, :]
+    causal = s_pos <= t_pos[:, None]
+    kth, last_tie = _top_k_fused(scores, t_pos, top_k=top_k)
+    return _cut_at(_sortable(scores, causal), causal, s_pos, kth[:, None],
+                   last_tie[:, None])
+
+
+def _top_k_xla(scores, t_pos, top_k: int):
+    """:func:`top_k_mask` in XLA: the CPU's form, the kernel's oracle,
+    and the form of every shape the kernel does not tile."""
     n_q, n_k = scores.shape
     s_pos = jnp.arange(n_k, dtype=jnp.int32)[None, :]
     causal = s_pos <= t_pos[:, None]
     if n_k <= top_k:
         return causal
-    scores = scores.astype(jnp.float32)
-    bits = jax.lax.bitcast_convert_type(
-        jnp.where(scores == 0, 0.0, scores), jnp.int32)   # -0.0 is 0.0
-    ordered = bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF))
-    u = jax.lax.bitcast_convert_type(ordered, jnp.uint32) ^ jnp.uint32(
-        0x80000000)
-    u = jnp.where(causal, u, jnp.uint32(0))     # no finite score maps to 0
+    u = _sortable(scores, causal)
     want = jnp.minimum(top_k, t_pos + 1).astype(jnp.int32)
 
     def at_least_k(cand):
@@ -252,7 +338,148 @@ def top_k_mask(scores, t_pos, top_k: int):
 
     pos_bits = 4 * math.ceil(max(n_k - 1, 1).bit_length() / 4)
     last_tie = _radix_search(fewer_before, pos_bits, n_q)[:, None]
-    return (above & causal) | (tie & (s_pos.astype(jnp.uint32) <= last_tie))
+    return _cut_at(u, causal, s_pos, kth, last_tie)
+
+
+def _causal_pieces(t_max, n_k: int, piece: int):
+    """The pieces of ``piece`` columns, from the first, that hold a
+    column ``s <= t_max``: the only ones a tile of rows whose last
+    position is ``t_max`` reads."""
+    return jnp.minimum(t_max // piece + 1, n_k // piece)
+
+
+def _count_keys(key_ref, n_pieces, piece: int, preds):
+    """Per row of the step, the number of its first ``n_pieces * piece``
+    keys for which each of ``preds(keys, first column)`` holds: a
+    ``(rows, piece)`` int32 count a predicate, added to lane by lane
+    each trip and summed across lanes once at the end."""
+    rows = key_ref.shape[0]
+
+    def trip(j, accs):
+        at = pl.multiple_of(j * piece, piece)
+        keys = key_ref[:, pl.ds(at, piece)]
+        return tuple(a + p(keys, at).astype(jnp.int32)
+                     for a, p in zip(accs, preds))
+
+    accs = jax.lax.fori_loop(0, n_pieces, trip, tuple(
+        jnp.zeros((rows, piece), jnp.int32) for _ in preds))
+    return [a.sum(-1, keepdims=True) for a in accs]
+
+
+def _topk_kernel(t_sm, t_ref, s_ref, kth_ref, last_ref, key_ref, *,
+                 top_k: int, piece: int):
+    """One grid step: ``TOPK_ROWS`` rows of scores. ``t_sm`` (Q,) the
+    rows' positions in SMEM, ``t_ref`` the step's (rows, 128), a row's
+    in every lane. Writes each row's k-th value as a signed key (the
+    order of ``_sortable``'s u, shifted by 2**31) and its last tie, n_k
+    where every tie is taken; a row with no more than ``top_k`` causal
+    keys takes them all (k-th value u = 0)."""
+    rows, n_k = s_ref.shape
+    base = pl.program_id(0) * rows
+    t_max = jax.lax.fori_loop(
+        0, rows, lambda r, m: jnp.maximum(m, t_sm[base + r]), jnp.int32(0))
+    kth_ref[...] = jnp.full(kth_ref.shape, _INT_MIN, jnp.int32)
+    last_ref[...] = jnp.full(last_ref.shape, n_k, jnp.int32)
+
+    @pl.when(t_max >= top_k)
+    def _():
+        n_pieces = _causal_pieces(t_max, n_k, piece)
+        t = t_ref[:, :1]
+        want = jnp.minimum(t + 1, top_k)
+        col = jax.lax.broadcasted_iota(jnp.int32, (rows, piece), 1)
+
+        def to_keys(j, c):
+            at = pl.multiple_of(j * piece, piece)
+            key_ref[:, pl.ds(at, piece)] = jnp.where(
+                col + at <= t, _ordered(s_ref[:, pl.ds(at, piece)]), _INT_MIN)
+            return c
+
+        jax.lax.fori_loop(0, n_pieces, to_keys, 0)
+        n_dig = 2 ** RADIX_BITS - 1
+
+        def value_pass(p, carry):
+            # the threshold so far as a signed key, and how many keys of
+            # the row are at or above it
+            r, n_r = carry
+            shift = 32 - RADIX_BITS * (p + 1)
+            cands = [jnp.broadcast_to(r ^ jnp.left_shift(jnp.int32(d), shift),
+                                      (rows, piece))
+                     for d in range(1, n_dig + 1)]
+            counts = _count_keys(key_ref, n_pieces, piece, [
+                functools.partial(lambda c, k, at: k >= c, c) for c in cands])
+            digit = jnp.zeros_like(r)
+            for n in counts:        # fewer keys at each larger digit
+                ok = n >= want
+                digit, n_r = digit + ok.astype(jnp.int32), jnp.where(ok, n,
+                                                                     n_r)
+            return r ^ jnp.left_shift(digit, shift), n_r
+
+        kth, n_kth = jax.lax.fori_loop(
+            0, 32 // RADIX_BITS, value_pass,
+            (jnp.full((rows, 1), _INT_MIN, jnp.int32),
+             jnp.zeros((rows, 1), jnp.int32)))
+        kth_ref[...] = jnp.broadcast_to(kth, kth_ref.shape)
+
+        # more keys tie at the k-th value than a row still needs: the
+        # cut among them by position, ties to the lower s
+        @pl.when(jnp.any(n_kth != want))
+        def _():
+            kth_b = jnp.broadcast_to(kth, (rows, piece))
+            above, = _count_keys(key_ref, n_pieces, piece,
+                                 [lambda k, at: k > kth_b])
+            need = want - above
+            pos_bits = max(n_k - 1, 1).bit_length()
+
+            def pos_pass(p, last):
+                cand = last | jnp.left_shift(1, pos_bits - 1 - p)
+                n, = _count_keys(key_ref, n_pieces, piece, [
+                    lambda k, at: (k == kth_b) & (col + at < cand)])
+                return jnp.where(n < need, cand, last)
+
+            last = jax.lax.fori_loop(0, pos_bits, pos_pass,
+                                     jnp.zeros((rows, 1), jnp.int32))
+            last_ref[...] = jnp.broadcast_to(last, last_ref.shape)
+
+
+@functools.partial(jax.jit, static_argnames=("top_k", "interpret"))
+def _top_k_fused(scores, t_pos, *, top_k: int, interpret: bool = False):
+    """Each row's k-th value and last tie as one kernel
+    (``radix_top_k_step`` in a trace): scores (Q, K) float32, t_pos (Q,)
+    → (kth, last_tie), (Q,) uint32 each, kth in ``_sortable``'s order
+    (what ``_cut_at`` takes). A grid over tiles of ``TOPK_ROWS`` rows, each
+    held whole in VMEM: its keys are made once and counted ``RADIX_BITS``
+    of the threshold a pass over the columns up to the tile's last causal
+    one, none beyond; the position search runs only in a tile where
+    some row has more ties at its k-th value than it needs. Jitted, so
+    that the full layers of a step program share one trace and lowering
+    of it."""
+    n_q, n_k = scores.shape
+    piece = math.gcd(n_k, TOPK_PIECE)
+    if n_q % TOPK_ROWS or piece % _LANES:
+        raise ValueError(f"{n_q} rows in tiles of {TOPK_ROWS}, {n_k} "
+                         f"columns in lanes of {_LANES}: not whole tiles")
+    t = t_pos.astype(jnp.int32)
+    row = lambda i, t: (i, 0)   # noqa: E731
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(n_q // TOPK_ROWS,),
+        in_specs=[pl.BlockSpec((TOPK_ROWS, _LANES), row),
+                  pl.BlockSpec((TOPK_ROWS, n_k), row)],
+        out_specs=[pl.BlockSpec((TOPK_ROWS, _LANES), row)] * 2,
+        scratch_shapes=[pltpu.VMEM((TOPK_ROWS, n_k), jnp.int32)])
+    kth, last = pl.pallas_call(
+        functools.partial(_topk_kernel, top_k=top_k, piece=piece),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((n_q, _LANES), jnp.int32)] * 2,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=_VMEM_BYTES),
+        name="radix_top_k_step",
+        interpret=interpret,
+    )(t, jnp.broadcast_to(t[:, None], (n_q, _LANES)),
+      scores.astype(jnp.float32))
+    return (jax.lax.bitcast_convert_type(kth[:, 0], jnp.uint32)
+            ^ jnp.uint32(0x80000000), last[:, 0].astype(jnp.uint32))
 
 
 def selector_scores(q_idx, w_idx, k_idx):
@@ -306,6 +533,18 @@ def chunk_steps(length: int, block: int, chunk: int) -> int:
     block, chunk = block_and_chunk(length, block, chunk)
     return sum(((i + 1) * block + chunk - 1) // chunk
                for i in range(length // block))
+
+
+def topk_blocks(length: int, block: int, top_k: int,
+                backend: str = "") -> Tuple[str, int]:
+    """For one route of (padded) ``length`` in one selecting layer: the
+    form of :func:`top_k_mask` (:func:`topk_path` at the block's
+    ``SELECTOR_DTYPE`` scores, as :func:`selected_attention` hands them
+    over) and how many blocks of queries run it, none where the route is
+    no longer than ``top_k`` (every causal key, no selection)."""
+    block, _ = block_and_chunk(length, block, block)
+    return (topk_path(block, length, SELECTOR_DTYPE, backend),
+            length // block if length > top_k else 0)
 
 
 def _attend_xla(q, q_shared, k, k_shared, v, keys, b, n_chunks, *,
@@ -598,7 +837,7 @@ def selected_attention(q_fn: Callable, k, k_shared, v, idx_fn: Callable,
 
                 scores = jax.lax.fori_loop(
                     0, n_chunks, score_chunk,
-                    jnp.full((block, length), -jnp.inf, jnp.float32))
+                    jnp.full((block, length), -jnp.inf, SELECTOR_DTYPE))
             with jax.named_scope(scope + ".topk"):
                 keys = top_k_mask(scores, t_pos, top_k)
         else:

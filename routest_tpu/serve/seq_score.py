@@ -79,8 +79,11 @@ none of this. As every recorded span they are
 ``TraceAnnotation``s too. Counters: ``rtpu_seq_tokens_total{kind=real|
 padded}`` from the plan, for every model. ``RouteLM``:
 ``rtpu_seq_attention_chunks_total{path=fused|xla}`` (the full layers'
-steps over chunks of keys) and ``rtpu_seq_window_blocks_total{path=
-fused|xla}`` (the sliding layers' blocks of queries) from the plan, as
+steps over chunks of keys), ``rtpu_seq_topk_blocks_total{path=fused|
+xla}`` (the full layers' blocks of queries that ran the selection, by
+``parallel/select.topk_path`` at a block's scores) and
+``rtpu_seq_window_blocks_total{path=fused|xla}`` (the sliding layers'
+blocks of queries) from the plan, as
 ``rtpu_seq_expert_blocks_total{path=fused|xla}`` (expert blocks x
 steps, by ``parallel/expert.expert_path`` at the model's widths); and,
 read from the device
@@ -154,6 +157,11 @@ def _seq_metrics():
                 "Online-softmax steps over chunks of keys that the full "
                 "layers of the dispatched steps ran, by the form of the "
                 "step (fused: the Pallas kernel; xla).", ("path",)),
+            "topk_blocks": reg.counter(
+                "rtpu_seq_topk_blocks_total",
+                "Blocks of queries whose selection the full layers of the "
+                "dispatched steps ran, by the form of the radix top-k "
+                "(fused: the Pallas kernel; xla).", ("path",)),
             "window_blocks": reg.counter(
                 "rtpu_seq_window_blocks_total",
                 "Blocks of queries that the sliding layers of the "
@@ -236,9 +244,9 @@ def _seq_metrics():
     return _metrics
 
 
-_COUNTERS = ("tokens", "chunks", "window_blocks", "expert_blocks",
-             "expert_rows", "sparse_keys", "linear_chunks", "gqa_keys",
-             "mtp_positions", "latent_keys", "latent_tiles",
+_COUNTERS = ("tokens", "chunks", "topk_blocks", "window_blocks",
+             "expert_blocks", "expert_rows", "sparse_keys", "linear_chunks",
+             "gqa_keys", "mtp_positions", "latent_keys", "latent_tiles",
              "expert_group_tokens", "ssm_chunks")
 
 

@@ -282,6 +282,22 @@ def test_route_lm_counters_are_what_they_were(registry, scorers):
     assert _family(registry, "rtpu_seq_sparse_keys_total") == {}
 
 
+def test_route_lm_counts_its_selecting_blocks_by_the_form_of_the_top_k(
+        registry, scorers):
+    """Blocks of queries of the full layers that ran the selection, from
+    the plan: a toy route is longer than its ``top_k`` of 16, and on the
+    CPU every one takes the XLA form."""
+    m, params, scorer = scorers["dots3"]
+    ids, lengths, rows_at = (jnp.asarray(a)
+                             for a in dots3.routes(4, LENGTHS))
+    scorer.score(ids, lengths, rows_at)
+    plan = scorer.plan(np.asarray(LENGTHS))
+    want = sum(s.length // 8 * len(s.routes) * 2 for s in plan)
+    assert _family(registry, "rtpu_seq_topk_blocks_total") == {
+        ("xla",): want}
+    assert want == 2 * (96 + 40 + 72) // 8
+
+
 def test_kexaone_counters_and_span_attributes(registry, scorers):
     from routest_tpu.obs import get_tracer
     from routest_tpu.parallel import gqa

@@ -177,6 +177,24 @@ def test_selected_attention_step_compiles_for_v5e(v5e, routes, length):
     assert "tpu_custom_call" in compiled.as_text()   # Mosaic, not interpret
 
 
+# Its full layers' radix top-k at a block's (256, L) float32 scores: the
+# longest selecting class, one whose columns are not whole 1,024-column
+# pieces, and the shortest.
+@pytest.mark.parametrize("length", [26624, 4608, 3072])
+def test_radix_top_k_step_compiles_for_v5e(v5e, length):
+    from routest_tpu.parallel import select
+
+    assert select.topk_path(256, length, jnp.float32, backend="tpu") \
+        == "fused"
+    compiled = jax.jit(functools.partial(
+        select._top_k_fused, top_k=2048)).lower(
+        jax.ShapeDtypeStruct((256, length), jnp.float32, sharding=v5e),
+        jax.ShapeDtypeStruct((256,), jnp.int32, sharding=v5e)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text                 # Mosaic, not interpret
+    assert "radix_top_k_step" in text
+
+
 # Its sliding layers' window step at the published widths (64 heads, key
 # parts of 192 + 64, values of 128, a window of 513): the same two
 # classes. The keys come with the length last, as the layer's expansion
