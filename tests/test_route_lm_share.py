@@ -9,25 +9,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _route_lm_toy import CONFIG, highest, model
+from _route_lm_toys import (F32, TOYS, expert_layer, highest, model, params,
+                            program)
 from benchmark.reference import dots3_ref as ref
 from routest_tpu.parallel import expert, select
 from routest_tpu.serve import seq_score
 
-D, M, E, K = 64, 32, 16, 4
-
-
-def _layer(seed=0, held=E):
-    ks = jax.random.split(jax.random.PRNGKey(seed), 8)
-    mlp = lambda k, lead: {      # noqa: E731
-        "w_gate": jax.random.normal(k[0], lead + (D, M)) / 8,
-        "w_up": jax.random.normal(k[1], lead + (D, M)) / 8,
-        "w_down": jax.random.normal(k[2], lead + (M, D)) / 6}
-    p = mlp(ks[:3], (held,))
-    p["router"] = jax.random.normal(ks[3], (D, E)) / 8
-    p["bias"] = 0.3 * jax.random.normal(ks[4], (E,))
-    p["shared"] = mlp(ks[5:8], ())
-    return p
+D, E, K = 64, 16, 4
 
 
 def _cut(p, first, count):
@@ -42,7 +30,7 @@ def test_the_parts_of_all_shares_add_up_to_the_uncut_layer(n_shares):
     """Every share routes over all 16 experts and adds its own experts'
     terms; the shared expert, which every chip computes alike, is
     counted once."""
-    p = _layer()
+    p = expert_layer()
     x = jax.random.normal(jax.random.PRNGKey(9), (50, D))
     whole, _, _ = ref.moe(p, x, K, (0, E))
     shared = ref.gated_mlp(x, p["shared"])
@@ -63,7 +51,7 @@ def test_the_parts_of_all_shares_add_up_to_the_uncut_layer(n_shares):
 
 
 def test_routing_is_over_all_experts_and_the_bias_moves_only_the_choice():
-    p = _layer(1)
+    p = expert_layer(1)
     x = jax.random.normal(jax.random.PRNGKey(2), (64, D))
     chosen, w = highest(expert.route_top_k)(x, p["router"], p["bias"], K)
     want_c, want_w = ref.route(p, x, K)
@@ -88,7 +76,7 @@ def test_grouped_product_with_uneven_and_empty_groups(tile, chunk, tokens,
     out."""
     monkeypatch.setattr(expert, "row_tile_of", lambda path: tile)
     monkeypatch.setattr(expert, "CHUNK_ROWS", chunk)
-    p = _layer(3)
+    p = expert_layer(3)
     x = jax.random.normal(jax.random.PRNGKey(4), (tokens, D))
     chosen, w = expert.route_top_k(x, p["router"], p["bias"], K)
     chosen = jnp.where(chosen == 5, 6, chosen)       # nobody takes 5
@@ -231,21 +219,17 @@ def test_ladder_is_the_least_padding_by_brute_force():
 def test_scorer_pass_spans_counters_and_results():
     from routest_tpu.obs import get_registry, get_tracer
 
-    m = model()
-    params = jax.jit(m.init)(jax.random.PRNGKey(0))
-    scorer = seq_score.RouteScorer(m, params, max_step_tokens=96,
-                                   max_classes=2)
-    from _route_lm_toy import routes
-
-    ids, lengths, rows_at = routes(4, [40, 17, 30, 9])
+    m, p = model("RouteLM", F32), params("RouteLM", F32, 0)
+    scorer = seq_score.RouteScorer(m, p, max_step_tokens=96, max_classes=2)
+    ids, lengths, rows_at = TOYS["RouteLM"].routes(4, [40, 17, 30, 9])
     before = {k[0]: c.value for k, c in (get_registry().get(
         "rtpu_seq_tokens_total").items() if get_registry().get(
             "rtpu_seq_tokens_total") else [])}
     res = highest(scorer.score)(jnp.asarray(ids), jnp.asarray(lengths),
                                 jnp.asarray(rows_at))
     plan = scorer.plan(lengths)
-    alone = highest(jax.jit(m.apply))(params, ids[1:2, :24], lengths[1:2],
-                                      rows_at[1:2])
+    alone = highest(program("RouteLM", F32))(p, ids[1:2, :24], lengths[1:2],
+                                             rows_at[1:2])
     np.testing.assert_allclose(res.lse[1, :17], alone["lse"][0, :17],
                                atol=2e-5)
     np.testing.assert_allclose(res.loglik[1], alone["loglik"][0], rtol=1e-5)
@@ -268,4 +252,4 @@ def test_scorer_pass_spans_counters_and_results():
     assert 0.3 < share < 0.7                 # 8 of 16 experts are held
     keys = get_registry().get(
         "rtpu_seq_selected_keys_per_query").items()[0][1].value
-    assert 1.0 <= keys <= CONFIG["index_topk"]
+    assert 1.0 <= keys <= TOYS["RouteLM"].config["index_topk"]

@@ -255,7 +255,7 @@ def test_selected_attention_on_the_cpu_is_the_xla_step_at_tileable_shapes():
 
 
 def test_the_scorer_names_the_step_and_counts_its_chunks():
-    from _route_lm_toy import highest, model, routes
+    from _route_lm_toys import F32, TOYS, highest, model, params
     from routest_tpu.obs import get_registry, get_tracer
     from routest_tpu.serve import seq_score
 
@@ -264,11 +264,10 @@ def test_the_scorer_names_the_step_and_counts_its_chunks():
         return ({k[0]: c.value for k, c in family.items()} if family
                 else {})
 
-    m = model()
-    scorer = seq_score.RouteScorer(
-        m, jax.jit(m.init)(jax.random.PRNGKey(0)), max_step_tokens=96,
-        max_classes=2)
-    ids, lengths, rows_at = routes(4, [40, 17, 30, 9])
+    m = model("RouteLM", F32)
+    scorer = seq_score.RouteScorer(m, params("RouteLM", F32, 0),
+                                   max_step_tokens=96, max_classes=2)
+    ids, lengths, rows_at = TOYS["RouteLM"].routes(4, [40, 17, 30, 9])
     before = chunks()
     highest(scorer.score)(jnp.asarray(ids), jnp.asarray(lengths),
                           jnp.asarray(rows_at))

@@ -10,23 +10,22 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import _route_lm_falcon_h1_toy as falcon
-import _route_lm_gigachat_toy as gigachat
-import _route_lm_kexaone_toy as kexaone
-import _route_lm_sala_toy as sala
-import _route_lm_toy as dots3
+import _route_lm_toys as toys
+from _route_lm_toys import F32, model, program
 from routest_tpu.serve import seq_score
 from routest_tpu.serve.seq_score import RouteScorer, plan_pass
 
 LENGTHS = [96, 33, 70]
-TOYS = {"dots3": dots3, "sala": sala, "kexaone": kexaone,
-        "gigachat": gigachat, "falcon": falcon}
+TOYS = {name: toys.TOYS[cls] for name, cls in (
+    ("dots3", "RouteLM"), ("sala", "RouteLMSala"),
+    ("kexaone", "RouteLMKExaone"), ("gigachat", "RouteLMGigaChat"),
+    ("falcon", "RouteLMFalconH1"))}
+dots3, sala, kexaone, gigachat, falcon = TOYS.values()
 
 
 def _scorer(toy, **kw):
-    m = toy.model()
-    params = jax.jit(m.init)(jax.random.PRNGKey(0))
-    return m, params, RouteScorer(m, params, **kw)
+    m, p = model(toy.name, F32), toys.params(toy.name, F32, 0)
+    return m, p, RouteScorer(m, p, **kw)
 
 
 FIRST_PASS = {}      # model → the seq.step spans of its compiling pass
@@ -71,11 +70,11 @@ def scored(request, scorers):
 
 
 def test_a_pass_through_the_scorer_is_the_model_route_by_route(scored):
-    _, m, params, _, (ids, lengths, rows_at), scores = scored
+    name, m, params, _, (ids, lengths, rows_at), scores = scored
     q = m.length_quantum
     for r, n in enumerate(int(v) for v in lengths):
         padded = -(-n // q) * q
-        alone = jax.jit(m.apply)(
+        alone = program(TOYS[name].name, F32)(
             params, jnp.pad(ids[r:r + 1, :n], ((0, 0), (0, padded - n))),
             lengths[r:r + 1], rows_at[r:r + 1])
         np.testing.assert_allclose(scores.lse[r, :n], alone["lse"][0, :n],
@@ -123,7 +122,7 @@ def test_the_modules_column_reaches_the_caller_over_the_table(scorers):
     assert tables["mtp_loglik"][2] is None and tables["mtp_lse"][2] == 2
     for r, n in enumerate(int(v) for v in lengths):
         padded = -(-n // 8) * 8
-        alone = jax.jit(m.apply)(
+        alone = program(kexaone.name, F32)(
             params, jnp.pad(ids[r:r + 1, :n], ((0, 0), (0, padded - n))),
             lengths[r:r + 1], rows_at[r:r + 1])
         for tap in ("mtp_next_logit", "mtp_lse"):
@@ -440,7 +439,7 @@ def test_falcon_counters_span_attributes_and_states(registry, scorers):
     assert all(s["attrs"]["mixers"] == "ssm=xla,attn=xla" for s in steps)
     for r, n in enumerate(int(v) for v in lengths):
         padded = -(-n // 8) * 8
-        alone = jax.jit(m.apply)(
+        alone = program(falcon.name, F32)(
             params, jnp.pad(ids[r:r + 1, :n], ((0, 0), (0, padded - n))),
             lengths[r:r + 1], rows_at[r:r + 1])
         np.testing.assert_allclose(scores.taps["state"][:, r],
@@ -581,8 +580,7 @@ class _NoStats:
     ((96, 72, 40), [(96, 199, 3)])])
 def test_a_step_with_nothing_to_wait_on_is_timed_with_the_next(
         silent, want, ledger):
-    m = dots3.model()
-    params = jax.jit(m.init)(jax.random.PRNGKey(0))
+    m, params = model("RouteLM", F32), toys.params("RouteLM", F32, 0)
     scorer = RouteScorer(_NoStats(m, silent), params, max_step_tokens=128)
     args = [jnp.asarray(a) for a in dots3.routes(4, LENGTHS)]
     scorer.score(*args)

@@ -310,11 +310,10 @@ def test_a_planted_fault_of_the_selection_stays_on_the_timed_path():
 
 
 def test_the_model_counts_the_blocks_that_select_by_form():
-    from _route_lm_toy import model
-
+    from _route_lm_toys import F32, model
     from routest_tpu.serve import seq_score
 
-    m = model()
+    m = model("RouteLM", F32)
     top_k = m.attention_sizes("full_attention").top_k
     plan = seq_score.plan_pass([96, 33, 70, 12], m.length_quantum, 128, 8)
     counted = [c for c in m.pass_counts(plan, [], 211)

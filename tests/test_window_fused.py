@@ -246,11 +246,11 @@ def test_the_one_span_of_keys_a_tile_of_queries_fetches(t0, window, length,
 
 
 def test_the_model_names_the_window_step_and_counts_its_blocks():
-    from _route_lm_toy import model
+    from _route_lm_toys import F32, model
     from benchmark import run as R
     from routest_tpu.models.route_lm import RouteLM
 
-    toy = model()
+    toy = model("RouteLM", F32)
     assert toy.window_steps(96) == ("xla", 12)      # blocks of 8, the CPU
     assert toy.window_steps(5) == ("xla", 1)
     assert toy.step_attrs(96)["window"] == "xla"
